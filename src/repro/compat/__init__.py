@@ -1,11 +1,6 @@
-"""Version-adaptive JAX shims — the only sanctioned import path for
-version-sensitive JAX symbols (see jax_compat.py for the support matrix)."""
+"""JAX compatibility shims — the only sanctioned import path for
+version-sensitive JAX symbols (see jax_compat.py)."""
 from repro.compat.jax_compat import (  # noqa: F401
-    HAS_DIFFERENTIABLE_BARRIER,
-    HAS_NATIVE_AXIS_TYPE,
-    HAS_NATIVE_MAKE_MESH,
-    HAS_NATIVE_SHARD_MAP,
-    HAS_PARTIAL_MANUAL_SHARD_MAP,
     JAX_VERSION,
     AxisType,
     abstract_mesh,
@@ -13,6 +8,7 @@ from repro.compat.jax_compat import (  # noqa: F401
     context_mesh,
     current_axis_types,
     describe_support,
+    enable_compilation_cache,
     import_pallas,
     import_pallas_tpu,
     in_manual_context,

@@ -1,64 +1,44 @@
-"""Version-adaptive JAX compatibility layer.
+"""JAX compatibility layer: the one place that names version-sensitive JAX
+API.
 
-Everything in this repo that touches a JAX API whose spelling changed between
-JAX generations goes through this module — the same way the thesis' system
-support hides PIM hardware-generation differences from programmers, this shim
-hides JAX-generation differences from every kernel, model, and launch path.
-New code MUST import version-sensitive symbols from ``repro.compat`` only
-(enforced by tests/test_compat.py and CI).
+Everything in this repo that touches a JAX API whose spelling has moved
+between JAX releases goes through this module — the same way the thesis'
+system support hides PIM hardware-generation differences from programmers.
+New code MUST import these symbols from ``repro.compat`` only (enforced by
+tests/test_compat.py and the CI lint in tools/check_jax_compat.py), so the
+next move is a one-file change.
 
-Support matrix (selected at import time):
+Supported: JAX 0.9 with its libtpu (the Pallas TPU namespace is always
+present). Older releases are refused at import.
 
-  symbol / behaviour        JAX 0.4.x (>= 0.4.35)            JAX >= 0.5
-  ------------------------  -------------------------------  -----------------------------
-  shard_map                 jax.experimental.shard_map       jax.shard_map
-    partial-manual axes     auto= (complement of manual      axis_names= (the manual set)
-                            set; jit-only — the 0.4.x
-                            eager impl raises
-                            NotImplementedError)
-    replication check flag  check_rep=                       check_vma=
-  AxisType                  local enum stub (Auto/           jax.sharding.AxisType
-                            Explicit/Manual)
-  make_mesh                 jax.make_mesh (axis_types        jax.make_mesh
-                            kwarg dropped); pre-0.4.35
-                            fallback via mesh_utils
-  manual-axis detection     thread-local recorded by this    jax.sharding.get_abstract_mesh()
-    (is_manual_axis, ...)   module's shard_map wrapper at      .axis_types, with the same
-                            trace time (0.4.x tracing only     thread-local as tie-breaker
-                            exposes manual axes through        for exact nested-context
-                            SPMDAxisContext at lowering,       info
-                            too late for trace-time policy)
-  pallas entry points       jax.experimental.pallas(+.tpu)   same (re-exported lazily)
-  tree utilities            jax.tree.* with jax.tree_util    jax.tree.*
-                            fallback
+Two behaviours are chosen here rather than inherited from JAX's defaults:
 
-Known 0.4.x behaviour change: a partial-manual ``shard_map`` (``axis_names``
-a strict subset of the mesh axes) is *promoted to fully-manual* there — the
-0.4.x jaxlib SPMD partitioner hard-crashes on manual-subgroup modules and
-the eager ``auto=`` path is unimplemented upstream. See the
-``HAS_PARTIAL_MANUAL_SHARD_MAP`` note below for the exact conditions and
-cost.
+* ``make_mesh`` builds **Auto** axes unless the caller asks otherwise. JAX
+  0.9 defaults every axis to Explicit, under which GSPMD-style code —
+  ``with_sharding_constraint`` on logical axes, ``dynamic_update_slice`` on
+  sharded operands — raises ``ShardingTypeError``; every mesh in this repo
+  is built for Auto propagation.
+* manual-axis detection (``is_manual_axis`` & co.) combines the abstract
+  mesh's axis types with a thread-local record kept by this module's
+  ``shard_map`` wrapper, which gives exact answers for nested contexts at
+  trace time.
 """
 from __future__ import annotations
 
 import contextlib
-import enum
 import functools
-import inspect
+import os
 import re as _re
 import threading
 from typing import Any, Callable, Dict, FrozenSet, Optional, Sequence, Tuple
 
 import jax
-from jax.sharding import Mesh
+from jax.experimental import pallas as _pl
+from jax.experimental.pallas import tpu as _pltpu
+from jax.sharding import AxisType, Mesh
 
 __all__ = [
     "JAX_VERSION",
-    "HAS_NATIVE_SHARD_MAP",
-    "HAS_NATIVE_AXIS_TYPE",
-    "HAS_NATIVE_MAKE_MESH",
-    "HAS_PARTIAL_MANUAL_SHARD_MAP",
-    "HAS_DIFFERENTIABLE_BARRIER",
     "optimization_barrier",
     "axis_size",
     "AxisType",
@@ -83,6 +63,7 @@ __all__ = [
     "tree_reduce",
     "tree_all",
     "describe_support",
+    "enable_compilation_cache",
 ]
 
 
@@ -100,53 +81,17 @@ def _parse_version(v: str) -> Tuple[int, ...]:
 
 
 JAX_VERSION: Tuple[int, ...] = _parse_version(jax.__version__)
+if JAX_VERSION < (0, 9):
+    raise ImportError(f"repro needs jax >= 0.9 (found {jax.__version__})")
+
+optimization_barrier = jax.lax.optimization_barrier
+axis_size = jax.lax.axis_size
 
 # ---------------------------------------------------------------------------
-# AxisType
+# shard_map with trace-time manual-axis recording
 # ---------------------------------------------------------------------------
-try:  # >= 0.5 public spelling
-    from jax.sharding import AxisType  # type: ignore[attr-defined]
-
-    HAS_NATIVE_AXIS_TYPE = True
-except ImportError:
-    HAS_NATIVE_AXIS_TYPE = False
-
-    class AxisType(enum.Enum):  # type: ignore[no-redef]
-        """Stub of jax.sharding.AxisType for JAX < 0.5."""
-
-        Auto = enum.auto()
-        Explicit = enum.auto()
-        Manual = enum.auto()
-
-
-# ---------------------------------------------------------------------------
-# shard_map: one spelling for every JAX generation
-# ---------------------------------------------------------------------------
-HAS_NATIVE_SHARD_MAP = hasattr(jax, "shard_map")
-if HAS_NATIVE_SHARD_MAP:
-    _raw_shard_map = jax.shard_map  # type: ignore[attr-defined]
-else:
-    from jax.experimental.shard_map import shard_map as _raw_shard_map
-
-_RAW_PARAMS = frozenset(inspect.signature(_raw_shard_map).parameters)
-
-# Partial-manual (a strict subset of mesh axes manual, the rest left to
-# GSPMD) is only dependable from 0.5 on: the 0.4.x jaxlib SPMD partitioner
-# hard-CHECK-fails (process abort) on many manual-subgroup modules
-# (spmd_partitioner.cc / hlo_sharding_util.cc), and the eager interpreter
-# path raises NotImplementedError. On 0.4.x this shim therefore *promotes*
-# partial-manual maps to fully-manual — legal whenever no in/out spec
-# mentions an auto axis and the body only issues collectives over its manual
-# axes (both true throughout this repo; the spec condition is verified at
-# call time). The cost is that GSPMD no longer distributes the body over the
-# auto axes on 0.4.x (redundant replicated compute there); semantics and
-# results are unchanged.
-HAS_PARTIAL_MANUAL_SHARD_MAP = JAX_VERSION >= (0, 5)
-
 # Thread-local stack of (abstract mesh, frozenset(manual axis names)),
-# pushed while the body of a compat shard_map is being traced. This is the
-# 0.4.x source of truth for manual-axis queries (the tracing axis env binds
-# auto axes too, so it cannot distinguish manual from auto there).
+# pushed while the body of a compat shard_map is being traced.
 _trace_ctx = threading.local()
 
 
@@ -167,26 +112,6 @@ def _recording_manual(mesh, manual: FrozenSet[str]):
         stack.pop()
 
 
-def _spec_axis_names(specs) -> FrozenSet[str]:
-    """Every mesh axis name mentioned anywhere in a pytree of PartitionSpecs."""
-    from jax.sharding import PartitionSpec  # noqa: PLC0415
-
-    names: set = set()
-    leaves = jax.tree_util.tree_leaves(
-        specs, is_leaf=lambda x: isinstance(x, PartitionSpec))
-    for leaf in leaves:
-        if not isinstance(leaf, PartitionSpec):
-            continue
-        for entry in leaf:
-            if entry is None:
-                continue
-            if isinstance(entry, (tuple, list)):
-                names.update(entry)
-            else:
-                names.add(entry)
-    return frozenset(names)
-
-
 def shard_map(
     f: Callable,
     mesh=None,
@@ -194,47 +119,21 @@ def shard_map(
     out_specs: Any = None,
     *,
     axis_names: Optional[FrozenSet[str]] = None,
-    check_vma: Optional[bool] = None,
-    check_rep: Optional[bool] = None,
-    auto: Optional[FrozenSet[str]] = None,
+    check_vma: bool = True,
 ):
-    """Normalized shard_map across JAX generations.
+    """``jax.shard_map`` that records its manual axes for the queries below.
 
-    ``axis_names`` is the >=0.5 spelling: the set of mesh axes that are
-    *manual* inside ``f`` (omitted = all axes manual). ``auto`` (the 0.4.x
-    spelling: the complement) is accepted for symmetry; pass at most one.
-    ``check_vma`` / ``check_rep`` are the same flag under its new / old name.
+    ``axis_names`` is the set of mesh axes that are *manual* inside ``f``
+    (omitted = all axes manual); the rest stay Auto (partial-manual).
     """
     if mesh is None:
         raise TypeError("shard_map: mesh is required")
     all_axes = frozenset(mesh.axis_names)
-    if axis_names is not None and auto is not None:
-        raise TypeError("shard_map: pass axis_names or auto, not both")
-    if axis_names is not None:
-        manual = frozenset(axis_names)
-    elif auto is not None:
-        manual = all_axes - frozenset(auto)
-    else:
-        manual = all_axes
+    manual = all_axes if axis_names is None else frozenset(axis_names)
     if not manual <= all_axes:
         raise ValueError(
             f"shard_map: manual axes {sorted(manual)} not a subset of mesh "
             f"axes {sorted(all_axes)}")
-    if manual != all_axes and not HAS_PARTIAL_MANUAL_SHARD_MAP:
-        offending = (_spec_axis_names(in_specs)
-                     | _spec_axis_names(out_specs)) & (all_axes - manual)
-        if offending:
-            raise NotImplementedError(
-                f"jax {jax.__version__} cannot partition partial-manual "
-                f"shard_map whose specs mention auto axes {sorted(offending)}"
-                " (the 0.4.x fully-manual promotion needs specs confined to "
-                "the manual axes)")
-        manual = all_axes  # promote: see HAS_PARTIAL_MANUAL_SHARD_MAP note
-    check = True
-    if check_vma is not None:
-        check = check_vma
-    elif check_rep is not None:
-        check = check_rep
 
     @functools.wraps(f)
     def traced(*args, **kwargs):
@@ -242,51 +141,23 @@ def shard_map(
             return f(*args, **kwargs)
 
     kw: Dict[str, Any] = {"mesh": mesh, "in_specs": in_specs,
-                          "out_specs": out_specs}
-    if "check_vma" in _RAW_PARAMS:
-        kw["check_vma"] = check
-    elif "check_rep" in _RAW_PARAMS:
-        kw["check_rep"] = check
+                          "out_specs": out_specs, "check_vma": check_vma}
     if manual != all_axes:
-        if "axis_names" in _RAW_PARAMS:
-            kw["axis_names"] = set(manual)
-        elif "auto" in _RAW_PARAMS:
-            kw["auto"] = all_axes - manual
-        else:  # pragma: no cover - no partial-manual support at all
-            raise NotImplementedError(
-                f"installed jax {jax.__version__} shard_map supports neither "
-                "axis_names= nor auto=; partial-manual maps unavailable")
-    return _raw_shard_map(traced, **kw)
+        kw["axis_names"] = set(manual)
+    return jax.shard_map(traced, **kw)
 
 
 # ---------------------------------------------------------------------------
 # Mesh construction
 # ---------------------------------------------------------------------------
-_native_make_mesh = getattr(jax, "make_mesh", None)
-HAS_NATIVE_MAKE_MESH = _native_make_mesh is not None
-_MM_PARAMS = (frozenset(inspect.signature(_native_make_mesh).parameters)
-              if HAS_NATIVE_MAKE_MESH else frozenset())
-
-
 def make_mesh(axis_shapes: Sequence[int], axis_names: Sequence[str], *,
               devices=None, axis_types=None) -> Mesh:
-    """jax.make_mesh across generations.
-
-    ``axis_types`` is honoured when the installed JAX understands it
-    (>= 0.5) and silently dropped otherwise — 0.4.x meshes are untyped and
-    axis-type policy is carried by this module's shard_map wrapper instead.
-    """
-    if HAS_NATIVE_MAKE_MESH:
-        kw: Dict[str, Any] = {}
-        if devices is not None:
-            kw["devices"] = devices
-        if axis_types is not None and "axis_types" in _MM_PARAMS:
-            kw["axis_types"] = axis_types
-        return _native_make_mesh(tuple(axis_shapes), tuple(axis_names), **kw)
-    from jax.experimental import mesh_utils  # noqa: PLC0415
-
-    devs = mesh_utils.create_device_mesh(tuple(axis_shapes), devices=devices)
-    return Mesh(devs, tuple(axis_names))
+    """``jax.make_mesh`` with Auto axes unless ``axis_types`` says otherwise
+    (see the module docstring for why Auto is the default here)."""
+    if axis_types is None:
+        axis_types = (AxisType.Auto,) * len(axis_names)
+    return jax.make_mesh(tuple(axis_shapes), tuple(axis_names),
+                         axis_types=tuple(axis_types), devices=devices)
 
 
 def abstract_mesh(mesh):
@@ -294,7 +165,7 @@ def abstract_mesh(mesh):
     if mesh is None:
         return None
     if isinstance(mesh, Mesh):
-        return getattr(mesh, "abstract_mesh", mesh)
+        return mesh.abstract_mesh
     return mesh  # already abstract
 
 
@@ -302,16 +173,12 @@ def abstract_mesh(mesh):
 # Trace-context queries (manual-axis detection)
 # ---------------------------------------------------------------------------
 def _native_context() -> Optional[Tuple[Any, FrozenSet[str]]]:
-    get = getattr(jax.sharding, "get_abstract_mesh", None)
-    if get is None:
+    m = jax.sharding.get_abstract_mesh()
+    if m is None or m.empty:
         return None
-    m = get()
-    if m is None or getattr(m, "empty", True):
-        return None
-    types = getattr(m, "axis_types", None) or ()
     manual = frozenset(
-        n for n, t in zip(m.axis_names, types)
-        if t == getattr(AxisType, "Manual", None))
+        n for n, t in zip(m.axis_names, m.axis_types)
+        if t == AxisType.Manual)
     return m, manual
 
 
@@ -361,148 +228,74 @@ def in_manual_context() -> bool:
 
 
 # ---------------------------------------------------------------------------
-# Collective helpers
-# ---------------------------------------------------------------------------
-_native_axis_size = getattr(jax.lax, "axis_size", None)
-
-
-def axis_size(axis_name) -> int:
-    """jax.lax.axis_size across generations (0.4.x lacks it).
-
-    The psum-of-1 fallback is the classic spelling: a literal reduced over a
-    named axis folds to the axis extent at trace time.
-    """
-    if _native_axis_size is not None:
-        return _native_axis_size(axis_name)
-    return jax.lax.psum(1, axis_name)
-
-
-# ---------------------------------------------------------------------------
-# optimization_barrier (differentiable on every supported JAX)
-# ---------------------------------------------------------------------------
-def _probe_differentiable_barrier() -> bool:
-    try:  # trace-only: no compile, no execution
-        jax.make_jaxpr(jax.grad(
-            lambda x: jax.lax.optimization_barrier(x)))(1.0)
-        return True
-    except NotImplementedError:
-        return False
-
-
-HAS_DIFFERENTIABLE_BARRIER = _probe_differentiable_barrier()
-
-if HAS_DIFFERENTIABLE_BARRIER:
-    optimization_barrier = jax.lax.optimization_barrier
-else:
-    # 0.4.x lacks the differentiation rule upstream; mirror the later-JAX
-    # semantics (barrier the cotangents too) via custom_vjp.
-    @jax.custom_vjp
-    def optimization_barrier(x):
-        return jax.lax.optimization_barrier(x)
-
-    def _barrier_fwd(x):
-        return jax.lax.optimization_barrier(x), None
-
-    def _barrier_bwd(_, g):
-        def leaf(ct):
-            dt = getattr(ct, "dtype", None)
-            if dt is not None and dt == jax.dtypes.float0:
-                return ct  # no barrier on symbolic zero cotangents
-            return jax.lax.optimization_barrier(ct)
-
-        return (jax.tree_util.tree_map(leaf, g),)
-
-    optimization_barrier.defvjp(_barrier_fwd, _barrier_bwd)
-
-
-# ---------------------------------------------------------------------------
 # Pallas entry points
 # ---------------------------------------------------------------------------
 def import_pallas():
-    """The pallas module (jax.experimental.pallas on every supported JAX)."""
-    from jax.experimental import pallas as pl  # noqa: PLC0415
-
-    return pl
+    """The pallas module (``jax.experimental.pallas``)."""
+    return _pl
 
 
 def import_pallas_tpu():
-    """The TPU pallas namespace, or None when this install lacks it."""
-    try:
-        from jax.experimental.pallas import tpu as pltpu  # noqa: PLC0415
-
-        return pltpu
-    except ImportError:
-        return None
+    """The TPU pallas namespace (``jax.experimental.pallas.tpu``)."""
+    return _pltpu
 
 
 def pallas_call(*args, **kwargs):
-    """Late-bound pl.pallas_call (resolves against the installed pallas)."""
-    return import_pallas().pallas_call(*args, **kwargs)
+    """``pl.pallas_call``."""
+    return _pl.pallas_call(*args, **kwargs)
 
 
 def pallas_prefetch_grid_spec():
-    """The scalar-prefetch grid-spec class (``pltpu.PrefetchScalarGridSpec``),
-    or None when this install lacks it.
+    """The scalar-prefetch grid-spec class (``pltpu.PrefetchScalarGridSpec``).
 
     Scalar-prefetch arguments are available to BlockSpec index maps before
     the kernel body runs — the mechanism that lets the paged decode kernel
-    resolve data-dependent page-table lookups into kv block indices. The
-    class lives in the TPU namespace and its location is version-sensitive,
-    so callers must obtain it here; when it is absent the paged attention
-    dispatch falls back to a pool gather + the dense decode kernel.
+    resolve data-dependent page-table lookups into kv block indices.
     """
-    pltpu = import_pallas_tpu()
-    if pltpu is None:
-        return None
-    return getattr(pltpu, "PrefetchScalarGridSpec", None)
+    return _pltpu.PrefetchScalarGridSpec
 
 
 def pallas_vmem_scratch(shape: Tuple[int, ...], dtype):
-    """A VMEM scratch allocation for ``pallas_call(scratch_shapes=...)``.
-
-    Uses ``pltpu.VMEM`` when the install has TPU Pallas; otherwise falls back
-    to the generic ANY-space ``pl.MemoryRef``, which the interpreter accepts —
-    so kernels carrying accumulators in scratch still run (interpret mode) on
-    installs without the TPU plugin instead of dereferencing a None module.
-    """
-    pltpu = import_pallas_tpu()
-    if pltpu is not None:
-        return pltpu.VMEM(tuple(shape), dtype)
-    pl = import_pallas()
-    return pl.MemoryRef(tuple(shape), dtype, pl.MemorySpace.ANY)
+    """A VMEM scratch allocation for ``pallas_call`` scratch shapes."""
+    return _pltpu.VMEM(tuple(shape), dtype)
 
 
 # ---------------------------------------------------------------------------
-# Tree utilities (jax.tree.* newer spelling, jax.tree_util fallback)
+# Tree utilities
 # ---------------------------------------------------------------------------
-_tree_ns = getattr(jax, "tree", None)
+tree_map = jax.tree.map
+tree_leaves = jax.tree.leaves
+tree_flatten = jax.tree.flatten
+tree_unflatten = jax.tree.unflatten
+tree_structure = jax.tree.structure
+tree_reduce = jax.tree.reduce
+tree_all = jax.tree.all
 
 
-def _tree(fn_new: str, fn_old: str):
-    fn = getattr(_tree_ns, fn_new, None) if _tree_ns is not None else None
-    return fn if fn is not None else getattr(jax.tree_util, fn_old)
-
-
-tree_map = _tree("map", "tree_map")
-tree_leaves = _tree("leaves", "tree_leaves")
-tree_flatten = _tree("flatten", "tree_flatten")
-tree_unflatten = _tree("unflatten", "tree_unflatten")
-tree_structure = _tree("structure", "tree_structure")
-tree_reduce = _tree("reduce", "tree_reduce")
-tree_all = _tree("all", "tree_all")
-
-
-# ---------------------------------------------------------------------------
-# Diagnostics
-# ---------------------------------------------------------------------------
 def describe_support() -> str:
-    """One-line banner of which implementation paths this install selected."""
-    return (
-        f"repro.compat: jax {jax.__version__} | "
-        f"shard_map={'jax.shard_map' if HAS_NATIVE_SHARD_MAP else 'jax.experimental.shard_map'} | "
-        f"AxisType={'native' if HAS_NATIVE_AXIS_TYPE else 'stub'} | "
-        f"make_mesh={'native' if HAS_NATIVE_MAKE_MESH else 'mesh_utils'} | "
-        f"partial-manual={'native' if HAS_PARTIAL_MANUAL_SHARD_MAP else 'promoted-to-full'} | "
-        f"diff-barrier={'native' if HAS_DIFFERENTIABLE_BARRIER else 'custom_vjp'} | "
-        f"manual-axis detection={'native+shim' if HAS_NATIVE_AXIS_TYPE else 'shim'}"
-    )
+    """One-line banner naming the JAX this process runs on."""
+    return (f"repro.compat: jax {jax.__version__} | default backend "
+            f"{jax.default_backend()} | mesh axes Auto by default")
+
+
+# the checkout root: src/repro/compat/jax_compat.py -> four levels up
+_CHECKOUT = os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.dirname(os.path.abspath(__file__)))))
+
+
+def enable_compilation_cache() -> str:
+    """Turn on JAX's persistent compilation cache; returns its directory.
+
+    Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX already reads it and
+    that directory stays in charge. Otherwise the cache lives at the fixed
+    path ``<checkout>/.jax_cache``: the path is part of the cache key, so a
+    directory that moved between runs would never hit. Entry points call
+    this (never an import), so tests and library users keep JAX's default.
+    """
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    path = os.path.join(_CHECKOUT, ".jax_cache")
+    jax.config.update("jax_compilation_cache_dir", path)
+    return path
+

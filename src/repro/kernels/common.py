@@ -31,11 +31,18 @@ def pad_positions(pos: jax.Array, to: int) -> jax.Array:
     return jnp.pad(pos, pads, constant_values=-1)
 
 
+def on_tpu() -> bool:
+    """Whether JAX's default backend is the TPU."""
+    return jax.default_backend() == "tpu"
+
+
 def use_interpret() -> bool:
-    """Pallas interpret mode: on unless running on a real TPU."""
-    if os.environ.get("REPRO_PALLAS_INTERPRET"):
-        return os.environ["REPRO_PALLAS_INTERPRET"] != "0"
-    return jax.default_backend() != "tpu"
+    """Pallas interpret mode: never on the TPU, whatever the environment
+    says; elsewhere on unless ``REPRO_PALLAS_INTERPRET=0`` (a switch for
+    CPU tests only)."""
+    if on_tpu():
+        return False
+    return os.environ.get("REPRO_PALLAS_INTERPRET", "1") != "0"
 
 
 def kv_quant_mode() -> str:
@@ -156,14 +163,14 @@ def attn_impl() -> str:
     """Attention backend for ``chunked_attention``: 'pallas' or 'jnp'.
 
     ``REPRO_ATTN_IMPL=pallas|jnp|auto`` (default auto = compiled Pallas on
-    TPU, jnp elsewhere). ``pallas`` off-TPU runs in interpret mode unless
-    ``REPRO_PALLAS_INTERPRET=0``. Read at trace time: set the knob before
-    building jitted programs (the launchers plumb ``--attn-impl`` here).
+    TPU, jnp elsewhere). ``pallas`` off-TPU runs in interpret mode. Read at
+    trace time: set the knob before building jitted programs (the launchers
+    plumb ``--attn-impl`` here).
     """
     v = os.environ.get("REPRO_ATTN_IMPL", "auto").lower()
     if v not in ATTN_IMPLS:
         raise ValueError(
             f"REPRO_ATTN_IMPL={v!r}: expected one of {ATTN_IMPLS}")
     if v == "auto":
-        return "pallas" if jax.default_backend() == "tpu" else "jnp"
+        return "pallas" if on_tpu() else "jnp"
     return v

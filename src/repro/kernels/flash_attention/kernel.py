@@ -5,7 +5,7 @@ lives only in VMEM; scores never round-trip to HBM (the jnp chunked path
 materializes them — this kernel removes the dominant memory-term contribution
 found by DAMOV for train/prefill cells, and the KV-stream term for decode).
 
-Two entry points share one tile-update body:
+One kernel body serves five entry points:
 
 * ``flash_attention_fwd`` — prefill/train. Grid ``(B, Hkv, nq, nk)``, kv
   minor => sequential on TPU; the ``G = Hq // Hkv`` grouped query heads of
@@ -14,26 +14,38 @@ Two entry points share one tile-update body:
   ``jnp.repeat`` copies). Emits ``(out, lse)`` so a recompute backward can
   run without saved score tiles.
 * ``flash_decode_fwd`` — serving. Small q (the fused-decode chunk step)
-  against the ring KV cache; grid ``(B, Hkv, nk)`` over kv blocks only, the
-  whole (G, S) query block resident in VMEM across the kv stream.
+  against the ring KV cache; one q block, the kv stream on the grid.
 * ``flash_decode_quant_fwd`` — serving over a Proteus-quantized KV cache:
   the kv BlockSpecs carry int8 (or nibble-packed int4) codes + per-row fp32
-  scales and dequantize per tile in VMEM, cutting the dominant decode HBM
+  scales, dequantized per tile in VMEM, cutting the dominant decode HBM
   stream ~2x/~4x.
+* ``flash_decode_paged_fwd`` / ``flash_decode_paged_quant_fwd`` — the same
+  over a paged pool: a scalar-prefetched page table resolves logical page ->
+  pool row inside the kv index maps, so the kernel streams exactly the pages
+  a slot owns, with no dense gather in HBM.
 
-Fully-masked kv tiles (max position sentinel == -1: dead ring slots, pad
-tiles) are skipped inside every kernel — the block-sparse analogue of the
-jnp path's ``attn_block_skip``.
+Tiles are 2-D inside the kernel: a q block is ``R = block_q * G`` rows
+(position-major, the G heads of one position adjacent), a kv block is
+``bk`` rows. Every block's last two dims are either the array's own or
+(8, 128)-divisible, which is what the TPU compiler accepts:
 
-Masking is position-based everywhere: per-row absolute q positions
-``(B, S)`` and per-slot kv positions ``(B, T)`` (-1 = empty/invalid slot)
-subsume causal/window/ring-cache/valid-length and pad-to-block masking in
-one rule, so both kernels serve every model family and the serving engine.
+* q positions travel as a ``(B, S*G, 1)`` column, kv positions as
+  ``(..., 1, bk)`` rows, so the mask is a plain ``(R, 1) x (1, bk)``
+  broadcast;
+* quant scales travel as ``(..., 1, bk)`` rows and scale the score and
+  probability columns (``q.(c*s) = (q.c)*s``), never needing a transpose.
+
+Masking is position-based everywhere: per-row absolute q positions and
+per-slot kv positions (-1 = empty/invalid slot) subsume causal/window/
+ring-cache/valid-length and pad-to-block masking in one rule. Fully-masked
+kv tiles (max position == -1: dead ring slots, pad tiles, trash pages) are
+skipped inside the kernel.
 """
 from __future__ import annotations
 
 import functools
 import math
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -47,80 +59,209 @@ pl = import_pallas()
 NEG_INF = -1e30
 
 
-def _tile_update(q, k, v, qp, kp, m_ref, l_ref, acc_ref, *, scale: float,
-                 causal: bool, window: int, softcap: float):
-    """One (G, bq) x (bk) online-softmax update.
+def _tile(ref) -> jax.Array:
+    """A block's contents with its leading unit dims dropped: 2-D tile."""
+    x = ref[...]
+    return x.reshape(x.shape[-2:])
 
-    q: (G, bq, D) f32   k/v: (bk, D) f32
-    qp: (bq,) int32 absolute q positions (-1 = padded row)
-    kp: (bk,) int32 absolute kv positions (-1 = empty/padded/invalid slot)
-    m/l: (G, bq) f32 scratch   acc: (G, bq, D) f32 scratch
+
+def _tile_update(q, k, v, qp, kp, m_ref, l_ref, acc_ref, *, scale: float,
+                 causal: bool, window: int, softcap: float, k_scale=None,
+                 v_scale=None):
+    """One (R q rows) x (bk kv rows) online-softmax update.
+
+    q: (R, D) f32   k/v: (bk, D) f32 (dequantized codes when scaled)
+    qp: (R, 1) int32 absolute q positions (-1 = padded row)
+    kp: (1, bk) int32 absolute kv positions (-1 = empty/padded/invalid slot)
+    k_scale/v_scale: (1, bk) f32 per-kv-row dequant scales, or None
+    m/l: (R, 1) f32 scratch   acc: (R, D) f32 scratch
     """
-    s = jax.lax.dot_general(q, k, (((2,), (1,)), ((), ())),
-                            preferred_element_type=jnp.float32) * scale
+    s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                            preferred_element_type=jnp.float32)
+    if k_scale is not None:
+        s = s * k_scale
+    s = s * scale
     if softcap > 0:
         s = softcap * jnp.tanh(s / softcap)
-    mask = (kp[None, :] >= 0) & (qp[:, None] >= 0)
+    mask = (kp >= 0) & (qp >= 0)                        # (R, bk)
     if causal:
-        mask &= kp[None, :] <= qp[:, None]
+        mask &= kp <= qp
     if window > 0:
-        mask &= kp[None, :] > qp[:, None] - window
-    mb = mask[None]                                    # (1, bq, bk)
-    s = jnp.where(mb, s, NEG_INF)
+        mask &= kp > qp - window
+    s = jnp.where(mask, s, NEG_INF)
     m_prev = m_ref[...]
-    m_new = jnp.maximum(m_prev, s.max(axis=2))
+    m_new = jnp.maximum(m_prev, s.max(axis=1, keepdims=True))
     # guard fully-masked rows: exp(NEG_INF - NEG_INF) would be 1
     safe_m = jnp.where(m_new <= NEG_INF / 2, 0.0, m_new)
-    p = jnp.exp(s - safe_m[..., None])
-    p = jnp.where(mb, p, 0.0)
+    p = jnp.where(mask, jnp.exp(s - safe_m), 0.0)
     alpha = jnp.where(m_prev <= NEG_INF / 2, 0.0, jnp.exp(m_prev - safe_m))
-    l_new = l_ref[...] * alpha + p.sum(axis=2)
-    acc_ref[...] = acc_ref[...] * alpha[..., None] + jax.lax.dot_general(
-        p, v, (((2,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+    l_ref[...] = l_ref[...] * alpha + p.sum(axis=1, keepdims=True)
+    if v_scale is not None:
+        p = p * v_scale
+    acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
+        p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
     m_ref[...] = m_new
-    l_ref[...] = l_new
 
 
-def _tile_init(m_ref, l_ref, acc_ref):
-    m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-    l_ref[...] = jnp.zeros_like(l_ref)
-    acc_ref[...] = jnp.zeros_like(acc_ref)
+def _codes(ref, head_dim: int) -> jax.Array:
+    """A code tile as f32: int8 codes, or nibble-packed int4 (half width)
+    unpacked in registers — HBM only ever saw the packed bytes."""
+    c = _tile(ref)
+    if c.shape[-1] != head_dim:
+        c = unpack_int4(c)
+    return c.astype(jnp.float32)
 
 
-def _tile_finalize(o_ref, lse_ref, m_ref, l_ref, acc_ref):
-    l = l_ref[...]
-    lsafe = jnp.where(l == 0.0, 1.0, l)
-    o_ref[0, 0] = (acc_ref[...] / lsafe[..., None]).astype(o_ref.dtype)
-    m = m_ref[...]
-    lse_ref[0, 0] = jnp.where(m <= NEG_INF / 2, NEG_INF, m + jnp.log(lsafe))
-
-
-def _flash_kernel(qp_ref, kp_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
-                  m_ref, l_ref, acc_ref, *, scale: float, causal: bool,
-                  window: int, softcap: float, kv_axis: int, n_kv: int):
-    ki = pl.program_id(kv_axis)
+def _flash_kernel(*refs, scale: float, causal: bool, window: int,
+                  softcap: float, n_kv: int, head_dim: int, quant: bool,
+                  paged: bool, with_lse: bool):
+    refs = list(refs[1:] if paged else refs)  # table: read by the index maps
+    qp_ref, kp_ref, q_ref = refs[:3]
+    if quant:
+        kc_ref, ks_ref, vc_ref, vs_ref = refs[3:7]
+        refs = refs[7:]
+    else:
+        k_ref, v_ref = refs[3:5]
+        refs = refs[5:]
+    o_ref = refs[0]
+    lse_ref = refs[1] if with_lse else None
+    m_ref, l_ref, acc_ref = refs[-3:]
+    ki = pl.program_id(3)
 
     @pl.when(ki == 0)
     def _init():
-        _tile_init(m_ref, l_ref, acc_ref)
+        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
+        l_ref[...] = jnp.zeros_like(l_ref)
+        acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    kp = kp_ref[0]
+    kp = _tile(kp_ref)
 
-    # block-sparse kv-tile skip: the -1 sentinel marks empty/invalid slots,
-    # so a tile whose max position is -1 is fully masked (dead ring slots,
-    # pad-to-block tiles) and contributes nothing — skip the dot/exp work.
+    # block-sparse kv-tile skip: a tile whose max position is -1 is fully
+    # masked (dead ring slots, pad-to-block tiles, trash pages)
     @pl.when(jnp.max(kp) >= 0)
     def _update():
-        _tile_update(q_ref[0, 0].astype(jnp.float32),
-                     k_ref[0, 0].astype(jnp.float32),
-                     v_ref[0, 0].astype(jnp.float32),
-                     qp_ref[0], kp, m_ref, l_ref, acc_ref,
-                     scale=scale, causal=causal, window=window,
-                     softcap=softcap)
+        if quant:
+            k, v = _codes(kc_ref, head_dim), _codes(vc_ref, head_dim)
+            ks, vs = _tile(ks_ref), _tile(vs_ref)
+        else:
+            k = _tile(k_ref).astype(jnp.float32)
+            v = _tile(v_ref).astype(jnp.float32)
+            ks = vs = None
+        _tile_update(_tile(q_ref).astype(jnp.float32), k, v, _tile(qp_ref),
+                     kp, m_ref, l_ref, acc_ref, scale=scale, causal=causal,
+                     window=window, softcap=softcap, k_scale=ks, v_scale=vs)
 
     @pl.when(ki == n_kv - 1)
     def _finalize():
-        _tile_finalize(o_ref, lse_ref, m_ref, l_ref, acc_ref)
+        l = l_ref[...]
+        lsafe = jnp.where(l == 0.0, 1.0, l)
+        o_ref[...] = (acc_ref[...] / lsafe).astype(o_ref.dtype).reshape(
+            o_ref.shape)
+        if lse_ref is not None:
+            m = m_ref[...]
+            lse_ref[...] = jnp.where(m <= NEG_INF / 2, NEG_INF,
+                                     m + jnp.log(lsafe)).reshape(lse_ref.shape)
+
+
+def _flash_call(q: jax.Array, k: jax.Array, v: jax.Array,
+                q_positions: jax.Array, kv_positions: jax.Array, *,
+                k_scale: Optional[jax.Array] = None,
+                v_scale: Optional[jax.Array] = None,
+                table: Optional[jax.Array] = None, block_q: int,
+                block_k: int, with_lse: bool, causal: bool, window: int,
+                softcap: float, interpret: bool):
+    """Shared launcher. q: (B, Hkv, S, G, D); q_positions: (B, S).
+
+    Contiguous kv: k/v (B, Hkv, T, Dc), scales (B, Hkv, T), kv_positions
+    (B, T), ``block_k | T``. Paged kv (``table`` (B, NP)): k/v pools
+    (P, Hkv, ps, Dc), scale pools (P, Hkv, ps), kv_positions (B, NP * ps);
+    one page per kv step. Returns out (B, Hkv, S, G, D) and, with
+    ``with_lse``, lse (B, Hkv, S, G) f32.
+    """
+    B, Hkv, S, G, D = q.shape
+    quant = k_scale is not None
+    paged = table is not None
+    Dc = k.shape[-1]
+    if Dc != D and not interpret:
+        # the in-register nibble unpack interleaves lanes, which Mosaic
+        # refuses ("unsupported shape cast"); int8 and auto compile
+        raise NotImplementedError(
+            "the int4 KV-cache decode kernel does not compile for the TPU; "
+            "use REPRO_KV_QUANT=int8 or auto there")
+    if paged:
+        bk = k.shape[2]
+        nk = table.shape[1]
+    else:
+        bk = block_k
+        assert k.shape[2] % bk == 0, (k.shape, bk)
+        nk = k.shape[2] // bk
+    assert S % block_q == 0, (S, block_q)
+    nq, R = S // block_q, block_q * G
+
+    # index maps see (b, h, i, j) plus the prefetched table when paged
+    def rows(b, h, i, j, *_):
+        return (b, h, i, 0)
+
+    if paged:
+        def kv_blk(b, h, i, j, tab):
+            return (tab[b, j], h, 0, 0)
+        sc_blk, sc_shape = kv_blk, (1, 1, 1, bk)
+        scales = [s[:, :, None, :] for s in (k_scale, v_scale)] \
+            if quant else []
+    else:
+        def kv_blk(b, h, i, j, *_):
+            return (b, h, j, 0)
+
+        def sc_blk(b, h, i, j, *_):
+            return (b, h, j, 0, 0)
+        sc_shape = (1, 1, 1, 1, bk)
+        scales = [s.reshape(B, Hkv, nk, 1, bk) for s in (k_scale, v_scale)] \
+            if quant else []
+
+    in_specs = [
+        pl.BlockSpec((1, R, 1), lambda b, h, i, j, *_: (b, i, 0)),
+        pl.BlockSpec((1, 1, 1, bk), lambda b, h, i, j, *_: (b, j, 0, 0)),
+        pl.BlockSpec((1, 1, R, D), rows),
+    ]
+    kv_spec = pl.BlockSpec((1, 1, bk, Dc), kv_blk)
+    operands = [jnp.repeat(q_positions, G, axis=1)[..., None],
+                kv_positions.reshape(B, nk, 1, bk),
+                q.reshape(B, Hkv, S * G, D)]
+    if quant:
+        sc_spec = pl.BlockSpec(sc_shape, sc_blk)
+        in_specs += [kv_spec, sc_spec, kv_spec, sc_spec]
+        operands += [k, scales[0], v, scales[1]]
+    else:
+        in_specs += [kv_spec, kv_spec]
+        operands += [k, v]
+    out_specs = [pl.BlockSpec((1, 1, R, D), rows)]
+    out_shape = [jax.ShapeDtypeStruct((B, Hkv, S * G, D), q.dtype)]
+    if with_lse:
+        out_specs.append(pl.BlockSpec((1, 1, R, 1), rows))
+        out_shape.append(jax.ShapeDtypeStruct((B, Hkv, S * G, 1),
+                                              jnp.float32))
+    kernel = functools.partial(
+        _flash_kernel, scale=1.0 / math.sqrt(D), causal=causal,
+        window=window, softcap=softcap, n_kv=nk, head_dim=D, quant=quant,
+        paged=paged, with_lse=with_lse)
+    grid_spec = pallas_prefetch_grid_spec()(
+        num_scalar_prefetch=1 if paged else 0,
+        grid=(B, Hkv, nq, nk),
+        in_specs=in_specs,
+        out_specs=out_specs,
+        scratch_shapes=[
+            pallas_vmem_scratch((R, 1), jnp.float32),
+            pallas_vmem_scratch((R, 1), jnp.float32),
+            pallas_vmem_scratch((R, D), jnp.float32),
+        ],
+    )
+    outs = pl.pallas_call(kernel, grid_spec=grid_spec, out_shape=out_shape,
+                          interpret=interpret)(
+        *([table] if paged else []), *operands)
+    out = outs[0].reshape(B, Hkv, S, G, D)
+    if with_lse:
+        return out, outs[1].reshape(B, Hkv, S, G)
+    return out
 
 
 def flash_attention_fwd(q: jax.Array, k: jax.Array, v: jax.Array,
@@ -130,45 +271,15 @@ def flash_attention_fwd(q: jax.Array, k: jax.Array, v: jax.Array,
                         block_k: int = 128, interpret: bool = True):
     """Prefill/train kernel. Shapes (already padded to block multiples):
 
-    q: (B, Hkv, G, S, D)   k/v: (B, Hkv, T, D)
+    q: (B, Hkv, S, G, D)   k/v: (B, Hkv, T, D)
     q_positions: (B, S) int32   kv_positions: (B, T) int32 (-1 = masked)
-    Returns (out (B, Hkv, G, S, D), lse (B, Hkv, G, S) f32).
+    Returns (out (B, Hkv, S, G, D), lse (B, Hkv, S, G) f32).
     """
-    B, Hkv, G, S, D = q.shape
-    T = k.shape[2]
-    bq = min(block_q, S)
-    bk = min(block_k, T)
-    assert S % bq == 0 and T % bk == 0, (S, bq, T, bk)
-    nq, nk = S // bq, T // bk
-    kernel = functools.partial(
-        _flash_kernel, scale=1.0 / math.sqrt(D), causal=causal, window=window,
-        softcap=softcap, kv_axis=3, n_kv=nk)
-    out, lse = pl.pallas_call(
-        kernel,
-        grid=(B, Hkv, nq, nk),
-        in_specs=[
-            pl.BlockSpec((1, bq), lambda b, h, i, j: (b, i)),
-            pl.BlockSpec((1, bk), lambda b, h, i, j: (b, j)),
-            pl.BlockSpec((1, 1, G, bq, D), lambda b, h, i, j: (b, h, 0, i, 0)),
-            pl.BlockSpec((1, 1, bk, D), lambda b, h, i, j: (b, h, j, 0)),
-            pl.BlockSpec((1, 1, bk, D), lambda b, h, i, j: (b, h, j, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, 1, G, bq, D), lambda b, h, i, j: (b, h, 0, i, 0)),
-            pl.BlockSpec((1, 1, G, bq), lambda b, h, i, j: (b, h, 0, i)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((B, Hkv, G, S, D), q.dtype),
-            jax.ShapeDtypeStruct((B, Hkv, G, S), jnp.float32),
-        ],
-        scratch_shapes=[
-            pallas_vmem_scratch((G, bq), jnp.float32),
-            pallas_vmem_scratch((G, bq), jnp.float32),
-            pallas_vmem_scratch((G, bq, D), jnp.float32),
-        ],
-        interpret=interpret,
-    )(q_positions, kv_positions, q, k, v)
-    return out, lse
+    S, T = q.shape[2], k.shape[2]
+    return _flash_call(q, k, v, q_positions, kv_positions,
+                       block_q=min(block_q, S), block_k=min(block_k, T),
+                       with_lse=True, causal=causal, window=window,
+                       softcap=softcap, interpret=interpret)
 
 
 def flash_decode_fwd(q: jax.Array, k: jax.Array, v: jax.Array,
@@ -179,84 +290,17 @@ def flash_decode_fwd(q: jax.Array, k: jax.Array, v: jax.Array,
     """Decode kernel: the serving engine's per-chunk inner loop.
 
     The whole small-q block (one scan step of the fused decode loop) stays in
-    VMEM while the ring KV cache streams through; grid over kv blocks only.
+    VMEM while the ring KV cache streams through.
 
-    q: (B, Hkv, G, S, D) with small S   k/v: (B, Hkv, T, D), T % block_k == 0
+    q: (B, Hkv, S, G, D) with small S   k/v: (B, Hkv, T, D), T % block_k == 0
     q_positions: (B, S) per-sequence positions (continuous batching)
     kv_positions: (B, T) per-slot ring-cache positions (-1 = empty slot)
-    Returns out (B, Hkv, G, S, D).
+    Returns out (B, Hkv, S, G, D).
     """
-    B, Hkv, G, S, D = q.shape
-    T = k.shape[2]
-    bk = min(block_k, T)
-    assert T % bk == 0, (T, bk)
-    nk = T // bk
-    kernel = functools.partial(
-        _flash_kernel, scale=1.0 / math.sqrt(D), causal=causal, window=window,
-        softcap=softcap, kv_axis=2, n_kv=nk)
-    out, _ = pl.pallas_call(
-        kernel,
-        grid=(B, Hkv, nk),
-        in_specs=[
-            pl.BlockSpec((1, S), lambda b, h, j: (b, 0)),
-            pl.BlockSpec((1, bk), lambda b, h, j: (b, j)),
-            pl.BlockSpec((1, 1, G, S, D), lambda b, h, j: (b, h, 0, 0, 0)),
-            pl.BlockSpec((1, 1, bk, D), lambda b, h, j: (b, h, j, 0)),
-            pl.BlockSpec((1, 1, bk, D), lambda b, h, j: (b, h, j, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, 1, G, S, D), lambda b, h, j: (b, h, 0, 0, 0)),
-            pl.BlockSpec((1, 1, G, S), lambda b, h, j: (b, h, 0, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((B, Hkv, G, S, D), q.dtype),
-            jax.ShapeDtypeStruct((B, Hkv, G, S), jnp.float32),
-        ],
-        scratch_shapes=[
-            pallas_vmem_scratch((G, S), jnp.float32),
-            pallas_vmem_scratch((G, S), jnp.float32),
-            pallas_vmem_scratch((G, S, D), jnp.float32),
-        ],
-        interpret=interpret,
-    )(q_positions, kv_positions, q, k, v)
-    return out
-
-
-def _dequant_rows(codes: jax.Array, scale: jax.Array, d: int) -> jax.Array:
-    """Dequantize one kv tile in VMEM: codes (bk, Dc) int8 + per-row scales
-    (bk,) fp32 -> (bk, d) fp32. Dc == d//2 means nibble-packed int4 codes
-    (unpacked in registers via the shared helper — HBM only ever saw the
-    packed bytes)."""
-    if codes.shape[-1] != d:
-        assert codes.shape[-1] * 2 == d, (codes.shape, d)
-        codes = unpack_int4(codes)
-    return codes.astype(jnp.float32) * scale[:, None]
-
-
-def _flash_decode_quant_kernel(qp_ref, kp_ref, q_ref, kq_ref, ks_ref, vq_ref,
-                               vs_ref, o_ref, lse_ref, m_ref, l_ref, acc_ref,
-                               *, scale: float, causal: bool, window: int,
-                               softcap: float, n_kv: int, head_dim: int):
-    ki = pl.program_id(2)
-
-    @pl.when(ki == 0)
-    def _init():
-        _tile_init(m_ref, l_ref, acc_ref)
-
-    kp = kp_ref[0]
-
-    @pl.when(jnp.max(kp) >= 0)          # block-sparse skip of dead kv tiles
-    def _update():
-        k = _dequant_rows(kq_ref[0, 0], ks_ref[0, 0], head_dim)
-        v = _dequant_rows(vq_ref[0, 0], vs_ref[0, 0], head_dim)
-        _tile_update(q_ref[0, 0].astype(jnp.float32), k, v,
-                     qp_ref[0], kp, m_ref, l_ref, acc_ref,
-                     scale=scale, causal=causal, window=window,
-                     softcap=softcap)
-
-    @pl.when(ki == n_kv - 1)
-    def _finalize():
-        _tile_finalize(o_ref, lse_ref, m_ref, l_ref, acc_ref)
+    return _flash_call(q, k, v, q_positions, kv_positions,
+                       block_q=q.shape[2], block_k=min(block_k, k.shape[2]),
+                       with_lse=False, causal=causal, window=window,
+                       softcap=softcap, interpret=interpret)
 
 
 def flash_decode_quant_fwd(q: jax.Array, k_codes: jax.Array,
@@ -273,87 +317,15 @@ def flash_decode_quant_fwd(q: jax.Array, k_codes: jax.Array,
     fp32 scales, and dequantized per tile **in VMEM**: HBM traffic drops
     ~2x (int8) / ~4x (int4) vs the bf16 cache while the math runs fp32.
 
-    q: (B, Hkv, G, S, D)   k/v codes: (B, Hkv, T, Dc) int8
+    q: (B, Hkv, S, G, D)   k/v codes: (B, Hkv, T, Dc) int8
     k/v scale: (B, Hkv, T) fp32      q_positions: (B, S) int32
-    kv_positions: (B, T) int32 (-1 = empty slot)  ->  out (B, Hkv, G, S, D).
+    kv_positions: (B, T) int32 (-1 = empty slot)  ->  out (B, Hkv, S, G, D).
     """
-    B, Hkv, G, S, D = q.shape
-    T = k_codes.shape[2]
-    Dc = k_codes.shape[3]
-    bk = min(block_k, T)
-    assert T % bk == 0, (T, bk)
-    nk = T // bk
-    kernel = functools.partial(
-        _flash_decode_quant_kernel, scale=1.0 / math.sqrt(D), causal=causal,
-        window=window, softcap=softcap, n_kv=nk, head_dim=D)
-    out, _ = pl.pallas_call(
-        kernel,
-        grid=(B, Hkv, nk),
-        in_specs=[
-            pl.BlockSpec((1, S), lambda b, h, j: (b, 0)),
-            pl.BlockSpec((1, bk), lambda b, h, j: (b, j)),
-            pl.BlockSpec((1, 1, G, S, D), lambda b, h, j: (b, h, 0, 0, 0)),
-            pl.BlockSpec((1, 1, bk, Dc), lambda b, h, j: (b, h, j, 0)),
-            pl.BlockSpec((1, 1, bk), lambda b, h, j: (b, h, j)),
-            pl.BlockSpec((1, 1, bk, Dc), lambda b, h, j: (b, h, j, 0)),
-            pl.BlockSpec((1, 1, bk), lambda b, h, j: (b, h, j)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, 1, G, S, D), lambda b, h, j: (b, h, 0, 0, 0)),
-            pl.BlockSpec((1, 1, G, S), lambda b, h, j: (b, h, 0, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((B, Hkv, G, S, D), q.dtype),
-            jax.ShapeDtypeStruct((B, Hkv, G, S), jnp.float32),
-        ],
-        scratch_shapes=[
-            pallas_vmem_scratch((G, S), jnp.float32),
-            pallas_vmem_scratch((G, S), jnp.float32),
-            pallas_vmem_scratch((G, S, D), jnp.float32),
-        ],
-        interpret=interpret,
-    )(q_positions, kv_positions, q, k_codes, k_scale, v_codes, v_scale)
-    return out
-
-
-# ---------------------------------------------------------------------------
-# Paged decode kernels (block-table KV cache)
-#
-# Flash-decoding split-KV over *pages*: the grid runs over each slot's
-# logical pages and a scalar-prefetched page table resolves logical page ->
-# physical pool row inside the kv BlockSpec index maps, so the kernel streams
-# exactly the pages a slot owns straight from the shared pool — no gather
-# materializing a dense per-slot copy in HBM. The pool keeps its storage
-# layout (P, ps, H, D); only one (ps, D) page per kv head moves to VMEM per
-# grid step. Masking stays purely positional: the (B, NP*ps) kv_positions
-# carry the ring/pad/-1 sentinels, so trash-page tiles are either skipped
-# (all -1) or causally masked.
-# ---------------------------------------------------------------------------
-def _flash_decode_paged_kernel(tab_ref, qp_ref, kp_ref, q_ref, k_ref, v_ref,
-                               o_ref, lse_ref, m_ref, l_ref, acc_ref, *,
-                               scale: float, causal: bool, window: int,
-                               softcap: float, n_kv: int):
-    del tab_ref  # consumed by the BlockSpec index maps (scalar prefetch)
-    ki = pl.program_id(2)
-
-    @pl.when(ki == 0)
-    def _init():
-        _tile_init(m_ref, l_ref, acc_ref)
-
-    kp = kp_ref[0]
-
-    @pl.when(jnp.max(kp) >= 0)          # skip dead pages (trash / unwritten)
-    def _update():
-        _tile_update(q_ref[0, 0].astype(jnp.float32),
-                     k_ref[0, :, 0].astype(jnp.float32),
-                     v_ref[0, :, 0].astype(jnp.float32),
-                     qp_ref[0], kp, m_ref, l_ref, acc_ref,
-                     scale=scale, causal=causal, window=window,
-                     softcap=softcap)
-
-    @pl.when(ki == n_kv - 1)
-    def _finalize():
-        _tile_finalize(o_ref, lse_ref, m_ref, l_ref, acc_ref)
+    return _flash_call(q, k_codes, v_codes, q_positions, kv_positions,
+                       k_scale=k_scale, v_scale=v_scale, block_q=q.shape[2],
+                       block_k=min(block_k, k_codes.shape[2]),
+                       with_lse=False, causal=causal, window=window,
+                       softcap=softcap, interpret=interpret)
 
 
 def flash_decode_paged_fwd(q: jax.Array, k_pages: jax.Array,
@@ -362,82 +334,17 @@ def flash_decode_paged_fwd(q: jax.Array, k_pages: jax.Array,
                            causal: bool = True, window: int = 0,
                            softcap: float = 0.0,
                            interpret: bool = True) -> jax.Array:
-    """Paged decode kernel.
+    """Paged decode kernel: flash-decoding split-KV over *pages*.
 
-    q: (B, Hkv, G, S, D) with small S   k/v pool: (P, ps, Hkv, D)
+    q: (B, Hkv, S, G, D) with small S   k/v pool: (P, Hkv, ps, D)
     table: (B, NP) int32 physical pool rows (page 0 = trash page)
     q_positions: (B, S)   kv_positions: (B, NP * ps) (-1 = empty/invalid)
-    Returns out (B, Hkv, G, S, D).
+    Returns out (B, Hkv, S, G, D).
     """
-    B, Hkv, G, S, D = q.shape
-    P, ps, _, _ = k_pages.shape
-    NP = table.shape[1]
-    grid_spec_cls = pallas_prefetch_grid_spec()
-    assert grid_spec_cls is not None, (
-        "paged decode kernel needs scalar-prefetch grid specs; gate calls on "
-        "ops.paged_decode_supported()")
-    kernel = functools.partial(
-        _flash_decode_paged_kernel, scale=1.0 / math.sqrt(D), causal=causal,
-        window=window, softcap=softcap, n_kv=NP)
-    grid_spec = grid_spec_cls(
-        num_scalar_prefetch=1,
-        grid=(B, Hkv, NP),
-        in_specs=[
-            pl.BlockSpec((1, S), lambda b, h, j, tab: (b, 0)),
-            pl.BlockSpec((1, ps), lambda b, h, j, tab: (b, j)),
-            pl.BlockSpec((1, 1, G, S, D), lambda b, h, j, tab: (b, h, 0, 0, 0)),
-            pl.BlockSpec((1, ps, 1, D), lambda b, h, j, tab: (tab[b, j], 0, h, 0)),
-            pl.BlockSpec((1, ps, 1, D), lambda b, h, j, tab: (tab[b, j], 0, h, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, 1, G, S, D), lambda b, h, j, tab: (b, h, 0, 0, 0)),
-            pl.BlockSpec((1, 1, G, S), lambda b, h, j, tab: (b, h, 0, 0)),
-        ],
-        scratch_shapes=[
-            pallas_vmem_scratch((G, S), jnp.float32),
-            pallas_vmem_scratch((G, S), jnp.float32),
-            pallas_vmem_scratch((G, S, D), jnp.float32),
-        ],
-    )
-    out, _ = pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=[
-            jax.ShapeDtypeStruct((B, Hkv, G, S, D), q.dtype),
-            jax.ShapeDtypeStruct((B, Hkv, G, S), jnp.float32),
-        ],
-        interpret=interpret,
-    )(table, q_positions, kv_positions, q, k_pages, v_pages)
-    return out
-
-
-def _flash_decode_paged_quant_kernel(tab_ref, qp_ref, kp_ref, q_ref, kq_ref,
-                                     ks_ref, vq_ref, vs_ref, o_ref, lse_ref,
-                                     m_ref, l_ref, acc_ref, *, scale: float,
-                                     causal: bool, window: int,
-                                     softcap: float, n_kv: int,
-                                     head_dim: int):
-    del tab_ref  # consumed by the BlockSpec index maps (scalar prefetch)
-    ki = pl.program_id(2)
-
-    @pl.when(ki == 0)
-    def _init():
-        _tile_init(m_ref, l_ref, acc_ref)
-
-    kp = kp_ref[0]
-
-    @pl.when(jnp.max(kp) >= 0)          # skip dead pages (trash / unwritten)
-    def _update():
-        k = _dequant_rows(kq_ref[0, :, 0], ks_ref[0, :, 0], head_dim)
-        v = _dequant_rows(vq_ref[0, :, 0], vs_ref[0, :, 0], head_dim)
-        _tile_update(q_ref[0, 0].astype(jnp.float32), k, v,
-                     qp_ref[0], kp, m_ref, l_ref, acc_ref,
-                     scale=scale, causal=causal, window=window,
-                     softcap=softcap)
-
-    @pl.when(ki == n_kv - 1)
-    def _finalize():
-        _tile_finalize(o_ref, lse_ref, m_ref, l_ref, acc_ref)
+    return _flash_call(q, k_pages, v_pages, q_positions, kv_positions,
+                       table=table, block_q=q.shape[2], block_k=0,
+                       with_lse=False, causal=causal, window=window,
+                       softcap=softcap, interpret=interpret)
 
 
 def flash_decode_paged_quant_fwd(q: jax.Array, k_codes: jax.Array,
@@ -452,53 +359,16 @@ def flash_decode_paged_quant_fwd(q: jax.Array, k_codes: jax.Array,
     int4 code pages + per-row fp32 scale pages, dequantized per page in VMEM
     — the narrow-code HBM saving and the paged allocation saving compose.
 
-    q: (B, Hkv, G, S, D)   code pools: (P, ps, Hkv, Dc) int8
-    scale pools: (P, ps, Hkv) fp32   table: (B, NP) int32
+    q: (B, Hkv, S, G, D)   code pools: (P, Hkv, ps, Dc) int8
+    scale pools: (P, Hkv, ps) fp32   table: (B, NP) int32
     q_positions: (B, S)   kv_positions: (B, NP * ps)
-    Returns out (B, Hkv, G, S, D).
+    Returns out (B, Hkv, S, G, D).
     """
-    B, Hkv, G, S, D = q.shape
-    P, ps, _, Dc = k_codes.shape
-    NP = table.shape[1]
-    grid_spec_cls = pallas_prefetch_grid_spec()
-    assert grid_spec_cls is not None, (
-        "paged decode kernel needs scalar-prefetch grid specs; gate calls on "
-        "ops.paged_decode_supported()")
-    kernel = functools.partial(
-        _flash_decode_paged_quant_kernel, scale=1.0 / math.sqrt(D),
-        causal=causal, window=window, softcap=softcap, n_kv=NP, head_dim=D)
-    grid_spec = grid_spec_cls(
-        num_scalar_prefetch=1,
-        grid=(B, Hkv, NP),
-        in_specs=[
-            pl.BlockSpec((1, S), lambda b, h, j, tab: (b, 0)),
-            pl.BlockSpec((1, ps), lambda b, h, j, tab: (b, j)),
-            pl.BlockSpec((1, 1, G, S, D), lambda b, h, j, tab: (b, h, 0, 0, 0)),
-            pl.BlockSpec((1, ps, 1, Dc), lambda b, h, j, tab: (tab[b, j], 0, h, 0)),
-            pl.BlockSpec((1, ps, 1), lambda b, h, j, tab: (tab[b, j], 0, h)),
-            pl.BlockSpec((1, ps, 1, Dc), lambda b, h, j, tab: (tab[b, j], 0, h, 0)),
-            pl.BlockSpec((1, ps, 1), lambda b, h, j, tab: (tab[b, j], 0, h)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, 1, G, S, D), lambda b, h, j, tab: (b, h, 0, 0, 0)),
-            pl.BlockSpec((1, 1, G, S), lambda b, h, j, tab: (b, h, 0, 0)),
-        ],
-        scratch_shapes=[
-            pallas_vmem_scratch((G, S), jnp.float32),
-            pallas_vmem_scratch((G, S), jnp.float32),
-            pallas_vmem_scratch((G, S, D), jnp.float32),
-        ],
-    )
-    out, _ = pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=[
-            jax.ShapeDtypeStruct((B, Hkv, G, S, D), q.dtype),
-            jax.ShapeDtypeStruct((B, Hkv, G, S), jnp.float32),
-        ],
-        interpret=interpret,
-    )(table, q_positions, kv_positions, q, k_codes, k_scale, v_codes, v_scale)
-    return out
+    return _flash_call(q, k_codes, v_codes, q_positions, kv_positions,
+                       k_scale=k_scale, v_scale=v_scale, table=table,
+                       block_q=q.shape[2], block_k=0, with_lse=False,
+                       causal=causal, window=window, softcap=softcap,
+                       interpret=interpret)
 
 
 def flash_attention_bh(q: jax.Array, k: jax.Array, v: jax.Array, *,
@@ -519,9 +389,9 @@ def flash_attention_bh(q: jax.Array, k: jax.Array, v: jax.Array, *,
     q_pos = jnp.where(jnp.arange(Sp) < S, jnp.arange(Sp), -1).astype(jnp.int32)
     kv_pos = jnp.where(jnp.arange(Tp) < T, jnp.arange(Tp), -1).astype(jnp.int32)
     out, _ = flash_attention_fwd(
-        pad_axis(q, 1, Sp)[:, None, None], pad_axis(k, 1, Tp)[:, None],
+        pad_axis(q, 1, Sp)[:, None, :, None], pad_axis(k, 1, Tp)[:, None],
         pad_axis(v, 1, Tp)[:, None],
         jnp.tile(q_pos[None], (BH, 1)), jnp.tile(kv_pos[None], (BH, 1)),
         causal=causal, window=window, block_q=bq, block_k=bk,
         interpret=interpret)
-    return out[:, 0, 0, :S]
+    return out[:, 0, :S, 0]

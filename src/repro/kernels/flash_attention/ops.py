@@ -19,7 +19,6 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from repro.compat import pallas_prefetch_grid_spec
 from repro.kernels.common import pad_axis, pad_positions, use_interpret
 from repro.kernels.flash_attention.kernel import (flash_attention_bh,
                                                  flash_attention_fwd,
@@ -30,12 +29,23 @@ from repro.kernels.flash_attention.kernel import (flash_attention_bh,
 
 __all__ = ["flash_attention", "flash_attention_gqa_fwd", "flash_decode",
            "flash_decode_quant", "flash_decode_paged",
-           "flash_decode_paged_quant", "paged_decode_supported",
-           "flash_attention_bh"]
+           "flash_decode_paged_quant", "flash_attention_bh"]
 
 
 def _default_positions(B: int, n: int) -> jax.Array:
     return jnp.broadcast_to(jnp.arange(n, dtype=jnp.int32), (B, n))
+
+
+def _to_kernel(q: jax.Array, Hkv: int) -> jax.Array:
+    """(B, S, Hq, D) -> the kernels' (B, Hkv, S, G, D) grouped layout."""
+    B, S, Hq, D = q.shape
+    return q.reshape(B, S, Hkv, Hq // Hkv, D).transpose(0, 2, 1, 3, 4)
+
+
+def _from_kernel(out: jax.Array) -> jax.Array:
+    """(B, Hkv, S, G, D) -> (B, S, Hq, D)."""
+    B, Hkv, S, G, D = out.shape
+    return out.transpose(0, 2, 1, 3, 4).reshape(B, S, Hkv * G, D)
 
 
 def flash_attention_gqa_fwd(
@@ -53,25 +63,23 @@ def flash_attention_gqa_fwd(
     """
     if interpret is None:
         interpret = use_interpret()
-    B, S, Hq, D = q.shape
+    B, S, _, _ = q.shape
     _, T, Hkv, _ = k.shape
-    G = Hq // Hkv
     bq = min(block_q, S)
     bk = min(block_k, T)
     Sp = -(-S // bq) * bq
     Tp = -(-T // bk) * bk
     q_pos = _default_positions(B, S) if q_positions is None else q_positions
     kv_pos = _default_positions(B, T) if kv_positions is None else kv_positions
-    q5 = pad_axis(q, 1, Sp).reshape(B, Sp, Hkv, G, D).transpose(0, 2, 3, 1, 4)
-    k4 = pad_axis(k, 1, Tp).transpose(0, 2, 1, 3)
-    v4 = pad_axis(v, 1, Tp).transpose(0, 2, 1, 3)
     out5, lse = flash_attention_fwd(
-        q5, k4, v4, pad_positions(q_pos.astype(jnp.int32), Sp),
+        _to_kernel(pad_axis(q, 1, Sp), Hkv),
+        pad_axis(k, 1, Tp).transpose(0, 2, 1, 3),
+        pad_axis(v, 1, Tp).transpose(0, 2, 1, 3),
+        pad_positions(q_pos.astype(jnp.int32), Sp),
         pad_positions(kv_pos.astype(jnp.int32), Tp),
         causal=causal, window=window, softcap=softcap,
         block_q=bq, block_k=bk, interpret=interpret)
-    out = out5.transpose(0, 3, 1, 2, 4).reshape(B, Sp, Hq, D)
-    return out[:, :S], lse[..., :S]
+    return _from_kernel(out5)[:, :S], lse.transpose(0, 1, 3, 2)[..., :S]
 
 
 @partial(jax.jit, static_argnames=("causal", "window", "softcap", "block_q",
@@ -105,31 +113,17 @@ def flash_decode(q: jax.Array, k: jax.Array, v: jax.Array,
     """
     if interpret is None:
         interpret = use_interpret()
-    B, S, Hq, D = q.shape
     _, T, Hkv, _ = k.shape
-    G = Hq // Hkv
     bk = min(block_k, T)
     Tp = -(-T // bk) * bk
-    q5 = q.reshape(B, S, Hkv, G, D).transpose(0, 2, 3, 1, 4)
     out5 = flash_decode_fwd(
-        q5, pad_axis(k, 1, Tp).transpose(0, 2, 1, 3),
+        _to_kernel(q, Hkv), pad_axis(k, 1, Tp).transpose(0, 2, 1, 3),
         pad_axis(v, 1, Tp).transpose(0, 2, 1, 3),
         q_positions.astype(jnp.int32),
         pad_positions(kv_positions.astype(jnp.int32), Tp),
         causal=causal, window=window, softcap=softcap, block_k=bk,
         interpret=interpret)
-    return out5.transpose(0, 3, 1, 2, 4).reshape(B, S, Hq, D)
-
-
-def paged_decode_supported() -> bool:
-    """Whether the paged decode kernels can run on this JAX install.
-
-    The paged kernels resolve the page table inside BlockSpec index maps via
-    scalar prefetch, which needs ``pltpu.PrefetchScalarGridSpec`` (absent on
-    CPU-only builds without the TPU pallas module). Callers fall back to
-    ``paged_gather`` + the dense path when this is False.
-    """
-    return pallas_prefetch_grid_spec() is not None
+    return _from_kernel(out5)
 
 
 def flash_decode_paged(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
@@ -139,7 +133,7 @@ def flash_decode_paged(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
                        interpret: Optional[bool] = None) -> jax.Array:
     """Decode-step attention against a paged (block-table) KV cache.
 
-    q: (B, S, Hq, D) with small S; k/v_pages: (P, ps, Hkv, D) shared page
+    q: (B, S, Hq, D) with small S; k/v_pages: (P, Hkv, ps, D) shared page
     pool in storage layout (physical page 0 is the trash page); table:
     (B, NP) int32 mapping each slot's logical pages to pool rows;
     q_positions: (B, S); kv_positions: (B, NP * ps) per-slot positions
@@ -148,15 +142,12 @@ def flash_decode_paged(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
     """
     if interpret is None:
         interpret = use_interpret()
-    B, S, Hq, D = q.shape
-    Hkv = k_pages.shape[2]
-    G = Hq // Hkv
-    q5 = q.reshape(B, S, Hkv, G, D).transpose(0, 2, 3, 1, 4)
     out5 = flash_decode_paged_fwd(
-        q5, k_pages, v_pages, table.astype(jnp.int32),
-        q_positions.astype(jnp.int32), kv_positions.astype(jnp.int32),
+        _to_kernel(q, k_pages.shape[1]), k_pages, v_pages,
+        table.astype(jnp.int32), q_positions.astype(jnp.int32),
+        kv_positions.astype(jnp.int32),
         causal=causal, window=window, softcap=softcap, interpret=interpret)
-    return out5.transpose(0, 3, 1, 2, 4).reshape(B, S, Hq, D)
+    return _from_kernel(out5)
 
 
 def flash_decode_paged_quant(q: jax.Array, k_codes: jax.Array,
@@ -168,22 +159,19 @@ def flash_decode_paged_quant(q: jax.Array, k_codes: jax.Array,
                              interpret: Optional[bool] = None) -> jax.Array:
     """Decode-step attention against a Proteus-quantized paged KV cache.
 
-    q: (B, S, Hq, D); code pools: (P, ps, Hkv, Dc) int8 (Dc = D, or D//2
-    when nibble-packed int4); scale pools: (P, ps, Hkv) fp32; table /
+    q: (B, S, Hq, D); code pools: (P, Hkv, ps, Dc) int8 (Dc = D, or D//2
+    when nibble-packed int4); scale pools: (P, Hkv, ps) fp32; table /
     positions as in :func:`flash_decode_paged`. Dequantization happens per
     page in VMEM, so the quantized-HBM and paged-allocation savings compose.
     """
     if interpret is None:
         interpret = use_interpret()
-    B, S, Hq, D = q.shape
-    Hkv = k_codes.shape[2]
-    G = Hq // Hkv
-    q5 = q.reshape(B, S, Hkv, G, D).transpose(0, 2, 3, 1, 4)
     out5 = flash_decode_paged_quant_fwd(
-        q5, k_codes, k_scale, v_codes, v_scale, table.astype(jnp.int32),
-        q_positions.astype(jnp.int32), kv_positions.astype(jnp.int32),
+        _to_kernel(q, k_codes.shape[1]), k_codes, k_scale, v_codes, v_scale,
+        table.astype(jnp.int32), q_positions.astype(jnp.int32),
+        kv_positions.astype(jnp.int32),
         causal=causal, window=window, softcap=softcap, interpret=interpret)
-    return out5.transpose(0, 3, 1, 2, 4).reshape(B, S, Hq, D)
+    return _from_kernel(out5)
 
 
 def flash_decode_quant(q: jax.Array, k_codes: jax.Array, k_scale: jax.Array,
@@ -201,14 +189,11 @@ def flash_decode_quant(q: jax.Array, k_codes: jax.Array, k_scale: jax.Array,
     """
     if interpret is None:
         interpret = use_interpret()
-    B, S, Hq, D = q.shape
     _, T, Hkv, _ = k_codes.shape
-    G = Hq // Hkv
     bk = min(block_k, T)
     Tp = -(-T // bk) * bk
-    q5 = q.reshape(B, S, Hkv, G, D).transpose(0, 2, 3, 1, 4)
     out5 = flash_decode_quant_fwd(
-        q5,
+        _to_kernel(q, Hkv),
         pad_axis(k_codes, 1, Tp).transpose(0, 2, 1, 3),
         pad_axis(k_scale, 1, Tp).transpose(0, 2, 1),
         pad_axis(v_codes, 1, Tp).transpose(0, 2, 1, 3),
@@ -217,4 +202,4 @@ def flash_decode_quant(q: jax.Array, k_codes: jax.Array, k_scale: jax.Array,
         pad_positions(kv_positions.astype(jnp.int32), Tp),
         causal=causal, window=window, softcap=softcap, block_k=bk,
         interpret=interpret)
-    return out5.transpose(0, 3, 1, 2, 4).reshape(B, S, Hq, D)
+    return _from_kernel(out5)

@@ -18,7 +18,7 @@ import jax.numpy as jnp
 from repro.compat import import_pallas, import_pallas_tpu
 
 pl = import_pallas()
-pltpu = import_pallas_tpu()  # None when this install lacks TPU pallas
+pltpu = import_pallas_tpu()
 
 
 def _qmm_kernel(x_ref, wq_ref, scale_ref, o_ref, acc_ref, *, n_k_blocks: int):

@@ -15,7 +15,7 @@ import jax.numpy as jnp
 from repro.compat import import_pallas, import_pallas_tpu
 
 pl = import_pallas()
-pltpu = import_pallas_tpu()  # None when this install lacks TPU pallas
+pltpu = import_pallas_tpu()
 
 
 def _rglru_kernel(a_ref, b_ref, o_ref, h_ref, *, block_t: int):

@@ -6,7 +6,7 @@ any JAX import; tests and benches see the real (1-device) platform.
 """
 from __future__ import annotations
 
-from typing import Optional, Tuple
+from typing import Optional, Sequence, Tuple
 
 import jax
 from jax.sharding import Mesh
@@ -20,11 +20,14 @@ def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
     return make_mesh(shape, axes)
 
 
-def make_local_mesh(axes: Tuple[str, ...] = ("data",)) -> Mesh:
-    """Mesh over whatever devices exist (tests / CPU examples)."""
-    n = len(jax.devices())
-    shape = (n,) + (1,) * (len(axes) - 1)
-    return make_mesh(shape, axes)
+def make_local_mesh(axes: Tuple[str, ...] = ("data",),
+                    devices: Optional[Sequence] = None) -> Mesh:
+    """Mesh over ``devices`` (default: every local device), all of them on
+    the first axis. Passing ``jax.devices()[i:i + 1]`` pins an engine or a
+    trainer to one chip."""
+    devices = list(jax.devices() if devices is None else devices)
+    shape = (len(devices),) + (1,) * (len(axes) - 1)
+    return make_mesh(shape, axes, devices=devices)
 
 
 def describe(mesh: Mesh) -> str:

@@ -18,28 +18,39 @@ import argparse
 import os
 import signal
 import time
-from typing import Any, Callable, Dict, List, Optional
+from typing import Any, Callable, Dict, List, Optional, Sequence
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.compat import enable_compilation_cache
 from repro.configs import ALL_IDS, ShapeConfig, get_config
 from repro.core.mimdram import plan_sharding, use_plan
 from repro.distributed.chaos import ChaosConfig, ShardChaosConfig
 from repro.distributed.fault_tolerance import (PreemptionHandler,
                                                RestartManifest)
+from repro.kernels.common import on_tpu
 from repro.launch import mesh as mesh_lib
 from repro.launch.engine import Request, ServeEngine
 from repro.launch.fleet import ServeFleet
-from repro.launch.steps import (make_decode_step, make_serving_jits,
+from repro.launch.steps import (make_decode_step, make_serving_jits, named,
                                 sample_tokens, spec_config)
 from repro.models import build_model, init_params
+from repro.models.module import param_pspecs
 
 # env knobs captured into (and replayed from) a serving RestartManifest so a
 # restarted process traces the same cache layout / kernels / drafter
 _SERVE_ENV_KNOBS = ("REPRO_KV_PAGES", "REPRO_KV_QUANT", "REPRO_SPEC_DECODE",
                     "REPRO_SPEC_K", "REPRO_ATTN_IMPL")
+
+
+def serving_config(arch: str, smoke: bool):
+    """``arch``'s config with bf16 weights, as deployments serve them (the
+    planner sizes serving cells the same way). Every weight the model casts
+    to the compute dtype at use comes out bit-identical; norms and other
+    explicitly-f32 parameters stay f32."""
+    return get_config(arch, smoke=smoke).replace(param_dtype="bfloat16")
 
 
 def _clone(tree):
@@ -63,7 +74,7 @@ def serve(arch: str, *, smoke: bool = True, batch: int = 4,
     ``accepted_len_per_draft``.
     """
     assert engine in ("fused", "loop"), engine
-    cfg = get_config(arch, smoke=smoke)
+    cfg = serving_config(arch, smoke)
     max_len = prompt_len + gen
     shape = ShapeConfig("serve", seq_len=max_len, global_batch=batch,
                         mode="decode")
@@ -199,18 +210,23 @@ def make_queue_engine(arch: str, *, smoke: bool = True, slots: int = 4,
                       prompt_len: int = 32, gen: int = 16, chunk: int = 8,
                       seed: int = 0, temperature: float = 0.0, top_k: int = 0,
                       spec: Optional[str] = None, spec_k: Optional[int] = None,
+                      devices: Optional[Sequence[Any]] = None,
                       **engine_kwargs: Any) -> ServeEngine:
     """Build a fresh :class:`ServeEngine` for ``arch`` (shared by queue mode,
-    the chaos smokes, and checkpoint/restore). ``engine_kwargs`` forwards the
-    robustness knobs (``max_queue``, ``deadline_ms``, ``chaos``,
-    ``page_pool_pages``, ...)."""
-    cfg = get_config(arch, smoke=smoke)
-    mesh = mesh_lib.make_local_mesh(("data",))
+    the chaos smokes, and checkpoint/restore) on ``devices`` (default: every
+    local device; ``jax.devices()[i:i + 1]`` pins it to one chip), with its
+    params made on and placed over exactly those devices. ``engine_kwargs``
+    forwards the robustness knobs (``max_queue``, ``deadline_ms``,
+    ``chaos``, ``page_pool_pages``, ...)."""
+    cfg = serving_config(arch, smoke)
+    mesh = mesh_lib.make_local_mesh(("data",), devices)
     plan = plan_sharding(
         cfg, ShapeConfig("serve", prompt_len + gen, slots, "decode"), mesh)
     model = build_model(cfg)
-    with use_plan(plan):
-        params = init_params(model.param_specs(), jax.random.PRNGKey(seed))
+    specs = model.param_specs()
+    with use_plan(plan), jax.default_device(mesh.devices.flat[0]):
+        params = init_params(specs, jax.random.PRNGKey(seed))
+    params = jax.device_put(params, named(plan, param_pspecs(specs, plan)))
     return ServeEngine(model, params, plan, slots=slots, prompt_len=prompt_len,
                        max_new=gen, chunk=chunk, temperature=temperature,
                        top_k=top_k, seed=seed, spec=spec, spec_k=spec_k,
@@ -293,9 +309,17 @@ def make_fleet(arch: str, *, shards: int = 2, backend: str = "inproc",
     """Build a :class:`ServeFleet` of identical engine shards.
 
     Every shard gets the same arch/seed/knobs, so any shard decodes any
-    request byte-identically — the property failover replay rests on. The
-    ``mp`` backend additionally records the serving env knobs so spawned
-    workers trace the same cache layout / kernels / drafter."""
+    request byte-identically — the property failover replay rests on.
+    ``inproc`` shards are one-device replicas: shard ``i`` lives on local
+    device ``i mod n``. The ``mp`` backend additionally records the serving
+    env knobs so spawned workers trace the same cache layout / kernels /
+    drafter; it is the CPU stand-in for a multi-host fleet, and refuses a
+    TPU host, where every worker would contend for the same chips."""
+    if backend == "mp" and on_tpu():
+        raise ValueError(
+            "fleet backend 'mp' cannot run on a TPU host: a chip belongs to "
+            "one process, so spawned workers would contend for it; use "
+            "backend='inproc', which places one replica on each chip")
     ekw = dict(arch=arch, smoke=smoke, slots=slots, prompt_len=prompt_len,
                gen=gen, chunk=chunk, seed=seed, temperature=temperature,
                top_k=top_k, spec=spec, spec_k=spec_k, **engine_kwargs)
@@ -305,7 +329,9 @@ def make_fleet(arch: str, *, shards: int = 2, backend: str = "inproc",
                        "env": {k: os.environ[k] for k in _SERVE_ENV_KNOBS
                                if k in os.environ}}
     else:
-        factory = lambda sid: make_queue_engine(**ekw)  # noqa: E731
+        devs = jax.devices()
+        factory = lambda sid: make_queue_engine(  # noqa: E731
+            devices=[devs[sid % len(devs)]], **ekw)
     return ServeFleet(factory, shards=shards, backend=backend,
                       worker_spec=worker_spec, chaos=fleet_chaos,
                       checkpoint_every=checkpoint_every,
@@ -573,6 +599,7 @@ def main() -> None:
                     "RestartManifest under this directory")
     ap.add_argument("--full", dest="smoke", action="store_false", default=True)
     args = ap.parse_args()
+    enable_compilation_cache()
     if args.attn_impl:
         os.environ["REPRO_ATTN_IMPL"] = args.attn_impl
     if args.kv_quant:

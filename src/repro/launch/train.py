@@ -38,7 +38,7 @@ import os
 import tempfile
 import time
 import warnings
-from typing import Any, Dict, List, Optional
+from typing import Any, Dict, List, Optional, Sequence
 
 import jax
 import jax.numpy as jnp
@@ -86,19 +86,21 @@ def train(arch: str, *, smoke: bool = True, steps: int = 100,
           proteus: bool = False, chaos: Any = None,
           max_bad_steps: int = 8, spike_factor: float = 3.0,
           spike_warmup: int = 10,
-          preempt_after: Optional[int] = None) -> Dict[str, Any]:
+          preempt_after: Optional[int] = None,
+          devices: Optional[Sequence[Any]] = None) -> Dict[str, Any]:
     """One training attempt. ``chaos`` is a :class:`TrainChaosConfig` (a
     fresh monkey is built) or a :class:`TrainChaosMonkey` (shared across a
     supervisor's attempts, so fire-once faults stay fired). ``preempt_after``
     requests a clean preemption once the run first crosses that absolute
-    step; a resumed run past it never re-fires."""
+    step; a resumed run past it never re-fires. ``devices`` (default: every
+    local device) is the data-parallel mesh."""
     print(compat.describe_support())
     cfg = get_config(arch, smoke=smoke)
     run = run or RunConfig(total_steps=steps, microbatches=1)
     shape = ShapeConfig("custom", seq_len=seq, global_batch=batch,
                         mode="train")
 
-    mesh = mesh_lib.make_local_mesh(("data",)) if use_mesh else None
+    mesh = mesh_lib.make_local_mesh(("data",), devices) if use_mesh else None
     plan = plan_sharding(cfg, shape, mesh)
     model = build_model(cfg)
     optimizer = make_optimizer(cfg.optimizer, run)
@@ -452,6 +454,7 @@ def main() -> None:
                     "prefill+decode launched from this process (sets "
                     "REPRO_KV_QUANT; the train step itself has no KV cache)")
     args = ap.parse_args()
+    compat.enable_compilation_cache()
     if args.attn_impl:
         os.environ["REPRO_ATTN_IMPL"] = args.attn_impl
     if args.kv_quant:

@@ -30,22 +30,24 @@ from typing import Any, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from repro.core.mimdram import constrain
+from jax.sharding import PartitionSpec as P
+
+from repro.compat import in_manual_context, shard_map
+from repro.core.mimdram import constrain, current_plan
 from repro.core.proteus import required_bits_float
 from repro.kernels.common import (attn_impl, kv_page_size, kv_quant_mode,
-                                  pack_int4, pad_axis, pad_positions,
+                                  on_tpu, pack_int4, pad_axis, pad_positions,
                                   unpack_int4)
 from repro.kernels.flash_attention.ops import (flash_attention_gqa_fwd,
                                                flash_decode,
                                                flash_decode_paged,
                                                flash_decode_paged_quant,
-                                               flash_decode_quant,
-                                               paged_decode_supported)
+                                               flash_decode_quant)
 
 # Pallas decode kernel: the whole (G, S) query block stays VMEM-resident
 # across the kv stream, so the positional path only routes to it while the
 # q-block row count is small; beyond this, forced-pallas calls fall back to
-# the jnp path (with a trace-time warning).
+# the jnp path (with a trace-time warning) off the TPU and raise on it.
 PALLAS_DECODE_MAX_Q_ROWS = 1024
 
 NEG_INF = -1e30
@@ -282,7 +284,9 @@ TRASH_PAGE = 0
 @dataclass
 class PagedKVCache:
     """Paged KV-cache leaf: ``pages`` is the pool — a plain array
-    ``(..., P, ps, H, D)`` or a :class:`QKVCache` of pooled codes+scales —
+    ``(..., P, H, ps, D)`` or a :class:`QKVCache` of pooled codes
+    ``(..., P, H, ps, Dc)`` + scales ``(..., P, H, ps)`` — head-major, so a
+    page of one kv head is a contiguous ``(ps, D)`` tile for the kernel —
     and ``table`` int32 ``(..., B, NP)`` maps each slot's logical page to a
     physical pool index (0 = trash page). Leading ``...`` dims (layers /
     groups) are shared between pool and table so ``lax.scan`` over layers
@@ -305,7 +309,7 @@ class PagedKVCache:
 
     @property
     def page_size(self) -> int:
-        return self._pool.shape[-3]
+        return self._pool.shape[-2]
 
     @property
     def num_pages(self) -> int:
@@ -319,7 +323,7 @@ class PagedKVCache:
 
     @property
     def num_heads(self) -> int:
-        return self._pool.shape[-2]
+        return self._pool.shape[-3]
 
 
 def aligned_cache_len(n: int, page_size: Optional[int] = None) -> int:
@@ -349,8 +353,8 @@ def paged_from_ring(ring, page_size: Optional[int] = None,
     npg = T // ps
     q = ring if mode == "off" else kv_quantize(ring, mode)
 
-    def to_pool(x):                     # (B, T, ...) -> (1 + B*NP, ps, ...)
-        px = x.reshape((B * npg, ps) + x.shape[2:])
+    def to_pool(x):              # (B, T, H, ...) -> (1 + B*NP, H, ps, ...)
+        px = jnp.swapaxes(x.reshape((B * npg, ps) + x.shape[2:]), 1, 2)
         return jnp.concatenate([jnp.zeros_like(px[:1]), px])
 
     pages = (QKVCache(to_pool(q.codes), to_pool(q.scale))
@@ -366,8 +370,8 @@ def paged_gather(cache: "PagedKVCache"):
     B = table.shape[0]
 
     def g(pool):
-        x = pool[table]                              # (B, NP, ps, ...)
-        return x.reshape((B, -1) + pool.shape[2:])
+        x = jnp.swapaxes(pool[table], 2, 3)          # (B, NP, ps, H, ...)
+        return x.reshape((B, -1) + x.shape[3:])
 
     if isinstance(cache.pages, QKVCache):
         return QKVCache(g(cache.pages.codes), g(cache.pages.scale))
@@ -389,7 +393,7 @@ def kv_cache_init(shape: Tuple[int, ...], dtype,
             f"paged cache_len {T} not a multiple of page size {ps}; "
             "size caches via aligned_cache_len")
         npg = T // ps
-        pages = _kv_zeros(lead + (B * npg + 1, ps, H, D), dtype, mode)
+        pages = _kv_zeros(lead + (B * npg + 1, H, ps, D), dtype, mode)
         return PagedKVCache(pages, _identity_table(B, npg, lead))
     return _kv_zeros(shape, dtype, mode)
 
@@ -411,7 +415,7 @@ def kv_cache_axes(axes: Tuple, mode: Optional[str] = None,
         lead = tuple(axes[:-4])
         # pool has no batch axis (pages are shared across slots): replicate
         # it; the table keeps the slot axis.
-        pool = lead + ("cache_pages", "cache_page_seq") + tuple(axes[-2:])
+        pool = lead + ("cache_pages", axes[-2], "cache_page_seq", axes[-1])
         pages = pool if mode == "off" else QKVCache(pool, pool[:-1])
         return PagedKVCache(pages, lead + (axes[-4], "cache_pages"))
     if mode == "off":
@@ -435,7 +439,7 @@ def kv_cache_update(cache, new: jax.Array, slot: jax.Array,
                     mode: Optional[str] = None):
     """Ring/paged cache write: quantizes ``new`` (B, S, H, D) row-wise before
     the write when the cache is quantized; paged caches scatter each row into
-    ``pool[table[b, slot // ps], slot % ps]`` (rows whose table entry is the
+    ``pool[table[b, slot // ps], :, slot % ps]`` (rows whose table entry is the
     trash page collide there harmlessly). ``slot`` is (B,) for the per-token
     decode path (S == 1) or (B, S) for a speculative verify block."""
     if isinstance(cache, PagedKVCache):
@@ -446,16 +450,16 @@ def kv_cache_update(cache, new: jax.Array, slot: jax.Array,
             phys = cache.table[b, s // ps]           # (B,)
             off = s % ps
 
-            def wr(pool, x):                         # x: (B, 1, ...)
-                return pool.at[phys, off].set(x[:, 0].astype(pool.dtype))
+            def wr(pool, x):                         # x: (B, 1, H, ...)
+                return pool.at[phys, :, off].set(x[:, 0].astype(pool.dtype))
         else:
             if s.ndim == 1:
                 s = s[:, None]
             phys = cache.table[b[:, None], s // ps]  # (B, S)
             off = s % ps
 
-            def wr(pool, x):                         # x: (B, S, ...)
-                return pool.at[phys, off].set(x.astype(pool.dtype))
+            def wr(pool, x):                         # x: (B, S, H, ...)
+                return pool.at[phys, :, off].set(x.astype(pool.dtype))
 
         if isinstance(cache.pages, QKVCache):
             mode = kv_quant_mode() if mode is None else mode
@@ -523,6 +527,37 @@ def _attn_tile(qc, kc, vc, mask, m, l, acc, scale, cap):
     return m_new, l_new, acc_new
 
 
+def _require_kernel(what: str) -> None:
+    """On the TPU the Pallas kernels are the attention path: a call they
+    cannot take is an error there, never a silent swap to the jnp path."""
+    if on_tpu():
+        raise NotImplementedError(
+            f"chunked_attention: {what} exceeds PALLAS_DECODE_MAX_Q_ROWS="
+            f"{PALLAS_DECODE_MAX_Q_ROWS}; no Pallas kernel takes it on the TPU")
+
+
+def _per_batch_shard(kernel, *args, shared: Tuple[int, ...] = ()):
+    """``kernel(*args)``, run once per batch shard of the plan's mesh.
+
+    XLA cannot partition a compiled Pallas (Mosaic) kernel, so under a plan
+    whose mesh has several devices the call goes inside a ``shard_map``:
+    the leading (batch) axis of every arg and output is split over the mesh
+    axes the plan gives ``act_batch``, and the args at the ``shared``
+    indices (the paged pools, which have no batch axis) are replicated.
+    Every row of attention depends only on its own batch row, so the result
+    is the unsharded call's.
+    """
+    plan = current_plan()
+    if (plan is None or plan.mesh is None or plan.mesh.size == 1
+            or in_manual_context()):
+        return kernel(*args)
+    B = args[0].shape[0]
+    batch = P(plan.spec("act_batch", dims=(B,))[0])
+    specs = tuple(P() if i in shared else batch for i in range(len(args)))
+    return shard_map(kernel, mesh=plan.mesh, in_specs=specs,
+                     out_specs=batch, check_vma=False)(*args)
+
+
 def _decode_positions(q_offset, kv_positions, kv_valid_len, B, S, T):
     """Per-sequence (B, S) q positions and (B, T) kv positions for the
     decode kernels; kv_valid_len folds into the -1 (masked) sentinel."""
@@ -584,18 +619,21 @@ def chunked_attention(
     # from the pool via scalar-prefetch page-table lookups; every other path
     # first gathers the slot's pages into the dense (B, T, H, D) layout.
     if paged:
-        if (backend == "pallas" and S * G <= PALLAS_DECODE_MAX_Q_ROWS
-                and paged_decode_supported()):
+        if backend == "pallas" and S * G <= PALLAS_DECODE_MAX_Q_ROWS:
             q_pos, kv_pos = _decode_positions(q_offset, kv_positions,
                                               kv_valid_len, B, S, T)
+            kw = dict(causal=causal, window=window, softcap=attn_softcap)
             if quant:
-                return flash_decode_paged_quant(
+                return _per_batch_shard(
+                    partial(flash_decode_paged_quant, **kw),
                     q, k.pages.codes, k.pages.scale, v.pages.codes,
-                    v.pages.scale, k.table, q_pos, kv_pos, causal=causal,
-                    window=window, softcap=attn_softcap)
-            return flash_decode_paged(q, k.pages, v.pages, k.table, q_pos,
-                                      kv_pos, causal=causal, window=window,
-                                      softcap=attn_softcap)
+                    v.pages.scale, k.table, q_pos, kv_pos,
+                    shared=(1, 2, 3, 4))
+            return _per_batch_shard(partial(flash_decode_paged, **kw), q,
+                                    k.pages, v.pages, k.table, q_pos, kv_pos,
+                                    shared=(1, 2))
+        if backend == "pallas":
+            _require_kernel(f"paged attention with {S * G} q-block rows")
         k = paged_gather(k)
         v = paged_gather(v)
 
@@ -625,15 +663,16 @@ def chunked_attention(
         if S * G <= PALLAS_DECODE_MAX_Q_ROWS:
             q_pos, kv_pos = _decode_positions(q_offset, kv_positions,
                                               kv_valid_len, B, S, T)
+            kw = dict(causal=causal, window=window, softcap=attn_softcap,
+                      block_k=ck)
             if quant:
                 # in-kernel dequant: HBM reads only codes + scales
-                return flash_decode_quant(
-                    q, k.codes, k.scale, v.codes, v.scale, q_pos, kv_pos,
-                    causal=causal, window=window, softcap=attn_softcap,
-                    block_k=ck)
-            return flash_decode(q, k, v, q_pos, kv_pos, causal=causal,
-                                window=window, softcap=attn_softcap,
-                                block_k=ck)
+                return _per_batch_shard(
+                    partial(flash_decode_quant, **kw), q, k.codes, k.scale,
+                    v.codes, v.scale, q_pos, kv_pos)
+            return _per_batch_shard(partial(flash_decode, **kw), q, k, v,
+                                    q_pos, kv_pos)
+        _require_kernel(f"positional attention with {S * G} q-block rows")
         warnings.warn(
             f"chunked_attention: positional call with {S * G} q-block rows "
             f"exceeds PALLAS_DECODE_MAX_Q_ROWS={PALLAS_DECODE_MAX_Q_ROWS}; "
@@ -959,14 +998,15 @@ def _flash_pallas_fwd_impl(q, k, v, causal, window, attn_softcap, cq, ck,
     lse (B,K,G,nq,cq) — the flash_attention_jnp residual layout."""
     B, S, Hkv, G, D = q.shape
     T = k.shape[1]
-    kv_pos = None
-    if kv_len:
-        ar = jnp.arange(T, dtype=jnp.int32)
-        kv_pos = jnp.broadcast_to(jnp.where(ar < kv_len, ar, -1), (B, T))
-    out, lse = flash_attention_gqa_fwd(
-        q.reshape(B, S, Hkv * G, D), k, v, causal=causal, window=window,
-        softcap=attn_softcap, kv_positions=kv_pos, block_q=cq, block_k=ck,
-        interpret=interpret)
+    ar = jnp.arange(T, dtype=jnp.int32)
+    kv_pos = jnp.broadcast_to(jnp.where(ar < kv_len, ar, -1) if kv_len
+                              else ar, (B, T))
+    kw = dict(causal=causal, window=window, softcap=attn_softcap,
+              block_q=cq, block_k=ck, interpret=interpret)
+    out, lse = _per_batch_shard(
+        lambda q, k, v, kv_pos: flash_attention_gqa_fwd(
+            q, k, v, kv_positions=kv_pos, **kw),
+        q.reshape(B, S, Hkv * G, D), k, v, kv_pos)
     return (out.reshape(B, S, Hkv, G, D),
             lse.reshape(B, Hkv, G, S // cq, cq))
 

@@ -164,5 +164,39 @@ elif MODE == "pipeline":
     assert abs(bubble_fraction(2, 4) - 0.2) < 1e-9
     print("PASS pipeline")
 
+elif MODE == "pallas_batch_shard":
+    # XLA cannot partition a compiled Pallas kernel: under a multi-device
+    # plan chunked_attention runs every kernel once per batch shard
+    # (shard_map), with the unsharded call's result
+    from repro.models.layers import chunked_attention, paged_from_ring
+    cfg = get_config("pimref-100m", smoke=True)
+    mesh = make_mesh((4, 2), ("data", "model"))
+    plan = plan_sharding(cfg, ShapeConfig("t", seq_len=64, global_batch=8,
+                                          mode="train"), mesh)
+    B, T, H, D = 8, 64, 2, 16
+    q, k, v = (jax.random.normal(kk, (B, T, H, D))
+               for kk in jax.random.split(jax.random.PRNGKey(0), 3))
+    off = jnp.full((B,), T - 1, jnp.int32)
+
+    def cases(q, k, v):
+        def prefill(q):
+            return chunked_attention(q, k, v, chunk_q=32, chunk_kv=32,
+                                     impl="pallas")
+        pk, pv = paged_from_ring(k, 16, "off"), paged_from_ring(v, 16, "off")
+        return (prefill(q), jax.grad(lambda q: prefill(q).sum())(q),
+                chunked_attention(q[:, -1:], k, v, q_offset=off, chunk_kv=32,
+                                  impl="pallas"),
+                chunked_attention(q[:, -1:], pk, pv, q_offset=off,
+                                  impl="pallas"))
+
+    def sharded(q, k, v):
+        with use_plan(plan):
+            return cases(q, k, v)
+
+    assert str(jax.make_jaxpr(sharded)(q, k, v)).count("shard_map") >= 4
+    for got, want in zip(jax.jit(sharded)(q, k, v), jax.jit(cases)(q, k, v)):
+        almost(got, want, 1e-5)
+    print("PASS pallas_batch_shard")
+
 else:
     raise SystemExit(f"unknown mode {MODE}")

@@ -1,7 +1,7 @@
-"""Tests for the JAX version-compatibility layer (repro.compat).
+"""Tests for the JAX compatibility layer (repro.compat).
 
 Three families:
-  * every exported symbol resolves on the installed JAX,
+  * every exported symbol resolves on the installed JAX (0.9),
   * is_manual_axis / current_axis_types agree with ground truth inside and
     outside shard_map (full- and partial-manual),
   * repo hygiene: version-fragile JAX spellings appear only inside
@@ -49,13 +49,12 @@ def test_version_flags_consistent():
     assert jax_compat._parse_version("0.5.0rc1") == (0, 5, 0)
     assert jax_compat._parse_version("0.4.39rc1") == (0, 4, 39)
     assert jax_compat._parse_version("0.7.2.dev123") == (0, 7, 2)
-    assert compat.HAS_NATIVE_SHARD_MAP == hasattr(jax, "shard_map")
-    assert compat.HAS_NATIVE_AXIS_TYPE == hasattr(jax.sharding, "AxisType")
+    assert compat.JAX_VERSION >= (0, 9)
     assert "repro.compat" in compat.describe_support()
 
 
 def test_axis_type_members():
-    # the stub and the native enum both expose these three members
+    assert compat.AxisType is jax.sharding.AxisType
     for member in ("Auto", "Explicit", "Manual"):
         assert hasattr(compat.AxisType, member)
 
@@ -64,10 +63,30 @@ def test_make_mesh_roundtrip():
     mesh = compat.make_mesh((1,), ("data",))
     assert mesh.axis_names == ("data",)
     assert mesh.shape["data"] == 1
-    # axis_types is accepted on every JAX (dropped when unsupported)
+    # Auto unless asked: jax.make_mesh alone would make every axis Explicit
+    assert tuple(mesh.axis_types) == (compat.AxisType.Auto,)
     mesh2 = compat.make_mesh(
-        (1,), ("data",), axis_types=(compat.AxisType.Auto,))
-    assert mesh2.axis_names == ("data",)
+        (1,), ("data",), axis_types=(compat.AxisType.Explicit,))
+    assert tuple(mesh2.axis_types) == (compat.AxisType.Explicit,)
+    mesh3 = compat.make_mesh((1,), ("data",), devices=jax.devices()[:1])
+    assert list(mesh3.devices.flat) == jax.devices()[:1]
+
+
+def test_enable_compilation_cache(monkeypatch, tmp_path):
+    before = jax.config.jax_compilation_cache_dir
+    # a directory named by the environment stays in charge, untouched
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    assert compat.enable_compilation_cache() == str(tmp_path)
+    assert jax.config.jax_compilation_cache_dir == before
+    # otherwise a fixed path inside the checkout
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+    try:
+        path = compat.enable_compilation_cache()
+        assert path == os.path.join(REPO, ".jax_cache")
+        assert jax.config.jax_compilation_cache_dir == path
+        assert compat.enable_compilation_cache() == path
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)
 
 
 def test_tree_utils():
@@ -80,8 +99,8 @@ def test_tree_utils():
 
 
 def test_optimization_barrier_differentiable():
-    # the 0.4.x upstream barrier has no differentiation rule; compat's must
-    # be transparent to value_and_grad (this is what models smoke-tests need)
+    # the barrier must be transparent to value_and_grad (the scan bodies of
+    # every model family differentiate through it)
     def loss(x):
         y = compat.optimization_barrier(x * 3.0)
         return (y ** 2).sum()
@@ -99,7 +118,7 @@ def test_optimization_barrier_differentiable():
 def test_pallas_entry_points():
     pl = compat.import_pallas()
     assert hasattr(pl, "pallas_call")
-    compat.import_pallas_tpu()  # may be None; must not raise
+    assert hasattr(compat.import_pallas_tpu(), "VMEM")
 
 
 # ---------------------------------------------------------------------------
@@ -150,26 +169,9 @@ def test_manual_detection_partial_manual():
                           axis_names=frozenset({"a"}), check_vma=False)
     out = jax.jit(fn)(jnp.arange(4.0))
     assert out.shape == (4,)
-    if compat.HAS_PARTIAL_MANUAL_SHARD_MAP:
-        assert seen["manual"] == frozenset({"a"})
-        assert seen["types"] == {"a": compat.AxisType.Manual,
-                                 "b": compat.AxisType.Auto}
-    else:
-        # 0.4.x promotes partial-manual to fully-manual (see compat docs);
-        # detection reports the effective (promoted) axis types
-        assert seen["manual"] == frozenset({"a", "b"})
-        assert seen["types"] == {"a": compat.AxisType.Manual,
-                                 "b": compat.AxisType.Manual}
-
-
-def test_partial_manual_promotion_rejects_auto_axis_specs():
-    if compat.HAS_PARTIAL_MANUAL_SHARD_MAP:
-        pytest.skip("native partial-manual: promotion path not taken")
-    mesh = compat.make_mesh((1, 1), ("a", "b"))
-    with pytest.raises(NotImplementedError):
-        compat.shard_map(lambda x: x, mesh=mesh,
-                         in_specs=P("a", "b"), out_specs=P("a", "b"),
-                         axis_names=frozenset({"a"}))
+    assert seen["manual"] == frozenset({"a"})
+    assert seen["types"] == {"a": compat.AxisType.Manual,
+                             "b": compat.AxisType.Auto}
 
 
 def test_context_mesh_nesting():
@@ -194,7 +196,7 @@ def test_shard_map_flag_spellings_equivalent():
     def body(v):
         return v * 2
 
-    for kw in ({"check_vma": False}, {"check_rep": False}, {}):
+    for kw in ({"check_vma": False}, {}):
         out = compat.shard_map(body, mesh=mesh, in_specs=P("data"),
                                out_specs=P("data"), **kw)(x)
         assert float(out.sum()) == float(x.sum()) * 2
@@ -203,8 +205,7 @@ def test_shard_map_flag_spellings_equivalent():
 def test_shard_map_rejects_conflicting_axis_args():
     mesh = compat.make_mesh((1, 1), ("a", "b"))
     with pytest.raises(TypeError):
-        compat.shard_map(lambda x: x, mesh=mesh, in_specs=P(), out_specs=P(),
-                         axis_names=frozenset({"a"}), auto=frozenset({"b"}))
+        compat.shard_map(lambda x: x, in_specs=P(), out_specs=P())
     with pytest.raises(ValueError):
         compat.shard_map(lambda x: x, mesh=mesh, in_specs=P(), out_specs=P(),
                          axis_names=frozenset({"nope"}))
@@ -280,17 +281,11 @@ def test_fleet_control_plane_stays_jax_free(tmp_path):
 
 
 def test_pallas_prefetch_grid_spec_resolves():
-    # may legitimately be None only where the TPU namespace is absent
     spec = compat.pallas_prefetch_grid_spec()
-    if compat.import_pallas_tpu() is not None:
-        assert spec is not None and callable(spec)
+    assert spec is compat.import_pallas_tpu().PrefetchScalarGridSpec
 
 
 def test_pallas_vmem_scratch_resolves():
-    # the helper must hand out a usable scratch allocation on every install,
-    # including ones where import_pallas_tpu() returns None
     scr = compat.pallas_vmem_scratch((8, 128), jnp.float32)
-    assert scr is not None
-    if compat.import_pallas_tpu() is None:
-        pl = compat.import_pallas()
-        assert isinstance(scr, pl.MemoryRef)
+    assert tuple(scr.shape) == (8, 128)
+    assert scr.memory_space == compat.import_pallas_tpu().VMEM

@@ -43,3 +43,7 @@ def test_mini_dryrun():
 
 def test_pipeline_parallel():
     _run("pipeline")
+
+
+def test_pallas_kernels_per_batch_shard():
+    _run("pallas_batch_shard")

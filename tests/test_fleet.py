@@ -292,6 +292,31 @@ def test_whole_fleet_dead_yields_typed_shard_lost(monkeypatch):
 # ---------------------------------------------------------------------------
 # Multiprocessing shards (the CPU multi-host gate)
 # ---------------------------------------------------------------------------
+def test_mp_backend_refused_on_tpu(monkeypatch):
+    """A chip belongs to one process: on a TPU host the mp stand-in would
+    have every worker contend for the same chips, so it refuses and names
+    the in-process per-device fleet instead of hanging."""
+    from repro.launch.serve import make_fleet
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    with pytest.raises(ValueError, match="inproc"):
+        make_fleet(ARCH, shards=2, backend="mp")
+
+
+def test_inproc_shards_are_one_device_replicas():
+    from repro.launch.serve import make_fleet
+    fleet = make_fleet(ARCH, shards=2, slots=2, prompt_len=8, gen=4,
+                       chunk=4)
+    try:
+        devs = jax.devices()
+        for sid, sh in enumerate(fleet.shards):
+            assert list(sh.eng.plan.mesh.devices.flat) == \
+                [devs[sid % len(devs)]]
+            for leaf in jax.tree_util.tree_leaves(sh.eng.params):
+                assert leaf.devices() == {devs[sid % len(devs)]}
+    finally:
+        fleet.close()
+
+
 def test_mp_two_shard_fleet_drains_byte_identical(ref_paged, monkeypatch):
     """The ROADMAP gate: a 2-shard multiprocessing fleet drains the mixed
     queue byte-identical to a single engine, with both workers serving."""

@@ -305,6 +305,76 @@ def test_dispatch_env_end_to_end_decode_step(monkeypatch, rng):
     np.testing.assert_allclose(outs["pallas"][1], outs["jnp"][1], atol=2e-4)
 
 
+# ---------------------------------------------------------------------------
+# On the TPU no path may hide the device: interpret mode, the paged gather and
+# the jnp path are CPU-only, and a call no kernel takes raises there
+# ---------------------------------------------------------------------------
+@pytest.fixture()
+def on_tpu_backend(monkeypatch):
+    """Make repro see a TPU default backend (no kernel runs in these
+    tests: each raises, or returns, before anything is compiled)."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+
+
+def test_interpret_mode_never_on_tpu(on_tpu_backend, monkeypatch):
+    from repro.kernels.common import use_interpret
+    monkeypatch.setenv("REPRO_PALLAS_INTERPRET", "1")
+    assert use_interpret() is False
+    assert attn_impl() == "pallas"
+
+
+def test_interpret_mode_env_switch_off_tpu(monkeypatch):
+    from repro.kernels.common import use_interpret
+    monkeypatch.delenv("REPRO_PALLAS_INTERPRET", raising=False)
+    assert use_interpret() is True
+    monkeypatch.setenv("REPRO_PALLAS_INTERPRET", "0")
+    assert use_interpret() is False
+
+
+def test_paged_gather_fallback_raises_on_tpu(on_tpu_backend, rng):
+    from repro.models.layers import PALLAS_DECODE_MAX_Q_ROWS, paged_from_ring
+    S = PALLAS_DECODE_MAX_Q_ROWS + 1
+    q, k, v = _qkv(rng, 1, S, 8, 1, 1, 8)
+    pos = jnp.zeros((1,), jnp.int32)
+    with pytest.raises(NotImplementedError, match="paged attention"):
+        chunked_attention(q, paged_from_ring(k, 4, "off"),
+                          paged_from_ring(v, 4, "off"), q_offset=pos,
+                          impl="pallas")
+
+
+def test_positional_jnp_fallback_raises_on_tpu(on_tpu_backend, rng):
+    from repro.models.layers import PALLAS_DECODE_MAX_Q_ROWS
+    S = PALLAS_DECODE_MAX_Q_ROWS + 1
+    q, k, v = _qkv(rng, 1, S, 8, 1, 1, 8)
+    with pytest.raises(NotImplementedError, match="positional attention"):
+        chunked_attention(q, k, v, q_offset=jnp.zeros((1,), jnp.int32),
+                          impl="pallas")
+
+
+def test_positional_jnp_fallback_warns_off_tpu(rng):
+    from repro.models.layers import PALLAS_DECODE_MAX_Q_ROWS
+    S = PALLAS_DECODE_MAX_Q_ROWS + 1
+    q, k, v = _qkv(rng, 1, S, 8, 1, 1, 8)
+    kw = dict(q_offset=jnp.zeros((1,), jnp.int32), chunk_kv=8)
+    with pytest.warns(UserWarning, match="falling back to the jnp path"):
+        out = chunked_attention(q, k, v, impl="pallas", **kw)
+    np.testing.assert_allclose(
+        np.asarray(out), np.asarray(chunked_attention(q, k, v, impl="jnp",
+                                                      **kw)), atol=1e-6)
+
+
+def test_int4_decode_kernel_refuses_compiled_mode(rng):
+    """The nibble unpack does not lower for the TPU: compiled mode raises a
+    clear error instead of a compiler failure deep in the program."""
+    q, k, v = _qkv(rng, 1, 1, 16, 2, 2, 32)
+    qk, qv = kv_quantize(k, "int4"), kv_quantize(v, "int4")
+    pos = jnp.arange(16, dtype=jnp.int32)[None]
+    from repro.kernels.flash_attention import ops
+    with pytest.raises(NotImplementedError, match="int4"):
+        ops.flash_decode_quant(q, qk.codes, qk.scale, qv.codes, qv.scale,
+                               pos[:, -1:], pos, interpret=False)
+
+
 def test_dispatch_impl_arg_overrides_env(monkeypatch, rng):
     monkeypatch.setenv("REPRO_ATTN_IMPL", "jnp")
     q, k, v = _qkv(rng, 1, 64, 64, 2, 2, 16)

@@ -89,8 +89,6 @@ def test_aligned_cache_len(paged_env):
 # ---------------------------------------------------------------------------
 # Paged Pallas decode kernels
 # ---------------------------------------------------------------------------
-@pytest.mark.skipif(not fa_ops.paged_decode_supported(),
-                    reason="no scalar-prefetch grid spec in this JAX build")
 @pytest.mark.parametrize("mode", ["off", "int8", "int4"])
 def test_paged_kernel_matches_dense(mode):
     """The paged kernel streaming pool pages through the table is bitwise
@@ -123,8 +121,6 @@ def test_paged_kernel_matches_dense(mode):
     np.testing.assert_array_equal(np.asarray(out), np.asarray(ref))
 
 
-@pytest.mark.skipif(not fa_ops.paged_decode_supported(),
-                    reason="no scalar-prefetch grid spec in this JAX build")
 def test_paged_chunked_attention_backends_agree(paged_env):
     """chunked_attention's paged pallas dispatch vs its paged jnp gather
     fallback on the same PagedKVCache."""

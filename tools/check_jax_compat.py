@@ -3,8 +3,8 @@
 
 Single source of truth for the rule — tests/test_compat.py imports this
 module and the CI compat-lint job runs it as a script (stdlib only, no jax
-needed). Import the shimmed symbols from ``repro.compat`` instead; see
-README.md for the support matrix.
+needed). Import these symbols from ``repro.compat`` instead, so the next
+JAX release that moves one is a one-file change.
 """
 from __future__ import annotations
 
@@ -64,7 +64,7 @@ def find_offenders(repo: str) -> List[str]:
 
 # Any Pallas call site (pallas_call / pl.* entry points / pltpu.* scratch)
 # must obtain its pallas modules from repro.compat — the entry-point location
-# is version-sensitive and the TPU namespace may be absent entirely.
+# is version-sensitive.
 #
 # Note (kv-quant PR): the Proteus-quantized decode kernel
 # (flash_decode_quant_fwd) and the block-sparse tile skip reuse the existing
