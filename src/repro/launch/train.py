@@ -156,6 +156,7 @@ def train(arch: str, *, smoke: bool = True, steps: int = 100,
     preempt = PreemptionHandler().install()
     straggler = StragglerMonitor()
     losses: List[float] = []
+    grad_norms: List[float] = []
     first_step = start
     preempted = False
     t_begin = time.time()
@@ -219,6 +220,7 @@ def train(arch: str, *, smoke: bool = True, steps: int = 100,
         loss = float(metrics["loss"])
         gnorm = float(metrics["grad_norm"])
         losses.append(loss)
+        grad_norms.append(gnorm)
 
         if bool(metrics["skipped"]):
             skipped_total += 1
@@ -256,9 +258,10 @@ def train(arch: str, *, smoke: bool = True, steps: int = 100,
                         cfg, shape, seed=_salted_seed(run.seed, salt))
                     if rb < first_step:
                         first_step = rb
-                        del losses[:]
+                        del losses[:], grad_norms[:]
                     else:
                         del losses[rb - first_step:]
+                        del grad_norms[rb - first_step:]
                     print(f"loss spike at step {step} ({observed:.3f} > "
                           f"{spike_factor:.1f}x EWMA): rolled back to step "
                           f"{rb}, data window re-seeded (salt={salt})")
@@ -292,7 +295,7 @@ def train(arch: str, *, smoke: bool = True, steps: int = 100,
     preempt.uninstall()
     drain_writer()
     return {"losses": losses, "final_loss": losses[-1] if losses else None,
-            "params": params, "opt_state": opt_state,
+            "grad_norms": grad_norms, "params": params, "opt_state": opt_state,
             "first_step": first_step, "resumed_at": resumed_at,
             "preempted": preempted, "skipped_steps": skipped_total,
             "rollbacks": rollbacks, "anomalies": anomalies,

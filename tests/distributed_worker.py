@@ -198,5 +198,20 @@ elif MODE == "pallas_batch_shard":
         almost(got, want, 1e-5)
     print("PASS pallas_batch_shard")
 
+elif MODE == "chip_smoke_dp":
+    # chip_smoke.py's 4-way data-parallel phase at smoke widths: the 4-way
+    # run agrees with one device, and its learning-rate-0 control fails
+    import importlib.util
+    spec = importlib.util.spec_from_file_location(
+        "chip_smoke", os.path.join(os.path.dirname(__file__), "..",
+                                   "chip_smoke.py"))
+    smoke = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(smoke)
+    out = smoke.dp_train_phase(smoke.CompileClock(), smoke=True, steps=3,
+                               batch=8, seq=32, n=4)
+    assert out["control"]["param_gap"] == 1.0, out
+    assert out["control"]["update_ratio"] == 0.0, out
+    print("PASS chip_smoke_dp")
+
 else:
     raise SystemExit(f"unknown mode {MODE}")

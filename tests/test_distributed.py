@@ -47,3 +47,7 @@ def test_pipeline_parallel():
 
 def test_pallas_kernels_per_batch_shard():
     _run("pallas_batch_shard")
+
+
+def test_chip_smoke_data_parallel_phase():
+    _run("chip_smoke_dp")
