@@ -255,8 +255,11 @@ def _flash_call(q: jax.Array, k: jax.Array, v: jax.Array,
             pallas_vmem_scratch((R, D), jnp.float32),
         ],
     )
+    # the kernel's name in a profile: flash_fwd, flash_decode[_paged][_quant]
+    name = ("flash_fwd" if with_lse else "flash_decode"
+            + ("_paged" if paged else "") + ("_quant" if quant else ""))
     outs = pl.pallas_call(kernel, grid_spec=grid_spec, out_shape=out_shape,
-                          interpret=interpret)(
+                          interpret=interpret, name=name)(
         *([table] if paged else []), *operands)
     out = outs[0].reshape(B, Hkv, S, G, D)
     if with_lse:

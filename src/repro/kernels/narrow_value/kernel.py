@@ -40,6 +40,7 @@ def required_bits_kernel(x: jax.Array, block: int = 256, *,
         out_specs=pl.BlockSpec((1,), lambda i: (i,)),
         out_shape=jax.ShapeDtypeStruct((nb,), jnp.int32),
         interpret=interpret,
+        name="narrow_required_bits",
     )(x)
 
 
@@ -73,6 +74,7 @@ def pack_int4_kernel(v: jax.Array, block: int = 512, *,
         out_specs=pl.BlockSpec((b // 2,), lambda i: (i,)),
         out_shape=jax.ShapeDtypeStruct((n // 2,), jnp.int8),
         interpret=interpret,
+        name="narrow_pack_int4",
     )(v)
 
 
@@ -88,4 +90,5 @@ def unpack_int4_kernel(p: jax.Array, block: int = 256, *,
         out_specs=pl.BlockSpec((2 * b,), lambda i: (i,)),
         out_shape=jax.ShapeDtypeStruct((2 * n,), jnp.int8),
         interpret=interpret,
+        name="narrow_unpack_int4",
     )(p)

@@ -66,4 +66,5 @@ def quant_matmul_kernel(x: jax.Array, wq: jax.Array, scales: jax.Array, *,
         out_shape=jax.ShapeDtypeStruct((M, N), out_dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
         interpret=interpret,
+        name="quant_matmul",
     )(x, wq, scales)

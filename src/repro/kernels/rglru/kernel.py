@@ -60,4 +60,5 @@ def rglru_scan_kernel(a: jax.Array, b: jax.Array, *, block_t: int = 128,
         out_shape=jax.ShapeDtypeStruct((B, T, D), jnp.float32),
         scratch_shapes=[pltpu.VMEM((D,), jnp.float32)],
         interpret=interpret,
+        name="rglru_scan",
     )(a, b)

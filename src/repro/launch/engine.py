@@ -39,6 +39,7 @@ preemption-safe serving. Every submitted request ends in exactly one
 """
 from __future__ import annotations
 
+import bisect
 import enum
 import time
 from collections import deque
@@ -56,9 +57,40 @@ from repro.distributed.chaos import (ChaosConfig, ChaosMonkey,
 from repro.distributed.fault_tolerance import StragglerMonitor
 from repro.kernels.common import kv_page_size
 from repro.launch import specs as specs_lib
+from repro.launch import tracing
 from repro.launch.steps import (make_generate_step, make_serving_jits,
                                 spec_config)
 from repro.models.layers import PagedKVCache, QKVCache
+
+
+# chunk wall times are kept as counts over fixed log-spaced buckets, ten a
+# decade from 10 us to 1000 s (bucket i holds times in [EDGES[i-1],
+# EDGES[i]); the first and last are open), so the record stays the same size
+# for the engine's whole life; each chunk's own time is its
+# ``repro.engine.chunk`` and ``repro.engine.sync`` spans in a profile
+CHUNK_HIST_EDGES = tuple(1e-5 * 10 ** (i / 10) for i in range(81))
+
+
+def chunk_hist() -> List[int]:
+    return [0] * (len(CHUNK_HIST_EDGES) + 1)
+
+
+def hist_quantile(counts: List[int], q: float) -> float:
+    """The ``q`` quantile (0..1) of a :data:`CHUNK_HIST_EDGES` histogram, as
+    its bucket's geometric middle (within 12% of the true value); 0 when
+    empty."""
+    n = sum(counts)
+    if n == 0:
+        return 0.0
+    rank, seen = max(q * n, 1.0), 0
+    for i, c in enumerate(counts):
+        seen += c
+        if seen >= rank:
+            break
+    edges = CHUNK_HIST_EDGES
+    lo = edges[max(i - 1, 0)]
+    hi = edges[min(i, len(edges) - 1)]
+    return (lo * hi) ** 0.5
 
 
 class ErrorReason(str, enum.Enum):
@@ -481,7 +513,8 @@ class ServeEngine:
         # instrumentation for benchmarks / regression tracking
         self.stats: Dict[str, Any] = {
             "decode_dispatches": 0, "prefills": 0, "tokens_out": 0,
-            "wall_seconds": 0.0, "chunk_seconds": [],
+            "wall_seconds": 0.0, "chunk_hist": chunk_hist(),
+            "chunk_seconds_sum": 0.0, "rejected_dead": 0,
             "kv_pages_in_use": 0, "kv_pages_peak": 0, "prefix_hits": 0,
             "deadline_miss": 0, "shed_events": 0, "retries": 0,
             "error_completions": 0, "straggler_events": 0,
@@ -532,7 +565,15 @@ class ServeEngine:
 
     def submit(self, request: Request) -> bool:
         """Enqueue a request; returns False (with an immediate ``queue_full``
-        error completion) when the bounded frontend queue is full."""
+        error completion) when the bounded frontend queue is full, and (with
+        an immediate ``step_failure`` one) when a failed dispatch has left
+        the engine dead."""
+        if self._dead:
+            self.stats["rejected_dead"] += 1
+            self._error(request.uid, (), ErrorReason.STEP_FAILURE,
+                        f"request {request.uid}: the engine is dead after a "
+                        "failed dispatch")
+            return False
         if self.max_queue is not None and len(self._queue) >= self.max_queue:
             self._error(request.uid, (), ErrorReason.QUEUE_FULL,
                         f"request {request.uid}: queue full "
@@ -691,6 +732,7 @@ class ServeEngine:
                 self._queue.popleft()
                 self._error(req.uid, (), ErrorReason.BAD_REQUEST, str(e))
                 continue
+            cap = need = 0
             if self.paged:
                 cap = min(req.max_new_tokens, self.max_len - n)
                 need = self._worst_pages(n, cap)
@@ -709,47 +751,56 @@ class ServeEngine:
                     self.stats["admission_blocked"] += 1
                     break
             self._queue.popleft()
-            slot = self._free.pop()
+            with tracing.span("engine.admit", uid=req.uid, prompt_len=len(t)):
+                self._start(req, batch, n, t, cap, need)
+
+    def _start(self, req: Request, batch: Dict[str, Any], n: int,
+               t: np.ndarray, cap: int, need: int) -> None:
+        """Prefill an admitted request and insert it into a free slot
+        (``cap`` and ``need``: its token cap and reserved pages, paged)."""
+        slot = self._free.pop()
+        with tracing.span("engine.prefill", uid=req.uid,
+                          bucket=self.prompt_len):
             logits, small = self._prefill(self.params, batch)
-            if self.paged:
-                first = jnp.argmax(logits[:, n - 1]).reshape(1, 1) \
-                    .astype(jnp.int32)
-                try:
-                    dest, trow = self._plan_pages(slot, t, n, cap)
-                except PagePoolExhaustedError as e:
-                    # reachable only under external pressure (chaos steal):
-                    # the reservation invariant covers engine-driven demand
-                    self._free.append(slot)
-                    self._error(req.uid, (), ErrorReason.PAGE_POOL, str(e))
-                    continue
-                self._reserved[slot] = need
-                self._reserved_total += need
-                args = (self.cache, self._tok, small, first, jnp.int32(slot),
-                        jnp.asarray(dest), jnp.asarray(trow), jnp.int32(n))
-                self._active[slot] = _Slot(request=req, n=n, cap=cap)
-            else:
-                cap = min(req.max_new_tokens, self.max_len - self.prompt_len)
-                first = jnp.argmax(logits[:, -1:], axis=-1).astype(jnp.int32)
-                args = (self.cache, self._tok, small, first, jnp.int32(slot),
-                        jnp.zeros((0,), jnp.int32), jnp.zeros((0,), jnp.int32),
-                        jnp.int32(0))
-                self._active[slot] = _Slot(request=req, n=n, cap=cap)
-            if self.spec != "off":
-                (self.cache, self._tok, self._hist,
-                 self._hist_len) = self._insert(
-                    *args, self._hist, self._hist_len, batch["tokens"],
-                    jnp.int32(len(t)))
-            else:
-                self.cache, self._tok = self._insert(*args)
-            if self._hook is not None:
-                # absolute logits positions: true prompt end (paged
-                # right-pad) vs bucket end (contiguous left-pad)
-                base = n if self.paged else self.prompt_len
-                pos = self._chaos.plan_request(req.uid, base, cap)
-                self._arm_np[slot] = -1 if pos is None else pos
-            if self.paged:
-                self._refresh_page_stats()
-            self.stats["prefills"] += 1
+        if self.paged:
+            first = jnp.argmax(logits[:, n - 1]).reshape(1, 1) \
+                .astype(jnp.int32)
+            try:
+                dest, trow = self._plan_pages(slot, t, n, cap)
+            except PagePoolExhaustedError as e:
+                # reachable only under external pressure (chaos steal): the
+                # reservation invariant covers engine-driven demand
+                self._free.append(slot)
+                self._error(req.uid, (), ErrorReason.PAGE_POOL, str(e))
+                return
+            self._reserved[slot] = need
+            self._reserved_total += need
+            args = (self.cache, self._tok, small, first, jnp.int32(slot),
+                    jnp.asarray(dest), jnp.asarray(trow), jnp.int32(n))
+            self._active[slot] = _Slot(request=req, n=n, cap=cap)
+        else:
+            cap = min(req.max_new_tokens, self.max_len - self.prompt_len)
+            first = jnp.argmax(logits[:, -1:], axis=-1).astype(jnp.int32)
+            args = (self.cache, self._tok, small, first, jnp.int32(slot),
+                    jnp.zeros((0,), jnp.int32), jnp.zeros((0,), jnp.int32),
+                    jnp.int32(0))
+            self._active[slot] = _Slot(request=req, n=n, cap=cap)
+        if self.spec != "off":
+            (self.cache, self._tok, self._hist,
+             self._hist_len) = self._insert(
+                 *args, self._hist, self._hist_len, batch["tokens"],
+                 jnp.int32(len(t)))
+        else:
+            self.cache, self._tok = self._insert(*args)
+        if self._hook is not None:
+            # absolute logits positions: true prompt end (paged right-pad)
+            # vs bucket end (contiguous left-pad)
+            base = n if self.paged else self.prompt_len
+            pos = self._chaos.plan_request(req.uid, base, cap)
+            self._arm_np[slot] = -1 if pos is None else pos
+        if self.paged:
+            self._refresh_page_stats()
+        self.stats["prefills"] += 1
 
     def _ensure_writable(self) -> None:
         """Copy-on-write pass before a decode chunk: any page the chunk may
@@ -838,7 +889,9 @@ class ServeEngine:
         if self._hook is not None:
             args += (jnp.asarray(self._arm_np),)
         try:
-            out = gen(*args)
+            with tracing.span("engine.chunk", dispatch=idx,
+                              slots=len(self._active)):
+                out = gen(*args)
         except Exception as e:  # noqa: BLE001 — donated operands consumed
             self._fail_all(f"chunk dispatch failed: {e!r}")
             return False
@@ -848,11 +901,15 @@ class ServeEngine:
         else:
             (self.cache, self._tok, self._key, done, n_valid, toks,
              failed) = out
-        toks_np = np.asarray(toks)          # ONE host sync per chunk
-        done_np = np.asarray(done)
-        n_np = np.asarray(n_valid)
-        failed_np = np.asarray(failed)
-        self.stats["chunk_seconds"].append(time.perf_counter() - t0)
+        with tracing.span("engine.sync", dispatch=idx):
+            toks_np = np.asarray(toks)          # ONE host sync per chunk
+            done_np = np.asarray(done)
+            n_np = np.asarray(n_valid)
+            failed_np = np.asarray(failed)
+        dt = time.perf_counter() - t0
+        self.stats["chunk_hist"][bisect.bisect_right(CHUNK_HIST_EDGES,
+                                                     dt)] += 1
+        self.stats["chunk_seconds_sum"] += dt
         self.stats["decode_dispatches"] += 1
         # watchdog: chunk dispatches slower than threshold x the wall-time
         # EMA are straggler events; `shed_after` consecutive events shed one
@@ -897,38 +954,43 @@ class ServeEngine:
                 reason: Optional[ErrorReason] = None,
                 error: Optional[str] = None) -> None:
         st = self._active.pop(slot)
-        self._free.append(slot)
-        self._arm_np[slot] = -1
-        self._reserved_total -= self._reserved.pop(slot, 0)
-        if self.paged:
-            for phys in self._host_table[slot]:
-                if phys:
-                    self._alloc.decref(int(phys))
-            self._host_table[slot] = 0
-            # device table -> trash page: the retired slot keeps riding the
-            # fused decode until reused, and its stale writes must not land
-            # in pages the allocator may hand to someone else (skipped when
-            # the engine is dead — the cache buffers may be gone)
-            if not self._dead:
-                self.cache = self._clear_slot(self.cache, jnp.int32(slot))
-            self._refresh_page_stats()
-        self.stats["tokens_out"] += len(st.produced)
-        uid = st.request.uid
-        self._deadline_at.pop(uid, None)
-        produced = st.produced
-        pre = self._resume_prefix.pop(uid, None)
-        if pre:
-            # restored request: tokens produced before the preemption were
-            # re-prefilled as prompt suffix; the completion carries the full
-            # stream so restore is invisible to callers
-            produced = pre + produced
-        if finish == "error":
-            self._error(uid, produced, reason or ErrorReason.STEP_FAILURE,
-                        error or "unknown failure")
-        else:
-            self.completions.append(Completion(
-                uid=uid, tokens=np.asarray(produced, np.int32),
-                finish_reason=finish))
+        with tracing.span("engine.retire", uid=st.request.uid,
+                          tokens=len(st.produced),
+                          reason=str(reason or finish)):
+            self._free.append(slot)
+            self._arm_np[slot] = -1
+            self._reserved_total -= self._reserved.pop(slot, 0)
+            if self.paged:
+                for phys in self._host_table[slot]:
+                    if phys:
+                        self._alloc.decref(int(phys))
+                self._host_table[slot] = 0
+                # device table -> trash page: the retired slot keeps riding
+                # the fused decode until reused, and its stale writes must
+                # not land in pages the allocator may hand to someone else
+                # (skipped when the engine is dead — the cache buffers may
+                # be gone)
+                if not self._dead:
+                    self.cache = self._clear_slot(self.cache, jnp.int32(slot))
+                self._refresh_page_stats()
+            self.stats["tokens_out"] += len(st.produced)
+            uid = st.request.uid
+            self._deadline_at.pop(uid, None)
+            produced = st.produced
+            pre = self._resume_prefix.pop(uid, None)
+            if pre:
+                # restored request: tokens produced before the preemption
+                # were re-prefilled as prompt suffix; the completion carries
+                # the full stream so restore is invisible to callers
+                produced = pre + produced
+            if finish == "error":
+                self._error(uid, produced,
+                            reason or ErrorReason.STEP_FAILURE,
+                            error or "unknown failure")
+            else:
+                self.completions.append(Completion(
+                    uid=uid, tokens=np.asarray(produced, np.int32),
+                    finish_reason=finish))
 
     def _fail_all(self, msg: str) -> None:
         """Unrecoverable dispatch failure: every in-flight and queued request

@@ -52,7 +52,8 @@ from repro.distributed.chaos import (ShardChaosConfig, ShardChaosMonkey,
 from repro.distributed.dispatcher import Dispatcher
 from repro.distributed.fault_tolerance import (HealthMonitor, RestartManifest,
                                                ShardState)
-from repro.launch.engine import Completion, ErrorReason, Request
+from repro.launch.engine import (Completion, ErrorReason, Request,
+                                 chunk_hist, hist_quantile)
 
 # engine stats counters a warm benchmark measurement resets to zero
 _RESET_STATS = ("tokens_out", "decode_dispatches", "prefills",
@@ -81,7 +82,8 @@ def _reset_engine_stats(eng) -> None:
     for k in _RESET_STATS:
         eng.stats[k] = 0
     eng.stats["wall_seconds"] = 0.0
-    eng.stats["chunk_seconds"] = []
+    eng.stats["chunk_hist"] = chunk_hist()
+    eng.stats["chunk_seconds_sum"] = 0.0
 
 
 def _step_report(eng, drained: int, beat: bool,
@@ -617,15 +619,16 @@ class ServeFleet:
                     s = shard.final_stats()
                 except ShardKilledError:
                     s = {}
-            cs = [float(x) for x in s.get("chunk_seconds") or ()]
+            hist = s.get("chunk_hist") or [0]
+            busy = float(s.get("chunk_seconds_sum", 0.0))
             rows.append({
                 "shard": sid, "state": str(self.monitor.state(sid)),
                 "tokens_out": int(s.get("tokens_out", 0)),
                 "dispatches": int(s.get("decode_dispatches", 0)),
-                "tok_s": (s.get("tokens_out", 0) / max(sum(cs), 1e-9)
-                          if cs else 0.0),
-                "p50_ms": float(np.percentile(cs, 50)) * 1e3 if cs else 0.0,
-                "p95_ms": float(np.percentile(cs, 95)) * 1e3 if cs else 0.0,
+                "tok_s": (s.get("tokens_out", 0) / busy if busy > 0
+                          else 0.0),
+                "p50_ms": hist_quantile(hist, 0.50) * 1e3,
+                "p95_ms": hist_quantile(hist, 0.95) * 1e3,
                 "error_completions": int(s.get("error_completions", 0)),
                 "deadline_miss": int(s.get("deadline_miss", 0)),
             })

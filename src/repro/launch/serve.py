@@ -11,6 +11,8 @@ sized to prompt_len + gen at prefill; no repad between phases):
 
   PYTHONPATH=src python -m repro.launch.serve --arch pimref-100m \
       --batch 4 --prompt-len 32 --gen 16 [--engine fused|loop] [--mode queue]
+  PYTHONPATH=src python -m repro.launch.serve --arch pimref-100m \
+      --mode queue --requests 4 --profile-dir /tmp/profile   # engine spans
 """
 from __future__ import annotations
 
@@ -32,6 +34,7 @@ from repro.distributed.fault_tolerance import (PreemptionHandler,
                                                RestartManifest)
 from repro.kernels.common import on_tpu
 from repro.launch import mesh as mesh_lib
+from repro.launch import tracing
 from repro.launch.engine import Request, ServeEngine
 from repro.launch.fleet import ServeFleet
 from repro.launch.steps import (make_decode_step, make_serving_jits, named,
@@ -598,8 +601,17 @@ def main() -> None:
                     help="persist each shard snapshot as an atomic "
                     "RestartManifest under this directory")
     ap.add_argument("--full", dest="smoke", action="store_false", default=True)
+    ap.add_argument("--profile-dir", default=None,
+                    help="record a profile of the run into this directory: "
+                    "device ops named by program scope, host spans "
+                    "repro.engine.{admit,prefill,chunk,sync,retire}")
     args = ap.parse_args()
     enable_compilation_cache()
+    with tracing.profile(args.profile_dir):
+        _run(args)
+
+
+def _run(args) -> None:
     if args.attn_impl:
         os.environ["REPRO_ATTN_IMPL"] = args.attn_impl
     if args.kv_quant:

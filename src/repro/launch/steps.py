@@ -21,6 +21,7 @@ from repro.configs.base import ModelConfig, RunConfig, ShapeConfig
 from repro.core import proteus
 from repro.core.mimdram import Plan, plan_sharding, use_plan
 from repro.launch import specs as specs_lib
+from repro.launch import tracing
 from repro.models import layers
 from repro.models import module as mod
 from repro.optim import Optimizer
@@ -129,12 +130,16 @@ def make_train_step(model, optimizer: Optimizer, plan: Plan, run: RunConfig,
             if grad_hook is not None:
                 loss, grads = grad_hook(loss, grads, arm)
             if not guard:
+                with tracing.scope("optimizer"):
+                    new_params, new_opt = optimizer.update(grads, opt_state,
+                                                           params)
+                return new_params, new_opt, {"loss": loss}
+            with tracing.scope("guard"):
+                gnorm = global_norm(grads)
+                ok = jnp.isfinite(loss) & jnp.isfinite(gnorm)
+            with tracing.scope("optimizer"):
                 new_params, new_opt = optimizer.update(grads, opt_state,
                                                        params)
-                return new_params, new_opt, {"loss": loss}
-            gnorm = global_norm(grads)
-            ok = jnp.isfinite(loss) & jnp.isfinite(gnorm)
-            new_params, new_opt = optimizer.update(grads, opt_state, params)
 
             def sel(new, old):
                 return jax.tree_util.tree_map(
@@ -142,7 +147,9 @@ def make_train_step(model, optimizer: Optimizer, plan: Plan, run: RunConfig,
 
             metrics = {"loss": loss, "grad_norm": gnorm,
                        "skipped": jnp.logical_not(ok)}
-        return sel(new_params, params), sel(new_opt, opt_state), metrics
+        with tracing.scope("guard"):
+            return (sel(new_params, params), sel(new_opt, opt_state),
+                    metrics)
 
     return train_step
 
@@ -176,7 +183,7 @@ def make_train_step_proteus(model, optimizer: Optimizer, plan: Plan,
             grads, pod_axis, bits=run.proteus_grad_bits,
             block=run.proteus_block, mean=True, n_pods=n_pods)
         loss = jax.lax.pmean(loss, pod_axis)
-        with use_plan(inner_plan):
+        with use_plan(inner_plan), tracing.scope("optimizer"):
             new_params, new_opt = optimizer.update(grads, opt_state, params)
         return new_params, new_opt, {"loss": loss}
 

@@ -29,6 +29,8 @@ side:
   PYTHONPATH=src python -m repro.launch.train --arch pimref-100m --steps 10 \
       --checkpoint-dir /tmp/ck --checkpoint-every 3 --preempt-after 5 \
       --max-restarts 2 --resume-verify    # byte-identity gate
+  PYTHONPATH=src python -m repro.launch.train --arch pimref-100m --steps 8 \
+      --profile-dir /tmp/profile          # device time by program scope
 """
 from __future__ import annotations
 
@@ -54,6 +56,7 @@ from repro.distributed import (PreemptionHandler, RestartManifest,
                                TrainChaosMonkey)
 from repro.distributed.chaos import nan_grad_hook
 from repro.launch import mesh as mesh_lib
+from repro.launch import tracing
 from repro.launch.steps import make_train_step
 from repro.models import build_model, init_params
 from repro.optim import make_optimizer
@@ -211,14 +214,17 @@ def train(arch: str, *, smoke: bool = True, steps: int = 100,
                 preempt.uninstall()
                 drain_writer()
                 raise
-        b = {k: jnp.asarray(v) for k, v in batch_fn(step).items()}
-        if hook is not None:
-            arm = jnp.asarray(1 if monkey.nan_armed(step) else 0, jnp.int32)
-            params, opt_state, metrics = step_fn(params, opt_state, b, arm)
-        else:
-            params, opt_state, metrics = step_fn(params, opt_state, b)
-        loss = float(metrics["loss"])
-        gnorm = float(metrics["grad_norm"])
+        with tracing.span("train.step", step=step):
+            b = {k: jnp.asarray(v) for k, v in batch_fn(step).items()}
+            if hook is not None:
+                arm = jnp.asarray(1 if monkey.nan_armed(step) else 0,
+                                  jnp.int32)
+                params, opt_state, metrics = step_fn(params, opt_state, b,
+                                                     arm)
+            else:
+                params, opt_state, metrics = step_fn(params, opt_state, b)
+            loss = float(metrics["loss"])            # the step's host sync
+            gnorm = float(metrics["grad_norm"])
         losses.append(loss)
         grad_norms.append(gnorm)
 
@@ -456,8 +462,17 @@ def main() -> None:
                     help="Proteus-quantized KV cache for any eval/serve "
                     "prefill+decode launched from this process (sets "
                     "REPRO_KV_QUANT; the train step itself has no KV cache)")
+    ap.add_argument("--profile-dir", default=None,
+                    help="record a profile of the run into this directory: "
+                    "device ops named by program scope, host spans "
+                    "repro.train.step")
     args = ap.parse_args()
     compat.enable_compilation_cache()
+    with tracing.profile(args.profile_dir):
+        _run(args)
+
+
+def _run(args) -> None:
     if args.attn_impl:
         os.environ["REPRO_ATTN_IMPL"] = args.attn_impl
     if args.kv_quant:

@@ -43,6 +43,7 @@ from repro.kernels.flash_attention.ops import (flash_attention_gqa_fwd,
                                                flash_decode_paged,
                                                flash_decode_paged_quant,
                                                flash_decode_quant)
+from repro.launch import tracing
 
 # Pallas decode kernel: the whole (G, S) query block stays VMEM-resident
 # across the kv stream, so the positional path only routes to it while the
@@ -902,6 +903,13 @@ def _flash_vjp_fwd(q, k, v, causal, window, attn_softcap, cq, ck, block_skip,
 
 def _flash_vjp_bwd(causal, window, attn_softcap, cq, ck, block_skip, kv_len,
                    res, do):
+    with tracing.scope("attn_bwd"):
+        return _flash_bwd_impl(causal, window, attn_softcap, cq, ck,
+                               block_skip, kv_len, res, do)
+
+
+def _flash_bwd_impl(causal, window, attn_softcap, cq, ck, block_skip, kv_len,
+                    res, do):
     q, k, v, out, lse = res
     B, S, Hkv, G, D = q.shape
     T = k.shape[1]

@@ -23,6 +23,7 @@ import jax.numpy as jnp
 from repro.compat import optimization_barrier
 from repro.configs.base import ModelConfig
 from repro.core.mimdram import constrain
+from repro.launch import tracing
 from repro.models import module as mod
 from repro.models.layers import (aligned_cache_len, chunked_attention, dense,
                                  gated_mlp, kv_cache_axes, kv_cache_init,
@@ -117,33 +118,48 @@ class TransformerLM:
     # -- one block -----------------------------------------------------------
     def _block(self, p, x, positions, *, window, block_skip=False):
         cfg = self.cfg
-        # barrier: stops XLA promoting the scan-saved bf16 residual stack to
-        # f32 via convert motion (observed 2x activation memory otherwise)
-        x = optimization_barrier(x)
-        p = mod.constrain_tree(p, self.block_specs())
-        xn = rms_norm(x, p["ln1"], cfg.norm_eps)
-        q, k, v = qkv(cfg, p["attn"], xn, positions)
-        o = chunked_attention(q, k, v, causal=True, window=window, q_offset=0,
-                              chunk_q=cfg.attn_chunk_q,
-                              chunk_kv=cfg.attn_chunk_kv,
-                              block_skip=cfg.attn_block_skip or block_skip)
-        x = x + dense(o, p["attn"]["w_o"], "bshe,hed->bsd")
-        x = constrain(x, "act_batch", "act_seq", "act_embed")
-        xn2 = rms_norm(x, p["ln2"], cfg.norm_eps)
-        if cfg.num_experts > 0:
-            y = moe_ffn(cfg, p["moe"], xn2)
-        else:
-            y = gated_mlp(xn2, p["mlp"]["wi_gate"], p["mlp"]["wi_up"],
-                          p["mlp"]["wo"])
-        x = x + y
-        x = constrain(x, "act_batch", "act_seq", "act_embed")
+        with tracing.scope("attn"):
+            # barrier: stops XLA promoting the scan-saved bf16 residual stack
+            # to f32 via convert motion (observed 2x activation memory
+            # otherwise)
+            x = optimization_barrier(x)
+            p = mod.constrain_tree(p, self.block_specs())
+            xn = rms_norm(x, p["ln1"], cfg.norm_eps)
+            q, k, v = qkv(cfg, p["attn"], xn, positions)
+            o = chunked_attention(q, k, v, causal=True, window=window,
+                                  q_offset=0, chunk_q=cfg.attn_chunk_q,
+                                  chunk_kv=cfg.attn_chunk_kv,
+                                  block_skip=cfg.attn_block_skip or block_skip)
+            x = x + dense(o, p["attn"]["w_o"], "bshe,hed->bsd")
+            x = constrain(x, "act_batch", "act_seq", "act_embed")
+        with tracing.scope("mlp"):
+            x = x + self._ffn(p, rms_norm(x, p["ln2"], cfg.norm_eps))
+            x = constrain(x, "act_batch", "act_seq", "act_embed")
         return x
+
+    def _ffn(self, p, xn2: jax.Array) -> jax.Array:
+        if self.cfg.num_experts > 0:
+            return moe_ffn(self.cfg, p["moe"], xn2)
+        return gated_mlp(xn2, p["mlp"]["wi_gate"], p["mlp"]["wi_up"],
+                         p["mlp"]["wo"])
+
+    def _embed(self, params, tokens: jax.Array) -> jax.Array:
+        with tracing.scope("embed"):
+            return params["embed"].astype(self.cdtype)[tokens]
+
+    def _head(self, params, x: jax.Array, last: bool = False) -> jax.Array:
+        """Final norm and vocabulary projection, of the last position alone
+        with ``last``."""
+        cfg = self.cfg
+        x = rms_norm(x, params["final_norm"], cfg.norm_eps)
+        head = (params["embed"].T if cfg.tie_embeddings else params["head"])
+        return dense(x[:, -1:] if last else x, head, "bsd,dv->bsv")
 
     # -- full-sequence forward (train / prefill) ------------------------------
     def forward(self, params, tokens: jax.Array,
                 patch_embeds: Optional[jax.Array] = None):
         cfg = self.cfg
-        x = params["embed"].astype(self.cdtype)[tokens]
+        x = self._embed(params, tokens)
         if patch_embeds is not None:
             x = jnp.concatenate([patch_embeds.astype(self.cdtype), x], axis=1)
         B, S, _ = x.shape
@@ -157,17 +173,17 @@ class TransformerLM:
         block_fn = body
         if cfg.remat != "none":
             block_fn = jax.checkpoint(body, prevent_cse=False)
-        if cfg.scan_layers:
-            x, _ = jax.lax.scan(block_fn, x, params["blocks"])
-        else:
-            for i in range(cfg.num_layers):
-                layer_p = jax.tree_util.tree_map(lambda a: a[i], params["blocks"])
-                x, _ = block_fn(x, layer_p)
-        x = rms_norm(x, params["final_norm"], cfg.norm_eps)
-        head = (params["embed"].T if cfg.tie_embeddings else params["head"])
-        logits = dense(x, head, "bsd,dv->bsv")
-        logits = constrain(logits, "act_batch", "act_seq", "act_vocab")
-        return logits
+        with tracing.scope("layers"):
+            if cfg.scan_layers:
+                x, _ = jax.lax.scan(block_fn, x, params["blocks"])
+            else:
+                for i in range(cfg.num_layers):
+                    layer_p = jax.tree_util.tree_map(lambda a: a[i],
+                                                     params["blocks"])
+                    x, _ = block_fn(x, layer_p)
+        with tracing.scope("head_loss"):
+            logits = self._head(params, x)
+            return constrain(logits, "act_batch", "act_seq", "act_vocab")
 
     def loss(self, params, batch) -> jax.Array:
         cfg = self.cfg
@@ -179,8 +195,9 @@ class TransformerLM:
             logits = logits[:, P:]
         labels = batch["labels"]
         mask = batch.get("loss_mask")
-        return softmax_xent(logits[:, :-1], labels[:, 1:],
-                            None if mask is None else mask[:, 1:])
+        with tracing.scope("head_loss"):
+            return softmax_xent(logits[:, :-1], labels[:, 1:],
+                                None if mask is None else mask[:, 1:])
 
     # -- serving ---------------------------------------------------------------
     def cache_len(self, max_len: int) -> int:
@@ -221,7 +238,7 @@ class TransformerLM:
         cfg = self.cfg
         tokens = batch["tokens"]
         patch = batch.get("patch_embeds")
-        x = params["embed"].astype(self.cdtype)[tokens]
+        x = self._embed(params, tokens)
         if patch is not None:
             x = jnp.concatenate([patch.astype(self.cdtype), x], axis=1)
         B, S, _ = x.shape
@@ -234,36 +251,35 @@ class TransformerLM:
 
         def body(carry, layer_p):
             h = carry
-            layer_p = mod.constrain_tree(layer_p, self.block_specs())
-            xn = rms_norm(h, layer_p["ln1"], cfg.norm_eps)
-            q, k, v = qkv(cfg, layer_p["attn"], xn, positions)
-            o = chunked_attention(q, k, v, causal=True, window=window,
-                                  q_offset=0, chunk_q=cfg.attn_chunk_q,
-                                  chunk_kv=cfg.attn_chunk_kv,
-                                  block_skip=cfg.attn_block_skip)
-            h = h + dense(o, layer_p["attn"]["w_o"], "bshe,hed->bsd")
-            xn2 = rms_norm(h, layer_p["ln2"], cfg.norm_eps)
-            if cfg.num_experts > 0:
-                y = moe_ffn(cfg, layer_p["moe"], xn2)
-            else:
-                y = gated_mlp(xn2, layer_p["mlp"]["wi_gate"],
-                              layer_p["mlp"]["wi_up"], layer_p["mlp"]["wo"])
-            h = h + y
-            return h, (store(k), store(v))
+            with tracing.scope("attn"):
+                layer_p = mod.constrain_tree(layer_p, self.block_specs())
+                xn = rms_norm(h, layer_p["ln1"], cfg.norm_eps)
+                q, k, v = qkv(cfg, layer_p["attn"], xn, positions)
+                o = chunked_attention(q, k, v, causal=True, window=window,
+                                      q_offset=0, chunk_q=cfg.attn_chunk_q,
+                                      chunk_kv=cfg.attn_chunk_kv,
+                                      block_skip=cfg.attn_block_skip)
+                h = h + dense(o, layer_p["attn"]["w_o"], "bshe,hed->bsd")
+            with tracing.scope("mlp"):
+                h = h + self._ffn(layer_p,
+                                  rms_norm(h, layer_p["ln2"], cfg.norm_eps))
+            with tracing.scope("attn"):
+                return h, (store(k), store(v))
 
-        if cfg.scan_layers:
-            x, (ck, cv) = jax.lax.scan(body, x, params["blocks"])
-        else:
-            ks, vs = [], []
-            for i in range(cfg.num_layers):
-                layer_p = jax.tree_util.tree_map(lambda a: a[i], params["blocks"])
-                x, (k1, v1) = body(x, layer_p)
-                ks.append(k1)
-                vs.append(v1)
-            ck, cv = stack_trees(ks), stack_trees(vs)
-        x = rms_norm(x, params["final_norm"], cfg.norm_eps)
-        head = (params["embed"].T if cfg.tie_embeddings else params["head"])
-        logits = dense(x if full_logits else x[:, -1:], head, "bsd,dv->bsv")
+        with tracing.scope("layers"):
+            if cfg.scan_layers:
+                x, (ck, cv) = jax.lax.scan(body, x, params["blocks"])
+            else:
+                ks, vs = [], []
+                for i in range(cfg.num_layers):
+                    layer_p = jax.tree_util.tree_map(lambda a: a[i],
+                                                     params["blocks"])
+                    x, (k1, v1) = body(x, layer_p)
+                    ks.append(k1)
+                    vs.append(v1)
+                ck, cv = stack_trees(ks), stack_trees(vs)
+        with tracing.scope("head_loss"):
+            logits = self._head(params, x, last=not full_logits)
         cache = {
             "k": ck, "v": cv,
             "pos_ids": ring_position_ids(B, S, T),
@@ -287,7 +303,7 @@ class TransformerLM:
         """
         cfg = self.cfg
         S = tokens.shape[1]
-        x = params["embed"].astype(self.cdtype)[tokens]          # (B,S,D)
+        x = self._embed(params, tokens)                          # (B,S,D)
         pos = cache["pos"]                                       # (B,)
         T = kv_cache_len(cache["k"])
         window = cfg.sliding_window if cfg.attention_kind == "sliding" else 0
@@ -304,23 +320,21 @@ class TransformerLM:
         def body(carry, xs):
             h = carry
             layer_p, ck, cv = xs
-            layer_p = mod.constrain_tree(layer_p, self.block_specs())
-            xn = rms_norm(h, layer_p["ln1"], cfg.norm_eps)
-            q, k, v = qkv(cfg, layer_p["attn"], xn, positions)
-            ck = kv_cache_update(ck, k, slot)
-            cv = kv_cache_update(cv, v, slot)
-            o = chunked_attention(
-                q, kv_cast(ck, h.dtype), kv_cast(cv, h.dtype), causal=True,
-                window=window, q_offset=pos, kv_positions=pos_ids,
-                chunk_kv=min(1024, T))
-            h = h + dense(o, layer_p["attn"]["w_o"], "bshe,hed->bsd")
-            xn2 = rms_norm(h, layer_p["ln2"], cfg.norm_eps)
-            if cfg.num_experts > 0:
-                y = moe_ffn(cfg, layer_p["moe"], xn2)
-            else:
-                y = gated_mlp(xn2, layer_p["mlp"]["wi_gate"],
-                              layer_p["mlp"]["wi_up"], layer_p["mlp"]["wo"])
-            return h + y, (ck, cv)
+            with tracing.scope("attn"):
+                layer_p = mod.constrain_tree(layer_p, self.block_specs())
+                xn = rms_norm(h, layer_p["ln1"], cfg.norm_eps)
+                q, k, v = qkv(cfg, layer_p["attn"], xn, positions)
+                ck = kv_cache_update(ck, k, slot)
+                cv = kv_cache_update(cv, v, slot)
+                o = chunked_attention(
+                    q, kv_cast(ck, h.dtype), kv_cast(cv, h.dtype),
+                    causal=True, window=window, q_offset=pos,
+                    kv_positions=pos_ids, chunk_kv=min(1024, T))
+                h = h + dense(o, layer_p["attn"]["w_o"], "bshe,hed->bsd")
+            with tracing.scope("mlp"):
+                h = h + self._ffn(layer_p,
+                                  rms_norm(h, layer_p["ln2"], cfg.norm_eps))
+            return h, (ck, cv)
 
         tm = jax.tree_util.tree_map
         blocks, ck0, cv0 = params["blocks"], cache["k"], cache["v"]
@@ -328,23 +342,23 @@ class TransformerLM:
             blocks = tm(lambda a: a[:layers], blocks)
             ck0 = tm(lambda a: a[:layers], ck0)
             cv0 = tm(lambda a: a[:layers], cv0)
-        if cfg.scan_layers:
-            x, (ck, cv) = jax.lax.scan(body, x, (blocks, ck0, cv0))
-        else:
-            n_layers = cfg.num_layers if layers is None else layers
-            ks, vs = [], []
-            for i in range(n_layers):
-                xs = tm(lambda a: a[i], (blocks, ck0, cv0))
-                x, (k1, v1) = body(x, xs)
-                ks.append(k1)
-                vs.append(v1)
-            ck, cv = stack_trees(ks), stack_trees(vs)
-        if layers is not None:
-            ck = tm(lambda f, p: f.at[:layers].set(p), cache["k"], ck)
-            cv = tm(lambda f, p: f.at[:layers].set(p), cache["v"], cv)
-        x = rms_norm(x, params["final_norm"], cfg.norm_eps)
-        head = (params["embed"].T if cfg.tie_embeddings else params["head"])
-        logits = dense(x, head, "bsd,dv->bsv")
+        with tracing.scope("layers"):
+            if cfg.scan_layers:
+                x, (ck, cv) = jax.lax.scan(body, x, (blocks, ck0, cv0))
+            else:
+                n_layers = cfg.num_layers if layers is None else layers
+                ks, vs = [], []
+                for i in range(n_layers):
+                    xs = tm(lambda a: a[i], (blocks, ck0, cv0))
+                    x, (k1, v1) = body(x, xs)
+                    ks.append(k1)
+                    vs.append(v1)
+                ck, cv = stack_trees(ks), stack_trees(vs)
+            if layers is not None:
+                ck = tm(lambda f, p: f.at[:layers].set(p), cache["k"], ck)
+                cv = tm(lambda f, p: f.at[:layers].set(p), cache["v"], cv)
+        with tracing.scope("head_loss"):
+            logits = self._head(params, x)
         new_cache = {"k": ck, "v": cv, "pos_ids": pos_ids,
                      "pos": pos + jnp.asarray(S, pos.dtype)}
         return logits, new_cache

@@ -272,6 +272,32 @@ def test_persistent_failure_fails_over_every_request():
     assert eng.step() is False                     # dead engine stays dead
 
 
+def test_submit_to_a_dead_engine_completes_at_once():
+    """A request submitted after a failed dispatch killed the engine gets an
+    immediate typed ``step_failure`` completion and is never queued, so
+    every request still ends in exactly one Completion."""
+    cfg, eng = _engine(slots=1, chaos=ChaosConfig(), max_retries=0,
+                       retry_backoff_s=0.0)
+
+    def always_fail(idx):
+        raise TransientStepError(f"persistent fault at chunk {idx}")
+
+    eng._chaos.on_chunk = always_fail
+    eng.run(_requests(cfg, 1))
+    assert eng.stats["rejected_dead"] == 0
+    late = [Request(uid=r.uid + 10, tokens=r.tokens, max_new_tokens=4)
+            for r in _requests(cfg, 2, seed=1)]
+    assert [eng.submit(r) for r in late] == [False, False]
+    assert not eng._queue
+    assert eng.stats["rejected_dead"] == 2
+    eng.run()                                      # drains at once
+    comps = _by_uid(eng)
+    assert sorted(comps) == [0, 10, 11] and len(eng.completions) == 3
+    for uid in (10, 11):
+        assert comps[uid].reason == "step_failure"
+        assert comps[uid].tokens.size == 0
+
+
 # ---------------------------------------------------------------------------
 # Watchdog + load shedding
 # ---------------------------------------------------------------------------
