@@ -5,14 +5,18 @@ lives only in VMEM; scores never round-trip to HBM (the jnp chunked path
 materializes them — this kernel removes the dominant memory-term contribution
 found by DAMOV for train/prefill cells, and the KV-stream term for decode).
 
-One kernel body serves five entry points:
+The online-softmax kernel body serves five forward entry points, and a
+second body serves the training backward:
 
 * ``flash_attention_fwd`` — prefill/train. Grid ``(B, Hkv, nq, nk)``, kv
   minor => sequential on TPU; the ``G = Hq // Hkv`` grouped query heads of
   one kv head ride in the q block, so each (k, v) tile is fetched from HBM
   once per kv head, not once per query head (GQA without materializing
-  ``jnp.repeat`` copies). Emits ``(out, lse)`` so a recompute backward can
-  run without saved score tiles.
+  ``jnp.repeat`` copies). Emits ``(out, lse)`` so the backward can run
+  without saved score tiles.
+* ``flash_attention_bwd`` — train. One kernel (``flash_bwd``) recomputes
+  each score tile from ``lse`` and writes dq, dk and dv, with the tiles the
+  causal, window and padding masks empty skipped; see its docstring.
 * ``flash_decode_fwd`` — serving. Small q (the fused-decode chunk step)
   against the ring KV cache; one q block, the kv stream on the grid.
 * ``flash_decode_quant_fwd`` — serving over a Proteus-quantized KV cache:
@@ -283,6 +287,255 @@ def flash_attention_fwd(q: jax.Array, k: jax.Array, v: jax.Array,
                        block_q=min(block_q, S), block_k=min(block_k, T),
                        with_lse=True, causal=causal, window=window,
                        softcap=softcap, interpret=interpret)
+
+
+# ---------------------------------------------------------------------------
+# Backward: one kernel writes dq, dk and dv
+# ---------------------------------------------------------------------------
+# A TPU v5e core's default scoped VMEM: the backward's tiles and its resident
+# dk/dv accumulators are sized to stay under it.
+VMEM_BUDGET = 16 * 1024 * 1024
+BWD_Q_ROWS = 1024            # q rows (positions x G heads) per block
+BWD_KV_ROWS = (512, 256, 128)  # kv rows per block, largest that fits first
+
+_NT = (((1,), (1,)), ((), ()))   # a @ b.T
+_NN = (((1,), (0,)), ((), ()))   # a @ b
+_TN = (((0,), (0,)), ((), ()))   # a.T @ b
+
+
+def _kv_block_range(i, block_q: int, block_k: int, n_kv: int, causal: bool,
+                    window: int, kv_len: int):
+    """[lo, hi]: the kv blocks that q block ``i`` (positions ``i * block_q``
+    on) attends to; kv position t >= ``kv_len`` is padding. Python ints or
+    traced scalars (an index map's) alike; the same bounds as
+    :func:`_pair_state`'s ``live`` on whole blocks."""
+    hi = n_kv - 1
+    if causal:
+        hi = jnp.minimum(hi, (i * block_q + block_q - 1) // block_k)
+    if kv_len:
+        hi = jnp.minimum(hi, (kv_len - 1) // block_k)
+    lo = 0
+    if window > 0:
+        lo = jnp.maximum(i * block_q - window + 1, 0) // block_k
+    return lo, hi
+
+
+def _bwd_vmem_bytes(rows: int, block_k: int, T: int, D: int,
+                    itemsize: int) -> int:
+    """VMEM the backward kernel holds: its (block_k, rows) float32 tiles
+    (scores or probabilities, dP, dS, the mask; the compiler reuses the
+    rest), double-buffered input blocks, the dq block and the resident dk/dv
+    accumulators with their output blocks."""
+    tiles = 4 * block_k * rows * 4
+    blocks = 2 * ((2 * rows + 2 * block_k) * D * itemsize + 3 * 8 * rows * 4)
+    dq = D * rows * (4 + 2 * itemsize)
+    dkv = 2 * T * D * (4 + 2 * itemsize)
+    return tiles + blocks + dq + dkv
+
+
+def bwd_blocks(S: int, T: int, G: int, D: int,
+               itemsize: int = 2) -> tuple:
+    """(block_q, block_k, split) for the backward, from shapes: about
+    ``BWD_Q_ROWS`` q rows a block (a multiple of 128 lanes, or all of S);
+    the largest kv block of ``BWD_KV_ROWS`` (or all of T) whose tiles and
+    resident dk/dv fit ``VMEM_BUDGET``; and tiles the masks cut split 2 x 2
+    where the halves stay multiples of 128. Blocks smaller than the
+    sequence let the causal and window skips engage, and the split skips
+    the empty quarter of a diagonal tile."""
+    unit = 128 // math.gcd(G, 128)             # positions per 128 lanes
+    bq = max(unit, BWD_Q_ROWS // G // unit * unit)
+    bq = S if S <= bq else bq
+    for bk in BWD_KV_ROWS:
+        bk = T if T <= bk else bk
+        Tp = -(-T // bk) * bk
+        if _bwd_vmem_bytes(bq * G, bk, Tp, D, itemsize) <= VMEM_BUDGET:
+            split = 2 if (bq * G) % 256 == 0 and bk % 256 == 0 else 1
+            return bq, bk, split
+    raise NotImplementedError(
+        f"flash attention backward: {T} kv positions of head_dim {D} do not "
+        f"fit the kernel's resident dk/dv accumulators in "
+        f"{VMEM_BUDGET >> 20} MiB of VMEM")
+
+
+def _pair_state(q0, q_len: int, k0, k_len: int, causal: bool, window: int,
+                kv_len: int):
+    """(live, whole) of the pairs between q positions [q0, q0 + q_len) and
+    kv positions [k0, k0 + k_len): whether any survives the masks, and
+    whether all do. Python bools where no mask applies."""
+    live, whole = True, True
+    if causal:
+        live = live & (k0 <= q0 + q_len - 1)
+        whole = whole & (k0 + k_len - 1 <= q0)
+    if window > 0:
+        live = live & (k0 + k_len - 1 > q0 - window)
+        whole = whole & (k0 > q0 + q_len - 1 - window)
+    if kv_len:
+        live = live & (k0 < kv_len)
+        whole = whole & (k0 + k_len <= kv_len)
+    return live, whole
+
+
+def _when(cond, fn):
+    """``fn()`` where ``cond`` holds: at trace time for a Python bool."""
+    if cond is True:
+        fn()
+    elif cond is not False:
+        pl.when(cond)(fn)
+
+
+def _flash_bwd_kernel(qp_ref, q_ref, do_ref, lse_ref, dlt_ref, k_ref, v_ref,
+                      dq_ref, dk_ref, dv_ref, dq_acc, dk_acc, dv_acc, *,
+                      scale: float, causal: bool, window: int,
+                      softcap: float, kv_len: int, block_q: int,
+                      block_k: int, G: int, n_q: int, n_kv: int,
+                      split: int):
+    """Grid (B, Hkv, nq, nk), kv innermost. Scores are laid out transposed,
+    (block_k, R), so lse, delta and q positions are lane-dense rows; dV and
+    dK are plain products, and dQ takes dS transposed."""
+    i, j = pl.program_id(2), pl.program_id(3)
+
+    @pl.when((i == 0) & (j == 0))
+    def _init_kv():
+        dk_acc[...] = jnp.zeros_like(dk_acc)
+        dv_acc[...] = jnp.zeros_like(dv_acc)
+
+    @pl.when(j == 0)
+    def _init_q():
+        dq_acc[...] = jnp.zeros_like(dq_acc)
+
+    def update(masked: bool, q_off: int, q_len: int, k_off: int, k_len: int):
+        rows = pl.ds(q_off * G, q_len * G)
+        kvr = pl.ds(k_off, k_len)
+        q, do = q_ref[0, 0, rows, :], do_ref[0, 0, rows, :]    # (r, D)
+        k, v = k_ref[0, 0, kvr, :], v_ref[0, 0, kvr, :]        # (bk, D)
+        s = jax.lax.dot_general(k, q, _NT,
+                                preferred_element_type=jnp.float32) * scale
+        if softcap > 0:
+            t = jnp.tanh(s / softcap)
+            s = softcap * t
+        p = jnp.exp(s - lse_ref[0, 0, :, rows])               # (bk, r)
+        if masked:
+            kp = j * block_k + k_off + jax.lax.broadcasted_iota(
+                jnp.int32, (k_len, 1), 0)
+            qp = qp_ref[:, rows]                               # (1, r)
+            cuts = ([kp <= qp] if causal else []) + (
+                [kp > qp - window] if window > 0 else []) + (
+                [kp < kv_len] if kv_len else [])
+            p = jnp.where(functools.reduce(jnp.logical_and, cuts), p, 0.0)
+        dp = jax.lax.dot_general(v, do, _NT,
+                                 preferred_element_type=jnp.float32)
+        ds = p * (dp - dlt_ref[0, 0, :, rows])
+        if softcap > 0:
+            ds = ds * (1.0 - t * t)
+        # P and dS meet the MXU in the operands' precision
+        p = p.astype(do.dtype)
+        ds = ds.astype(q.dtype)
+        dv_acc[j, kvr, :] += jax.lax.dot_general(
+            p, do, _NN, preferred_element_type=jnp.float32)
+        dk_acc[j, kvr, :] += jax.lax.dot_general(
+            ds, q, _NN, preferred_element_type=jnp.float32)
+        dq_acc[rows, :] += jax.lax.dot_general(
+            ds, k, _TN, preferred_element_type=jnp.float32)
+
+    # skip every pair the masks empty; apply the mask only where it cuts:
+    # a tile it cuts runs as split x split sub-tiles, each skipped, masked
+    # or run whole on its own
+    q0, k0 = i * block_q, j * block_k
+    live, whole = _pair_state(q0, block_q, k0, block_k, causal, window,
+                              kv_len)
+    _when(live & whole, lambda: update(False, 0, block_q, 0, block_k))
+    sq, sk = block_q // split, block_k // split
+
+    def cut_tile():
+        for a in range(split):
+            for c in range(split):
+                lv, wh = _pair_state(q0 + a * sq, sq, k0 + c * sk, sk,
+                                     causal, window, kv_len)
+                _when(lv & wh, functools.partial(update, False, a * sq, sq,
+                                                 c * sk, sk))
+                _when(lv & jnp.logical_not(wh), functools.partial(
+                    update, True, a * sq, sq, c * sk, sk))
+
+    if whole is not True:
+        _when(live & jnp.logical_not(whole), cut_tile)
+
+    @pl.when(j == n_kv - 1)
+    def _write_dq():
+        dq_ref[...] = (dq_acc[...] * scale).astype(dq_ref.dtype).reshape(
+            dq_ref.shape)
+
+    @pl.when(i == n_q - 1)
+    def _write_dkv():
+        dk_ref[0, 0, j] = (dk_acc[j] * scale).astype(dk_ref.dtype)
+        dv_ref[0, 0, j] = dv_acc[j].astype(dv_ref.dtype)
+
+
+def flash_attention_bwd(q: jax.Array, k: jax.Array, v: jax.Array,
+                        do: jax.Array, lse: jax.Array, delta: jax.Array, *,
+                        causal: bool = True, window: int = 0,
+                        softcap: float = 0.0, kv_len: int = 0,
+                        block_q: int, block_k: int, split: int = 1,
+                        interpret: bool = True):
+    """Backward of :func:`flash_attention_fwd` at default positions: q row
+    s at position s, kv row t at position t, and t >= ``kv_len`` masked
+    when ``kv_len`` > 0. Shapes (already padded to block multiples):
+
+    q/do: (B, Hkv, S, G, D)   k/v: (B, Hkv, T, D)
+    lse: (B, Hkv, S, G) f32 from the forward; delta: rowsum(do * out), the
+    same shape, f32. Returns (dq, dk, dv) in the shapes of q, k, v.
+
+    One kernel, grid ``(B, Hkv, nq, nk)``: a q block's G heads ride in its
+    rows as in the forward, so each k/v tile is read once per kv head; dq
+    accumulates over the kv blocks in VMEM, and dk/dv over the q blocks in
+    VMEM accumulators that stay resident for the whole (b, kv head) and are
+    written once. Tiles the masks empty are skipped, and their k/v index
+    maps repeat a live block, so a skipped step fetches nothing; a tile the
+    masks cut runs as ``split`` x ``split`` sub-tiles, each skipped, masked
+    or run whole.
+    """
+    B, Hkv, S, G, D = q.shape
+    T = k.shape[2]
+    assert S % block_q == 0 and T % block_k == 0, (S, T, block_q, block_k)
+    nq, nk, R = S // block_q, T // block_k, block_q * G
+
+    def kv_row(b, h, i, j):
+        lo, hi = _kv_block_range(i, block_q, block_k, nk, causal, window,
+                                 kv_len)
+        return jnp.minimum(jnp.maximum(j, lo), hi)
+
+    q_rows = pl.BlockSpec((1, 1, R, D), lambda b, h, i, j: (b, h, i, 0))
+    row = pl.BlockSpec((1, 1, 1, R), lambda b, h, i, j: (b, h, 0, i))
+    kv = pl.BlockSpec((1, 1, block_k, D),
+                      lambda b, h, i, j: (b, h, kv_row(b, h, i, j), 0))
+    dkv = pl.BlockSpec((1, 1, nk, block_k, D),
+                       lambda b, h, i, j: (b, h, 0, 0, 0))
+    kernel = functools.partial(
+        _flash_bwd_kernel, scale=1.0 / math.sqrt(D), causal=causal,
+        window=window, softcap=softcap, kv_len=kv_len, block_q=block_q,
+        block_k=block_k, G=G, n_q=nq, n_kv=nk, split=split)
+    grid_spec = pallas_prefetch_grid_spec()(
+        num_scalar_prefetch=0,
+        grid=(B, Hkv, nq, nk),
+        in_specs=[pl.BlockSpec((1, R), lambda b, h, i, j: (0, i)),
+                  q_rows, q_rows, row, row, kv, kv],
+        out_specs=[q_rows, dkv, dkv],
+        scratch_shapes=[
+            pallas_vmem_scratch((R, D), jnp.float32),
+            pallas_vmem_scratch((nk, block_k, D), jnp.float32),
+            pallas_vmem_scratch((nk, block_k, D), jnp.float32),
+        ],
+    )
+    dkv_shape = jax.ShapeDtypeStruct((B, Hkv, nk, block_k, D), k.dtype)
+    dq, dk, dv = pl.pallas_call(
+        kernel, grid_spec=grid_spec,
+        out_shape=[jax.ShapeDtypeStruct((B, Hkv, S * G, D), q.dtype),
+                   dkv_shape, dkv_shape],
+        interpret=interpret, name="flash_bwd")(
+        jnp.repeat(jnp.arange(S, dtype=jnp.int32), G)[None],
+        q.reshape(B, Hkv, S * G, D), do.reshape(B, Hkv, S * G, D),
+        lse.reshape(B, Hkv, 1, S * G), delta.reshape(B, Hkv, 1, S * G), k, v)
+    return (dq.reshape(q.shape), dk.reshape(B, Hkv, T, D),
+            dv.reshape(B, Hkv, T, D))
 
 
 def flash_decode_fwd(q: jax.Array, k: jax.Array, v: jax.Array,

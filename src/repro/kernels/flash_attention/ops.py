@@ -20,14 +20,17 @@ import jax
 import jax.numpy as jnp
 
 from repro.kernels.common import pad_axis, pad_positions, use_interpret
-from repro.kernels.flash_attention.kernel import (flash_attention_bh,
+from repro.kernels.flash_attention.kernel import (bwd_blocks,
+                                                 flash_attention_bh,
+                                                 flash_attention_bwd,
                                                  flash_attention_fwd,
                                                  flash_decode_fwd,
                                                  flash_decode_paged_fwd,
                                                  flash_decode_paged_quant_fwd,
                                                  flash_decode_quant_fwd)
 
-__all__ = ["flash_attention", "flash_attention_gqa_fwd", "flash_decode",
+__all__ = ["flash_attention", "flash_attention_gqa_fwd",
+           "flash_attention_gqa_bwd", "flash_decode",
            "flash_decode_quant", "flash_decode_paged",
            "flash_decode_paged_quant", "flash_attention_bh"]
 
@@ -58,8 +61,8 @@ def flash_attention_gqa_fwd(
 ) -> Tuple[jax.Array, jax.Array]:
     """Pallas forward, any S/T. q: (B, S, Hq, D), k/v: (B, T, Hkv, D).
 
-    Returns (out (B, S, Hq, D), lse (B, Hkv, G, S) f32) — lse is what a
-    recompute-based backward needs instead of saved score tiles.
+    Returns (out (B, S, Hq, D), lse (B, Hkv, G, S) f32) — lse is what
+    :func:`flash_attention_gqa_bwd` needs instead of saved score tiles.
     """
     if interpret is None:
         interpret = use_interpret()
@@ -80,6 +83,44 @@ def flash_attention_gqa_fwd(
         causal=causal, window=window, softcap=softcap,
         block_q=bq, block_k=bk, interpret=interpret)
     return _from_kernel(out5)[:, :S], lse.transpose(0, 1, 3, 2)[..., :S]
+
+
+def flash_attention_gqa_bwd(
+    q: jax.Array, k: jax.Array, v: jax.Array, out: jax.Array,
+    lse: jax.Array, do: jax.Array, *, causal: bool = True, window: int = 0,
+    softcap: float = 0.0, kv_len: int = 0,
+    interpret: Optional[bool] = None,
+) -> Tuple[jax.Array, jax.Array, jax.Array]:
+    """Pallas backward of :func:`flash_attention_gqa_fwd` at default
+    positions, any S/T; ``kv_len`` > 0 masks kv positions >= kv_len.
+
+    q/out/do: (B, S, Hq, D)   k/v: (B, T, Hkv, D)   lse: (B, Hkv, G, S) f32,
+    as the forward returned it. Returns (dq, dk, dv) in the layouts of q, k
+    and v. Block sizes come from the shapes (``bwd_blocks``); S and T are
+    padded to them, and padded rows carry zero ``do`` and ``delta``, so
+    they add nothing.
+    """
+    if interpret is None:
+        interpret = use_interpret()
+    B, S, Hq, D = q.shape
+    _, T, Hkv, _ = k.shape
+    G = Hq // Hkv
+    bq, bk, split = bwd_blocks(S, T, G, D, jnp.dtype(k.dtype).itemsize)
+    Sp = -(-S // bq) * bq
+    Tp = -(-T // bk) * bk
+    delta = (do.astype(jnp.float32) * out.astype(jnp.float32)).sum(-1)
+    delta = delta.reshape(B, S, Hkv, G).transpose(0, 2, 1, 3)
+    dq5, dk, dv = flash_attention_bwd(
+        _to_kernel(pad_axis(q, 1, Sp), Hkv),
+        pad_axis(k, 1, Tp).transpose(0, 2, 1, 3),
+        pad_axis(v, 1, Tp).transpose(0, 2, 1, 3),
+        _to_kernel(pad_axis(do, 1, Sp), Hkv),
+        pad_axis(lse.transpose(0, 1, 3, 2), 2, Sp), pad_axis(delta, 2, Sp),
+        causal=causal, window=window, softcap=softcap,
+        kv_len=kv_len or (T if Tp != T else 0), block_q=bq, block_k=bk,
+        split=split, interpret=interpret)
+    return (_from_kernel(dq5)[:, :S], dk.transpose(0, 2, 1, 3)[:, :T],
+            dv.transpose(0, 2, 1, 3)[:, :T])
 
 
 @partial(jax.jit, static_argnames=("causal", "window", "softcap", "block_q",

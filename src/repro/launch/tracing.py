@@ -25,9 +25,17 @@ import jax
 # device scopes, by what they hold: ``layers`` is the scanned block stack's
 # own work (slicing each layer's weights out of the stacked parameters,
 # writing residuals and gradients into stacked buffers); ``attn``, ``mlp``
-# and ``attn_bwd`` (the jnp flash backward) lie inside it
+# and ``attn_bwd`` (the flash backward: the Pallas kernel with its layout
+# work, or the jnp recompute backward) lie inside it
 SCOPES = ("embed", "layers", "attn", "attn_bwd", "mlp", "head_loss",
           "optimizer", "guard")
+# Pallas kernels by their ``pallas_call`` name, which names the kernel's op
+# in a profile (``pallas:flash_bwd``): ``flash_fwd`` and ``flash_bwd`` are
+# the training attention's forward and backward
+KERNELS = ("flash_fwd", "flash_bwd", "flash_decode", "flash_decode_paged",
+           "flash_decode_quant", "flash_decode_paged_quant", "quant_matmul",
+           "rglru_scan", "narrow_required_bits", "narrow_pack_int4",
+           "narrow_unpack_int4")
 SPAN_PREFIX = "repro."
 # host spans (without the prefix) and the ids each carries
 SPANS = {

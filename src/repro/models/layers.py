@@ -6,7 +6,7 @@ family and the serving engine. It dispatches between two backends
 elsewhere):
 
 * **pallas** — the ``repro.kernels.flash_attention`` TPU kernels: GQA-native
-  prefill/train forward with a recompute-based custom VJP, and a
+  prefill/train forward and its backward kernel under a custom VJP, and a
   decode-specialized kernel streaming the ring KV cache.
 * **jnp** — the chunked online-softmax implementation below (same flash
   structure in pure jnp); the oracle the Pallas path is tested against.
@@ -38,7 +38,8 @@ from repro.core.proteus import required_bits_float
 from repro.kernels.common import (attn_impl, kv_page_size, kv_quant_mode,
                                   on_tpu, pack_int4, pad_axis, pad_positions,
                                   unpack_int4)
-from repro.kernels.flash_attention.ops import (flash_attention_gqa_fwd,
+from repro.kernels.flash_attention.ops import (flash_attention_gqa_bwd,
+                                               flash_attention_gqa_fwd,
                                                flash_decode,
                                                flash_decode_paged,
                                                flash_decode_paged_quant,
@@ -994,16 +995,17 @@ flash_attention_jnp.defvjp(_flash_vjp_fwd, _flash_vjp_bwd)
 
 
 # ---------------------------------------------------------------------------
-# Pallas-backed flash attention (train/prefill): Pallas forward kernel,
-# recompute-based jnp backward from (out, lse) — same residual contract as
-# flash_attention_jnp, so training runs on the TPU kernel with O(S)
-# activation memory and no saved score tiles.
+# Pallas-backed flash attention (train/prefill): the Pallas forward kernel
+# saves (out, lse), and the Pallas backward kernel recomputes each score tile
+# from them in VMEM and writes dq, dk and dv. Training runs both on the TPU
+# with O(S) activation memory and no score tiles in HBM. The residuals are
+# private to this pair; flash_attention_jnp keeps its own.
 # ---------------------------------------------------------------------------
 def _flash_pallas_fwd_impl(q, k, v, causal, window, attn_softcap, cq, ck,
                            kv_len, interpret):
     """q:(B,S,Hkv,G,D) k/v:(B,T,Hkv,D); S % cq == 0, T % ck == 0 (caller
     pads); kv_len > 0 masks kv positions >= kv_len. Returns (out, lse) with
-    lse (B,K,G,nq,cq) — the flash_attention_jnp residual layout."""
+    lse (B,Hkv,G,S) f32, as the kernel wrapper gives it."""
     B, S, Hkv, G, D = q.shape
     T = k.shape[1]
     ar = jnp.arange(T, dtype=jnp.int32)
@@ -1015,8 +1017,7 @@ def _flash_pallas_fwd_impl(q, k, v, causal, window, attn_softcap, cq, ck,
         lambda q, k, v, kv_pos: flash_attention_gqa_fwd(
             q, k, v, kv_positions=kv_pos, **kw),
         q.reshape(B, S, Hkv * G, D), k, v, kv_pos)
-    return (out.reshape(B, S, Hkv, G, D),
-            lse.reshape(B, Hkv, G, S // cq, cq))
+    return out.reshape(B, S, Hkv, G, D), lse
 
 
 @partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8, 9))
@@ -1036,8 +1037,16 @@ def _flash_pallas_vjp_fwd(q, k, v, causal, window, attn_softcap, cq, ck,
 
 def _flash_pallas_vjp_bwd(causal, window, attn_softcap, cq, ck, kv_len,
                           interpret, res, do):
-    return _flash_vjp_bwd(causal, window, attn_softcap, cq, ck, False, kv_len,
-                          res, do)
+    q, k, v, out, lse = res
+    B, S, Hkv, G, D = q.shape
+    kw = dict(causal=causal, window=window, softcap=attn_softcap,
+              kv_len=kv_len, interpret=interpret)
+    rows = (B, S, Hkv * G, D)
+    with tracing.scope("attn_bwd"):
+        dq, dk, dv = _per_batch_shard(
+            partial(flash_attention_gqa_bwd, **kw), q.reshape(rows), k, v,
+            out.reshape(rows), lse, do.reshape(rows))
+    return dq.reshape(q.shape), dk, dv
 
 
 flash_attention_pallas.defvjp(_flash_pallas_vjp_fwd, _flash_pallas_vjp_bwd)

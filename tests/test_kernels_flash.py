@@ -13,8 +13,7 @@ import pytest
 from repro.kernels.common import attn_impl, kv_quant_mode
 from repro.kernels.flash_attention import flash_attention, flash_attention_bh
 from repro.models.layers import (KV_ERROR_BUDGET, attention_ref,
-                                 chunked_attention, flash_attention_jnp,
-                                 flash_attention_pallas, kv_dequantize,
+                                 chunked_attention, kv_dequantize,
                                  kv_quantize, ring_cache_store,
                                  ring_position_ids)
 
@@ -77,28 +76,105 @@ def test_flash_bf16(rng):
 
 
 # ---------------------------------------------------------------------------
-# Gradients: Pallas fwd + recompute bwd vs the jnp flash path and the oracle
+# Gradients: the Pallas forward and backward kernels vs the jnp flash path
+# and the oracle
 # ---------------------------------------------------------------------------
-@pytest.mark.parametrize("win,cap,G", [(0, 0.0, 2), (48, 0.0, 1),
-                                       (0, 20.0, 4)])
-def test_flash_pallas_grads(win, cap, G, rng):
-    B, S, Hkv, D = 2, 128, 2, 32
-    q, k, v = _qkv(rng, B, S, S, G * Hkv, Hkv, D)
+F32, BF16 = jnp.float32, jnp.bfloat16
+
+
+def _rel_gap(a, b):
+    a, b = np.asarray(a, np.float32), np.asarray(b, np.float32)
+    return float(np.linalg.norm(a - b) / np.linalg.norm(b))
+
+
+@pytest.mark.parametrize("win,cap,G,S,dtype", [
+    pytest.param(0, 0.0, 2, 128, F32, id="0-0.0-2"),
+    pytest.param(48, 0.0, 1, 128, F32, id="48-0.0-1"),
+    pytest.param(0, 20.0, 4, 128, F32, id="0-20.0-4"),
+    pytest.param(0, 0.0, 1, 128, F32, id="causal-G1"),
+    pytest.param(48, 20.0, 2, 128, F32, id="window-softcap-G2"),
+    pytest.param(0, 0.0, 2, 100, F32, id="odd-kv_len-G2"),
+    pytest.param(48, 0.0, 1, 100, F32, id="odd-kv_len-window-G1"),
+    pytest.param(0, 0.0, 2, 600, F32, id="tile-skip-G2"),
+    pytest.param(0, 0.0, 2, 128, BF16, id="bf16-causal-G2"),
+    pytest.param(48, 20.0, 1, 128, BF16, id="bf16-window-softcap-G1"),
+])
+def test_flash_pallas_grads(win, cap, G, S, dtype, rng):
+    """dq/dk/dv of the Pallas path (forward and backward kernels, through
+    the dispatch: odd S pads to the chunk and masks kv past it by kv_len;
+    S = 600 gives the backward 3 q blocks and 2 kv blocks, so it skips the
+    tiles above the diagonal) against the jnp flash backward and the
+    oracle's autodiff."""
+    B, Hkv, D = 2, 2, 32
+    q, k, v = _qkv(rng, B, S, S, G * Hkv, Hkv, D, dtype)
     do = jax.random.normal(jax.random.split(rng, 4)[3], q.shape)
-    qg = q.reshape(B, S, Hkv, G, D)
 
-    def f_pallas(qg, k, v):
-        return (flash_attention_pallas(qg, k, v, True, win, cap, 64, 64, 0,
-                                       True).reshape(q.shape) * do).sum()
+    def grads(attn):
+        def f(q, k, v):
+            return (attn(q, k, v).astype(F32) * do).sum()
+        return jax.grad(f, argnums=(0, 1, 2))(q, k, v)
 
-    def f_jnp(qg, k, v):
-        return (flash_attention_jnp(qg, k, v, True, win, cap, 64, 64, False,
-                                    0).reshape(q.shape) * do).sum()
+    def dispatch(impl):
+        return grads(lambda q, k, v: chunked_attention(
+            q, k, v, causal=True, window=win, attn_softcap=cap, chunk_q=64,
+            chunk_kv=64, impl=impl))
 
-    gp = jax.grad(f_pallas, argnums=(0, 1, 2))(qg, k, v)
-    gj = jax.grad(f_jnp, argnums=(0, 1, 2))(qg, k, v)
-    for a, b in zip(gp, gj):
-        np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=2e-4)
+    gp, gj = dispatch("pallas"), dispatch("jnp")
+    gr = grads(lambda q, k, v: attention_ref(
+        q.astype(F32), k.astype(F32), v.astype(F32), causal=True,
+        window=win, attn_softcap=cap))
+    for a, j, r in zip(gp, gj, gr):
+        if dtype == F32:
+            # float32 products on both paths: only the order of the sums
+            # differs (largest seen 1e-5 on gradients of order 1)
+            np.testing.assert_allclose(np.asarray(a), np.asarray(j),
+                                       atol=5e-5)
+            np.testing.assert_allclose(np.asarray(a), np.asarray(r),
+                                       atol=5e-5)
+        else:
+            # bf16 operands, and P and dS rounded to bf16 for the MXU where
+            # the jnp path keeps them float32, then bf16 gradients: a few
+            # bf16 roundings (2^-9 each) of relative error: seen 3.5e-3,
+            # and 2.9e-3 on the jnp path
+            assert _rel_gap(a, r) < 1e-2
+            assert _rel_gap(a, r) < 2 * _rel_gap(j, r) + 2e-3
+
+
+@pytest.mark.parametrize("split", [1, 2])
+@pytest.mark.parametrize("causal,window,kv_len", [
+    (True, 0, 0), (True, 80, 0), (True, 0, 250), (False, 0, 250),
+])
+def test_flash_bwd_tile_skip_matches_one_tile(causal, window, kv_len, split,
+                                              rng):
+    """The backward kernel with small blocks, where whole tiles are
+    skipped (above the diagonal, behind the window, past kv_len), the tiles
+    the masks cut run masked (split into 2 x 2 sub-tiles, each skipped,
+    masked or run whole, with ``split`` 2), equals one unsplit tile over
+    everything, where nothing is skipped."""
+    from repro.kernels.flash_attention import flash_attention_fwd
+    from repro.kernels.flash_attention.kernel import (_kv_block_range,
+                                                      flash_attention_bwd)
+    B, Hkv, G, S, D, bq, bk = 1, 2, 2, 512, 32, 128, 256
+    ks = jax.random.split(rng, 4)
+    q = jax.random.normal(ks[0], (B, Hkv, S, G, D))
+    k = jax.random.normal(ks[1], (B, Hkv, S, D))
+    v = jax.random.normal(ks[2], (B, Hkv, S, D))
+    do = jax.random.normal(ks[3], q.shape)
+    pos = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32), (B, S))
+    kv_pos = jnp.where(pos < kv_len, pos, -1) if kv_len else pos
+    kw = dict(causal=causal, window=window)
+    out, lse = flash_attention_fwd(q, k, v, pos, kv_pos, block_q=S,
+                                   block_k=S, **kw)
+    delta = (do * out).sum(-1)
+    ranges = [_kv_block_range(i, bq, bk, S // bk, causal, window, kv_len)
+              for i in range(S // bq)]
+    assert any(lo > 0 or hi < S // bk - 1 for lo, hi in ranges)
+    one = flash_attention_bwd(q, k, v, do, lse, delta, kv_len=kv_len,
+                              block_q=S, block_k=S, **kw)
+    tiled = flash_attention_bwd(q, k, v, do, lse, delta, kv_len=kv_len,
+                                block_q=bq, block_k=bk, split=split, **kw)
+    for a, b in zip(tiled, one):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=1e-5)
 
 
 def test_flash_pallas_grads_odd_length_via_dispatch(rng):

@@ -4,8 +4,11 @@ Interpret mode accepts block shapes the TPU compiler refuses, so each kernel
 of the serving and training paths is compiled ahead of time here for one
 chip of a described (not attached) ``v5e:2x2`` topology: stablelm-1.6b's
 attention (32 kv heads, head_dim 64, bf16) with 8 slots of a 576-token cache
-and a 512-token prefill. Nothing runs; a refused block shape, a kernel that
-does not lower, or scratch that overflows VMEM fails the compile.
+and a 512-token prefill; and the training attention's forward and backward
+kernels at internlm2-1.8b-6l's per-row shapes (1024 tokens, 16 query heads,
+8 kv heads, head_dim 128, bf16). Nothing runs; a refused block shape, a
+kernel that does not lower, or scratch that overflows VMEM fails the
+compile.
 
 The topology is described inside a fixture only: one process at a time may
 hold the TPU library, so no module-level code here may touch it.
@@ -21,13 +24,14 @@ from repro.compat import make_mesh
 from repro.configs import ShapeConfig, get_config
 from repro.core.mimdram import plan_sharding, use_plan
 from repro.kernels.flash_attention import ops
-from repro.models.layers import chunked_attention
+from repro.models.layers import chunked_attention, flash_attention_pallas
 
 H, D = 32, 64                  # stablelm-1.6b kv heads and head_dim
 B, T, S = 8, 576, 512          # slots, cache length, prefill bucket
 PS = 128                       # chip_smoke.py's page size
 NP = -(-T // PS)
 P = B * NP + 1                 # engine pool: every slot's pages + trash page
+TS, TQ, TKV, TD = 1024, 16, 8, 128   # the train cell's row: internlm2-1.8b
 
 
 @pytest.fixture(scope="module")
@@ -59,11 +63,25 @@ def _sd(sharding, shape, dtype):
     return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
 
 
+def _train_attention_grads(q, k, v):
+    """dq, dk, dv of the train step's Pallas attention on one row: the
+    forward kernel with its chunks, the backward kernel with its own."""
+    def loss(q, k, v):
+        qg = q.reshape(1, TS, TKV, TQ // TKV, TD)
+        return flash_attention_pallas(qg, k, v, True, 0, 0.0, 512, 1024, 0,
+                                      False).astype(jnp.float32).sum()
+    return jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
+
+
 def _cases(sh):
     bf, i8, i32, f32 = jnp.bfloat16, jnp.int8, jnp.int32, jnp.float32
     q1 = _sd(sh, (B, 1, H, D), bf)
     qp, kp = _sd(sh, (B, 1), i32), _sd(sh, (B, T), i32)
     return {
+        "prefill_bwd": (
+            _train_attention_grads,
+            [_sd(sh, (1, TS, TQ, TD), bf), _sd(sh, (1, TS, TKV, TD), bf),
+             _sd(sh, (1, TS, TKV, TD), bf)]),
         "prefill": (
             lambda q, k, v: ops.flash_attention_gqa_fwd(
                 q, k, v, block_q=512, block_k=1024, interpret=False),
@@ -93,7 +111,8 @@ def _cases(sh):
 
 
 @pytest.mark.parametrize(
-    "name", ["prefill", "decode", "decode_int8", "paged", "paged_int8"])
+    "name", ["prefill", "decode", "decode_int8", "paged", "paged_int8",
+             "prefill_bwd"])
 def test_flash_kernel_compiles_for_v5e(name, one_chip):
     fn, args = _cases(one_chip)[name]
     compiled = jax.jit(fn).lower(*args).compile()
@@ -108,8 +127,8 @@ def test_flash_kernel_compiles_for_v5e(name, one_chip):
 def test_attention_compiles_per_batch_shard_on_4_chips(topo, monkeypatch):
     """XLA cannot partition a compiled Pallas kernel: under a 4-chip
     data-parallel plan (what training does on a 4-chip host) the prefill
-    kernel, its backward and the decode kernel must compile per batch
-    shard."""
+    kernel, its backward kernel (dq, dk and dv) and the decode kernel must
+    compile per batch shard."""
     monkeypatch.setenv("REPRO_PALLAS_INTERPRET", "0")
     mesh = make_mesh((4,), ("data",), devices=topo.devices[:4])
     plan = plan_sharding(get_config("pimref-100m", smoke=True),
@@ -120,11 +139,12 @@ def test_attention_compiles_per_batch_shard_on_4_chips(topo, monkeypatch):
 
     def step(q, k, v):
         with use_plan(plan):
-            def prefill(q):
-                return chunked_attention(q, k, v, impl="pallas")
-            grad = jax.grad(lambda q: prefill(q).astype(jnp.float32).sum())
-            return grad(q), chunked_attention(q[:, -1:], k, v, q_offset=off,
-                                              impl="pallas")
+            grad = jax.grad(lambda q, k, v: chunked_attention(
+                q, k, v, impl="pallas").astype(jnp.float32).sum(),
+                argnums=(0, 1, 2))
+            return grad(q, k, v), chunked_attention(
+                q[:, -1:], k, v, q_offset=off, impl="pallas")
 
     text = jax.jit(step).lower(x, x, x).compile().as_text()
     assert "tpu_custom_call" in text
+    assert "flash_bwd" in text

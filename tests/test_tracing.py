@@ -71,6 +71,19 @@ def test_scopes_change_metadata_only(monkeypatch):
     assert any("transpose(jvp(" in n and "/attn_bwd/" in n for n in names)
 
 
+def test_kernel_names_are_listed():
+    """Every literal ``pallas_call`` name in the kernels is one that
+    tracing lists, the flash backward among them."""
+    src = os.path.join(os.path.dirname(__file__), "..", "src", "repro",
+                       "kernels")
+    names = set()
+    for path in glob.glob(os.path.join(src, "**", "*.py"), recursive=True):
+        with open(path) as f:
+            names |= set(re.findall(r'name="(\w+)"', f.read()))
+    assert "flash_bwd" in names
+    assert names <= set(tracing.KERNELS)
+
+
 def test_names_are_listed():
     """Every scope and span the program opens is one that tracing lists,
     with the ids listed for it."""
