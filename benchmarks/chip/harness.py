@@ -58,7 +58,7 @@ def run_cell(cell: spec.Cell, *, seed: int, seconds: float, trace: bool,
 
     metrics: Dict[str, Any] = {}
     for m in (cell.per_layer if trace else cell.end_to_end):
-        value = spec.load_reader(m["name"])(rec)
+        value = spec.load_reader(m["name"], cell.root)(rec)
         if value is None or not math.isfinite(value):
             continue
         metrics[m["name"]] = {"value": float(value), "unit": m["unit"]}

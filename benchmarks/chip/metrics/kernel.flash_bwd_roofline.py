@@ -9,27 +9,18 @@ the calls are the executions of the op that runs once a call,
 ``pallas:flash_bwd_dkv`` where dq has a kernel of its own, else
 ``pallas:flash_bwd``. Silent where the step has no such op."""
 from benchmarks.chip.roofline import share
+from benchmarks.chip.trace import kernel_time, ops_in
 
 MODULE = "train_step"
 PREFIX = "pallas:flash_bwd"
 ONCE_A_CALL = ("pallas:flash_bwd_dkv", "pallas:flash_bwd")
 
 
-def _in_step(table):
-    out = {}
-    for module, ops in table.items():
-        if MODULE in module:
-            for name, v in ops.items():
-                if name.startswith(PREFIX):
-                    out[name] = out.get(name, 0.0) + v
-    return out
-
-
 def read(run):
     if not run.trace:
         return None
-    t = sum(_in_step(run.trace["op_in_module_s"]).values())
-    calls = _in_step(run.trace["op_in_module_n"])
+    t = kernel_time(run.trace, MODULE, PREFIX)
+    calls = ops_in(run.trace, MODULE, PREFIX, "op_in_module_n")
     n = next((calls[k] for k in ONCE_A_CALL if calls.get(k)), 0.0)
     if t <= 0 or n <= 0:
         return None

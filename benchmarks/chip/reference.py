@@ -1,10 +1,11 @@
-"""The plain reference: the dense LM written out in jax.numpy, in float32 at
-the highest matrix precision, with no kernels, cache or batching tricks.
+"""The plain reference, in jax.numpy, in float32 at the highest matrix
+precision, with no kernels, cache or batching tricks: the parts every model
+family shares. Each family's forward pass lives in ``families/<family>.py``
+and is built from ``mm`` and ``rms_norm``; here are the loss, the gradients
+summed over blocks of rows, and AdamW with global-norm clipping, linear
+warm-up and cosine decay, as the training traffic file states them.
 
-It imports nothing of the program. Its block is the one the configuration
-files describe (RMSNorm, rotary on every head dim, SwiGLU, no biases), and
-its optimizer is AdamW with global-norm clipping, linear warm-up and cosine
-decay, as the training traffic file states them.
+It imports nothing of the program.
 
 ``mode="fp8"`` is the control: every matrix product takes its operands
 rounded to float8 e4m3 (one scale per tensor), in the forward and, for
@@ -63,88 +64,6 @@ def rms_norm(x, w, eps):
     return x * jax.lax.rsqrt(jnp.mean(x * x, -1, keepdims=True) + eps) * w
 
 
-def rotary(x, pos, theta):
-    """Rotate-half rotary embedding over every head dim; x (B,S,H,D)."""
-    half = x.shape[-1] // 2
-    freq = theta ** (-jnp.arange(half, dtype=jnp.float32) / half)
-    ang = pos.astype(jnp.float32)[:, None] * freq[None, :]
-    cos, sin = jnp.cos(ang)[None, :, None], jnp.sin(ang)[None, :, None]
-    x1, x2 = x[..., :half], x[..., half:]
-    return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], -1)
-
-
-def block(p: Dict[str, jax.Array], x: jax.Array, m: Dict[str, Any],
-          mode: str) -> jax.Array:
-    """One decoder block over x (B,S,d), causal, positions 0..S-1."""
-    S = x.shape[1]
-    f32 = lambda a: a.astype(jnp.float32)
-    pos = jnp.arange(S)
-    h = rms_norm(x, f32(p["ln1"]), m["norm_eps"])
-    q = rotary(mm("bsd,dhe->bshe", h, p["w_q"], mode), pos, m["rope_theta"])
-    k = rotary(mm("bsd,dhe->bshe", h, p["w_k"], mode), pos, m["rope_theta"])
-    v = mm("bsd,dhe->bshe", h, p["w_v"], mode)
-    g = m["num_heads"] // m["num_kv_heads"]
-    if g > 1:
-        k, v = jnp.repeat(k, g, axis=2), jnp.repeat(v, g, axis=2)
-    s = mm("bqhd,bkhd->bhqk", q, k, mode) / math.sqrt(q.shape[-1])
-    causal = jnp.tril(jnp.ones((S, S), bool))
-    s = jnp.where(causal[None, None], s, -jnp.inf)
-    a = jax.nn.softmax(s, axis=-1)
-    o = mm("bhqk,bkhd->bqhd", a, v, mode)
-    x = x + mm("bshe,hed->bsd", o, p["w_o"], mode)
-    h = rms_norm(x, f32(p["ln2"]), m["norm_eps"])
-    u = jax.nn.silu(mm("bsd,df->bsf", h, p["wi_gate"], mode)) \
-        * mm("bsd,df->bsf", h, p["wi_up"], mode)
-    return x + mm("bsf,fd->bsd", u, p["wo"], mode)
-
-
-def _layer_params(params: Dict[str, Any], i) -> Dict[str, jax.Array]:
-    b = params["blocks"]
-    return {"ln1": b["ln1"][i], "ln2": b["ln2"][i],
-            **{k: v[i] for k, v in b["attn"].items()},
-            **{k: v[i] for k, v in b["mlp"].items()}}
-
-
-def _head(params):
-    return params["embed"].T if "head" not in params else params["head"]
-
-
-def forward(params: Dict[str, Any], tokens: jax.Array, m: Dict[str, Any],
-            mode: str = "f32") -> jax.Array:
-    """Logits (B,S,V) in float32: the whole model in one function."""
-    x = params["embed"][tokens].astype(jnp.float32)
-    for i in range(m["num_layers"]):
-        x = block(_layer_params(params, i), x, m, mode)
-    x = rms_norm(x, params["final_norm"].astype(jnp.float32), m["norm_eps"])
-    return mm("bsd,dv->bsv", x, _head(params), mode)
-
-
-# -- serving: layer by layer, logits only at the rows that are compared ------
-@partial(jax.jit, static_argnames=("mode", "mkey"))
-def _block_jit(p, x, mode, mkey):
-    return block(p, x, dict(mkey), mode)
-
-
-@partial(jax.jit, static_argnames=("mode", "eps"))
-def _rows_logits(x, norm, head, rows, mode, eps):
-    x = rms_norm(x, norm.astype(jnp.float32), eps)
-    xr = jnp.take_along_axis(x, rows[:, :, None], axis=1)
-    return mm("bsd,dv->bsv", xr, head, mode)
-
-
-def row_logits(params: Dict[str, Any], tokens: np.ndarray, rows: np.ndarray,
-               m: Dict[str, Any], mode: str = "f32") -> jax.Array:
-    """Logits (B,R,V) at positions ``rows`` (B,R) of ``tokens`` (B,S), one
-    layer at a time so that only one layer's float32 copy is ever live."""
-    mkey = tuple(sorted((k, v) for k, v in m.items()
-                        if isinstance(v, (int, float, str, bool))))
-    x = params["embed"][jnp.asarray(tokens)].astype(jnp.float32)
-    for i in range(m["num_layers"]):
-        x = _block_jit(_layer_params(params, i), x, mode, mkey)
-    return _rows_logits(x, params["final_norm"], _head(params),
-                        jnp.asarray(rows, jnp.int32), mode, m["norm_eps"])
-
-
 # -- training: loss, gradients in row blocks, and AdamW ----------------------
 def xent_sum(logits: jax.Array, tokens: jax.Array) -> jax.Array:
     """Next-token cross entropy summed over every position of every row."""
@@ -153,8 +72,10 @@ def xent_sum(logits: jax.Array, tokens: jax.Array) -> jax.Array:
     return jnp.sum(lse - tgt)
 
 
-def make_loss_and_grad(m: Dict[str, Any], mode: str = "f32") -> Callable:
-    """jit((params, tokens block) -> (summed loss, summed gradients))."""
+def make_loss_and_grad(forward: Callable, m: Dict[str, Any],
+                       mode: str = "f32") -> Callable:
+    """jit((params, tokens block) -> (summed loss, summed gradients)) of a
+    family's ``forward``."""
     def f(params, tokens):
         return xent_sum(forward(params, tokens, m, mode), tokens)
     return jax.jit(jax.value_and_grad(f))
@@ -228,13 +149,15 @@ def adamw_step(params, grads, mu, nu, t, lr, *, b1, b2, eps, wd, clip):
     return params, gnorm, mu, nu
 
 
-def train_steps(params0: Any, batches: Sequence[np.ndarray],
-                m: Dict[str, Any], run: Dict[str, Any], mode: str = "f32",
+def train_steps(forward: Callable, params0: Any,
+                batches: Sequence[np.ndarray], m: Dict[str, Any],
+                run: Dict[str, Any], mode: str = "f32",
                 rows_per_block: int = 2) -> Dict[str, Any]:
-    """The reference's first steps from ``params0`` (left as it is): each
+    """A family's ``forward`` through its first steps from ``params0`` (left
+    as it is): each
     step's loss, the leaf norms of the first step's clipped gradient, and the
     leaf norms of the parameters' change over all the steps."""
-    vg = make_loss_and_grad(m, mode)
+    vg = make_loss_and_grad(forward, m, mode)
     params = jax.tree_util.tree_map(
         lambda p: jnp.array(p, jnp.float32, copy=True), params0)
     mu = jax.tree_util.tree_map(jnp.zeros_like, params)

@@ -1,4 +1,7 @@
-"""Operations and bytes from shapes, the table of peaks, and shares of them.
+"""Operations and bytes of attention from shapes, the table of peaks, and
+shares of them. A model family's own counts (its matrix products, and how
+its layers attend) live in its file, ``families/<family>.py``, built on
+these.
 
 The attention counts follow the repository's analytic roofline
 (``benchmarks/bench_kernels.py:_attn_roofline``), with two changes so that a
@@ -49,15 +52,6 @@ def causal_attention(seq: int, heads: int, kv_heads: int, head_dim: int,
     nbytes = ((2.0 * heads + 2.0 * kv_heads) * seq * head_dim * dtype_bytes
               + 4.0 * heads * seq)
     return flops, nbytes
-
-
-def matmul_params(m: Dict[str, Any]) -> float:
-    """Weights that take part in a matrix product per token: every block's
-    projections and the output head (the embedding is a lookup)."""
-    d, f, v, L = m["d_model"], m["d_ff"], m["vocab_size"], m["num_layers"]
-    hd = m["head_dim"]
-    attn = d * hd * (2 * m["num_heads"] + 2 * m["num_kv_heads"])
-    return float(L * (attn + 3 * d * f) + d * v)
 
 
 def share(flops: float, nbytes: float, seconds: float,

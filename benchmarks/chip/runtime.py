@@ -21,12 +21,15 @@ TRACE_DIR = Path(__file__).resolve().parent / ".trace"
 TRACE_S = 20.0
 
 
-def model_config(config_file: Dict[str, Any]):
-    """The program's ModelConfig for a configuration file's ``model``."""
+def model_config(config_file: Dict[str, Any], family):
+    """The program's ModelConfig for a configuration file's ``model``, of
+    the program family that the benchmark's family file names."""
     from repro.configs.base import ModelConfig  # noqa: PLC0415
 
     return ModelConfig(name=config_file["name"],
-                       source=config_file["source"], **config_file["model"])
+                       source=config_file["source"],
+                       **dict(config_file["model"],
+                              family=family.PROGRAM_FAMILY))
 
 
 def program_model(cfg):

@@ -12,12 +12,12 @@ from __future__ import annotations
 import gc
 import os
 import time
-from typing import Any, Dict, List, Optional, Tuple
+from typing import Any, Callable, Dict, List, Optional, Tuple
 
 import jax
 import numpy as np
 
-from benchmarks.chip import check, reference, roofline, traffic, weights
+from benchmarks.chip import check, traffic, weights
 from benchmarks.chip.record import RequestRecord, RunRecord
 from benchmarks.chip.runtime import Runtime, model_config, program_model
 
@@ -39,21 +39,22 @@ class Server:
                  fault: Optional[Any] = None):
         m, e = cell.config["model"], cell.traffic["engine"]
         self.m, self.e = m, e
+        self.family = cell.family()
         # the KV layout is the traffic file's: the program reads its page
         # size when it builds its programs
         page = int(e.get("kv_page_size", 0))
         os.environ["REPRO_KV_PAGES"] = str(page)
-        cfg = model_config(cell.config)
+        cfg = model_config(cell.config, self.family)
         mesh = mesh_lib.make_local_mesh(("data",), devices)
         self.plan = plan_sharding(
             cfg, ShapeConfig("serve", e["prompt_bucket"] + e["max_new"],
                              e["slots"], "decode"), mesh)
         self.model = program_model(cfg)
         specs = self.model.param_specs()
-        weights.check_layout(m, specs)
+        weights.check_layout(self.family, m, specs)
         self.init = weights.make_init(
-            m, cell.config["init"], named(self.plan, param_pspecs(specs,
-                                                                  self.plan)))
+            self.family, m, cell.config["init"],
+            named(self.plan, param_pspecs(specs, self.plan)))
         with rt.phase("weights"):
             params = self.init(weights.seed_key(seed))
             jax.block_until_ready(params)
@@ -101,14 +102,11 @@ def drive(server: Server, sched: List[traffic.Arrival], seconds: float,
     """The window: submit each request when due, step the engine, and note
     when each client holds its tokens. Work counts are accumulated as
     tokens arrive, over valid positions only (a prompt's own tokens, not its
-    padding to the bucket): each request's prefill, and for each decode row
-    the matrix products and the attention over the positions it may attend
-    to, with the attention's least bytes."""
-    eng, m, e = server.engine, server.m, server.e
-    L = m["num_layers"]
-    H, Hkv, D = m["num_heads"], m["num_kv_heads"], m["head_dim"]
-    per_tok_flops = 2.0 * roofline.matmul_params(m)
-    row_flops = roofline.decode_attention(1, H, Hkv, D)[0]   # per position
+    padding to the bucket), as the model family counts them: each request's
+    prefill, and for each decode row the matrix products and the attention
+    over the positions it may attend to, with the attention's least
+    bytes."""
+    eng, m, e, fam = server.engine, server.m, server.e, server.family
     recs = {a.uid: RequestRecord(uid=a.uid, due=a.due_s, max_new=a.max_new,
                                  prompt_len=len(a.tokens))
             for a in sched}
@@ -134,25 +132,18 @@ def drive(server: Server, sched: List[traffic.Arrival], seconds: float,
         n = r.prompt_len
         if r.admitted is None:
             r.admitted = ts
-            work["prefill_model_flops"] += per_tok_flops * n + L * \
-                roofline.causal_attention(n, H, Hkv, D)[0]
+            work["prefill_model_flops"] += fam.prefill_flops(m, n)
         if count > r.n_tokens:
             t_a, t_b = r.n_tokens, count
             if r.first is None:
                 r.first = te
             r.last = te
             r.n_chunks += 1
-            # token 0 comes from the prefill; the decode row that makes
-            # token t (t >= 1) attends the n + t positions before it
+            # token 0 comes from the prefill
             a = max(t_a, 1)
-            rows = t_b - a
-            kv = rows * n + (a + t_b - 1) * rows / 2
-            work["decode_attn_flops"] += L * row_flops * kv
-            work["decode_attn_bytes"] += L * (
-                2.0 * Hkv * kv + 2.0 * H * rows) * D * 2
-            work["decode_model_flops"] += (per_tok_flops * rows
-                                           + L * row_flops * kv)
-            work["decode_tokens"] += rows
+            for k, v in fam.decode_work(m, n, a, t_b).items():
+                work[k] += v
+            work["decode_tokens"] += t_b - a
             if ts < seconds:
                 rec.tokens_in_window += t_b - t_a
             r.n_tokens = count
@@ -232,12 +223,12 @@ def check_rows(sample: List[RequestRecord], prompts: Dict[int, np.ndarray]
     return out
 
 
-def reference_gaps(params, m: Dict[str, Any], cases, mode: str,
-                   seq_len: int, rows: int,
+def reference_gaps(row_logits: Callable, params, m: Dict[str, Any], cases,
+                   mode: str, seq_len: int, rows: int,
                    per_call: int = 4) -> Dict[str, List[np.ndarray]]:
     """Per request: ``ref`` gaps of the served tokens under the float32
-    reference, and ``ctl`` gaps of the tokens ``mode`` would put first
-    (``mode="f32"``: none). Every call has the same shape (``per_call``
+    reference (the family's ``row_logits``), and ``ctl`` gaps of the tokens
+    ``mode`` would put first (``mode="f32"``: none). Every call has the same shape (``per_call``
     requests of ``seq_len`` tokens, ``rows`` compared rows; the last group
     padded with its own last request), so the reference compiles once per
     cell and the compile cache serves every later run."""
@@ -251,8 +242,8 @@ def reference_gaps(params, m: Dict[str, Any], cases, mode: str,
             toks[j, :len(seq)] = seq
             rws[j, :len(rw)] = rw
             rws[j, len(rw):] = rw[-1]
-        ref = np.asarray(reference.row_logits(params, toks, rws, m, "f32"))
-        ctl = (np.asarray(reference.row_logits(params, toks, rws, m, mode))
+        ref = np.asarray(row_logits(params, toks, rws, m, "f32"))
+        ctl = (np.asarray(row_logits(params, toks, rws, m, mode))
                if mode != "f32" else None)
         for j, (_, rw, served) in enumerate(group):
             rr = ref[j, :len(rw)]
@@ -293,7 +284,8 @@ def run(cell, *, seed: int, seconds: float, rt: Runtime, devices,
         with rt.phase("check"):
             params = init(weights.seed_key(seed))
             cases = check_rows(sample, prompts)
-            gaps = reference_gaps(params, m, cases, control or "f32",
+            gaps = reference_gaps(server.family.row_logits, params, m,
+                                  cases, control or "f32",
                                   e["prompt_bucket"] + e["max_new"],
                                   e["max_new"])
             del params

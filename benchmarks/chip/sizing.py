@@ -58,9 +58,10 @@ def train_sizes(cell, batch: int, devices, run: bool) -> dict:
     from repro.optim import make_optimizer  # noqa: PLC0415
 
     m, t = cell.config["model"], dict(cell.traffic)
+    fam = cell.family()
     t["batch"] = batch
     S = t["seq_len"]
-    cfg = model_config(cell.config)
+    cfg = model_config(cell.config, fam)
     mesh = mesh_lib.make_local_mesh(("data",), devices)
     shape = ShapeConfig("train", seq_len=S, global_batch=batch, mode="train")
     plan = plan_sharding(cfg, shape, mesh)
@@ -71,7 +72,7 @@ def train_sizes(cell, batch: int, devices, run: bool) -> dict:
     p_sh = named(plan, param_pspecs(specs, plan))
     o_sh = named(plan, param_pspecs(opt.state_specs(specs), plan))
     b_sh = named(plan, specs_lib.batch_pspecs(cfg, shape, plan))["tokens"]
-    init = weights.make_init(m, cell.config["init"], p_sh)
+    init = weights.make_init(fam, m, cell.config["init"], p_sh)
     key = weights.seed_key(0)
     p_sds = jax.eval_shape(init, key)
     p_sds = jax.tree_util.tree_map(

@@ -43,7 +43,7 @@ def test_fused_kernel_counts_its_calls_and_time_alone():
     s = summary([("flash_fwd", 1.0), ("flash_bwd", 0.5)] * 3)
     got = roofline(run_of(s))
     assert got == pytest.approx(100 * 2.5 * 1e9 / 197e12 / 0.5e-3)
-    # the forward's reader still sums every Pallas op of the step
+    # every Pallas op of the step, where no kernel is named
     assert trace.kernel_count(s, "train_step") == 12
 
 
@@ -72,3 +72,14 @@ def test_silent_without_the_backward_kernel(kernels):
 def test_silent_without_a_trace():
     assert roofline(RunRecord(kind="train", seconds=1.0, peaks=PEAKS,
                               work=dict(WORK))) is None
+
+
+def test_forward_roofline_counts_the_forward_kernel_alone():
+    """The forward's reader takes the time and the calls of
+    ``pallas:flash_fwd`` alone, beside the backward kernel in the step."""
+    s = summary([("flash_fwd", 1.0), ("flash_bwd", 0.5)] * 3)
+    got = spec.load_reader("kernel.flash_fwd_roofline")(run_of(s))
+    # 6 forward calls of 1 ms over two steps: 1e9 operations a call
+    assert got == pytest.approx(100 * 1e9 / 197e12 / 1e-3)
+    assert spec.load_reader("kernel.flash_fwd_roofline")(
+        run_of(summary([("flash_bwd", 0.5)]))) is None

@@ -23,7 +23,7 @@ def step_ops(t0):
          f"{STEP}/jvp(layers)/while"),
         (op("%fusion.1 = bf16[8] fusion(%a)", 2, 6),
          f"{STEP}/jvp(layers)/while/body/closed_call/attn/dot_general"),
-        (op('%closed_call.2 = bf16[8] custom-call(%q), '
+        (op('%flash_fwd.2 = bf16[8] custom-call(%q), '
             'custom_call_target="tpu_custom_call"', 6, 7),
          f"{STEP}/jvp(layers)/while/body/closed_call/attn/flash_fwd"),
         (op("%bitcast_dynamic-update-slice_fusion.3 = f32[8] fusion(%b)",
@@ -130,7 +130,7 @@ def test_device_scopes_by_hand():
     assert ops["attn_bwd/bwd"] == pytest.approx(
         {"bitcast_dynamic-update-slice_fusion": 5e-3})
     assert ops["attn/fwd"] == pytest.approx({"fusion": 4e-3,
-                                             "pallas:closed_call": 1e-3})
+                                             "pallas:flash_fwd": 1e-3})
     rows = s["device_scopes"]
     assert rows[0][:3] in (["jit_train_step", "attn", "fwd"],
                            ["jit_train_step", "attn_bwd", "bwd"])

@@ -63,6 +63,10 @@ def test_every_cell_resolves_and_reports_enough(workload):
         assert m["moves"] in e2e
     assert cell.limits["limits"]
     assert cell.chips in (1, 4)
+    family = cell.family()
+    for name in ("PROGRAM_FAMILY", "layout", "init_stds", "forward",
+                 "row_logits", "train_work", "prefill_flops", "decode_work"):
+        assert hasattr(family, name), name
 
 
 @pytest.mark.parametrize("entry", BENCH["configs"], ids=lambda c: c["name"])
@@ -75,7 +79,8 @@ def test_config_files_state_source_and_departures(entry):
         assert cfg[key] != was            # a listed key really changed
     assert not any(WIDTHS.search(k) for k in entry["reduced"])
     m = cfg["model"]
-    assert m["head_dim"] * m["num_heads"] == m["d_model"]
+    if m["family"] == "dense":     # the dense block's widths, as published
+        assert m["head_dim"] * m["num_heads"] == m["d_model"]
 
 
 def test_a_new_traffic_file_is_found_without_edits(tmp_path):

@@ -176,18 +176,33 @@ def module_count(summary: Dict[str, Any], module: str) -> float:
     return sum(v for k, v in summary["module_n"].items() if module in k)
 
 
-def kernel_time(summary: Dict[str, Any], module: str) -> float:
-    """Seconds per device of Pallas kernels inside ``module``'s programs."""
-    return sum(v for m, ops in summary["op_in_module_s"].items()
-               if module in m for k, v in ops.items()
-               if k.startswith("pallas:"))
+def ops_in(summary: Dict[str, Any], module: str, prefix: str,
+           table: str = "op_in_module_s") -> Dict[str, float]:
+    """{op name: seconds per device} of the ops whose name starts with
+    ``prefix`` inside programs whose jit name contains ``module``, summed
+    over those programs; with ``table="op_in_module_n"``, executions per
+    device."""
+    out: Dict[str, float] = {}
+    for mod, ops in summary[table].items():
+        if module in mod:
+            for name, v in ops.items():
+                if name.startswith(prefix):
+                    out[name] = out.get(name, 0.0) + v
+    return out
 
 
-def kernel_count(summary: Dict[str, Any], module: str) -> float:
-    """Pallas kernel executions per device inside ``module``'s programs."""
-    return sum(v for m, ops in summary["op_in_module_n"].items()
-               if module in m for k, v in ops.items()
-               if k.startswith("pallas:"))
+def kernel_time(summary: Dict[str, Any], module: str,
+                prefix: str = "pallas:") -> float:
+    """Seconds per device of the Pallas kernels (those named ``prefix...``)
+    inside ``module``'s programs."""
+    return sum(ops_in(summary, module, prefix).values())
+
+
+def kernel_count(summary: Dict[str, Any], module: str,
+                 prefix: str = "pallas:") -> float:
+    """Executions per device of the Pallas kernels (those named
+    ``prefix...``) inside ``module``'s programs."""
+    return sum(ops_in(summary, module, prefix, "op_in_module_n").values())
 
 
 def load_xplane(path: str) -> Tuple[Dict[str, Dict[str, List[Event]]],

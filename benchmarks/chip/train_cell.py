@@ -18,7 +18,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from benchmarks.chip import check, data, reference, roofline, weights
+from benchmarks.chip import check, data, reference, weights
 from benchmarks.chip.record import RunRecord
 from benchmarks.chip.runtime import Runtime, model_config, program_model
 
@@ -61,29 +61,24 @@ def run(cell, *, seed: int, seconds: float, rt: Runtime, devices,
         fault: Optional[Callable] = None, control: Optional[str] = None
         ) -> Tuple[RunRecord, Dict[str, Any]]:
     m, t = cell.config["model"], cell.traffic
+    fam = cell.family()
     B, S = t["batch"], t["seq_len"]
     rec = RunRecord(kind="train", seconds=seconds, chips=len(devices),
                     tokens_per_step=B * S)
-    H, Hkv, D, L = (m["num_heads"], m["num_kv_heads"], m["head_dim"],
-                    m["num_layers"])
-    f_att, b_att = roofline.causal_attention(S, H, Hkv, D)
-    rec.work.update(
-        attn_call_flops=B * f_att, attn_call_bytes=B * b_att,
-        model_flops_per_token=6.0 * roofline.matmul_params(m)
-        + 3.0 * L * f_att / S)
-    cfg = model_config(cell.config)
+    rec.work.update(fam.train_work(m, B, S))
+    cfg = model_config(cell.config, fam)
     mesh = mesh_lib.make_local_mesh(("data",), devices)
     shape = ShapeConfig("train", seq_len=S, global_batch=B, mode="train")
     plan = plan_sharding(cfg, shape, mesh)
     model = program_model(cfg)
     specs = model.param_specs()
-    weights.check_layout(m, specs)
+    weights.check_layout(fam, m, specs)
     rcfg = run_config(t)
     opt = make_optimizer("adamw", rcfg)
     p_sh = named(plan, param_pspecs(specs, plan))
     o_sh = named(plan, param_pspecs(opt.state_specs(specs), plan))
     b_sh = named(plan, specs_lib.batch_pspecs(cfg, shape, plan))["tokens"]
-    init = weights.make_init(m, cell.config["init"], p_sh)
+    init = weights.make_init(fam, m, cell.config["init"], p_sh)
     with rt.phase("weights"):
         params = init(weights.seed_key(seed))
         opt_state = jax.jit(opt.init, out_shardings=o_sh)(params)
@@ -142,18 +137,22 @@ def run(cell, *, seed: int, seconds: float, rt: Runtime, devices,
     pool.clear()
     gc.collect()
     with rt.phase("check"):
-        numbers = compare(init, seed, checked, prog, m, t, control)
+        numbers = compare(fam.forward, init, seed, checked, prog, m, t,
+                          control)
     return rec, numbers
 
 
-def compare(init, seed: int, batches, prog: Dict[str, Any], m, t,
+def compare(forward: Callable, init, seed: int, batches,
+            prog: Dict[str, Any], m, t,
             control: Optional[str]) -> Dict[str, Any]:
-    """The program's first steps against the reference's, from the same
-    seed; with ``control``, the reference computed that way in the
-    program's place, against the float32 reference."""
+    """The program's first steps against the reference's (the family's
+    ``forward``), from the same seed; with ``control``, the reference
+    computed that way in the program's place, against the float32
+    reference."""
     p0 = init(weights.seed_key(seed))
     rows = t["check"]["rows_per_block"]
-    ref = reference.train_steps(p0, batches, m, t["run"], "f32", rows)
+    ref = reference.train_steps(forward, p0, batches, m, t["run"], "f32",
+                                rows)
     ref_grad, ref_change = ref["grad"], ref["change"]
     leaves = check.counted_leaves(ref_grad)
 
@@ -173,6 +172,6 @@ def compare(init, seed: int, batches, prog: Dict[str, Any], m, t,
         half = [b[:len(b) // 2] for b in batches]
         for tag, mode, bs in (("control_", control, batches),
                               ("half_batch_", "f32", half)):
-            out.update(numbers(reference.train_steps(p0, bs, m, t["run"],
-                                                     mode, rows), tag))
+            out.update(numbers(reference.train_steps(
+                forward, p0, bs, m, t["run"], mode, rows), tag))
     return out
