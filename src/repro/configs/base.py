@@ -31,10 +31,26 @@ class ModelConfig:
     experts_per_token: int = 0
     capacity_factor: float = 1.25
     moe_every: int = 1               # every k-th layer is MoE (1 = all)
+    # the device's share of each expert layer: experts [expert_offset,
+    # expert_offset + experts_held) of the num_experts routed over, dropless
+    # (0 = all experts, on the capacity-bounded path)
+    experts_held: int = 0
+    expert_offset: int = 0
     # --- attention ---
     attention_kind: str = "full"     # full | sliding | hybrid_local (rg-lru 1:2)
     sliding_window: int = 0
     rope_theta: float = 10_000.0
+    # one period of layer kinds, "sliding" or "full", repeated through the
+    # stack (empty = every layer as attention_kind says)
+    layer_pattern: Tuple[str, ...] = ()
+    # YaRN rotary scaling of the pattern's full layers (factor 0 = off), as
+    # transformers' _compute_yarn_parameters defines it; the attention
+    # factor 0 takes its default, 0.1 ln(factor) + 1
+    yarn_factor: float = 0.0
+    yarn_original_max_positions: int = 0
+    yarn_beta_fast: float = 32.0
+    yarn_beta_slow: float = 1.0
+    yarn_attention_factor: float = 0.0
     # --- hybrid / ssm ---
     local_window: int = 2048         # recurrentgemma local-attn window
     conv_width: int = 4              # temporal conv width in recurrent block
@@ -64,6 +80,10 @@ class ModelConfig:
     attn_chunk_kv: int = 1024
     # notes for DESIGN/EXPERIMENTS
     source: str = ""
+
+    def __post_init__(self):
+        # a configuration file gives the pattern as a list
+        object.__setattr__(self, "layer_pattern", tuple(self.layer_pattern))
 
     @property
     def resolved_head_dim(self) -> int:
@@ -155,7 +175,8 @@ def param_count(cfg: ModelConfig) -> int:
         total_blocks = n_blocks * blk
     else:
         if cfg.num_experts > 0:
-            ffn = 3 * d * cfg.d_ff * cfg.num_experts + d * cfg.num_experts
+            held = cfg.experts_held or cfg.num_experts
+            ffn = 3 * d * cfg.d_ff * held + d * cfg.num_experts
         else:
             ffn = 3 * d * cfg.d_ff
         blk = attn + ffn + 2 * d

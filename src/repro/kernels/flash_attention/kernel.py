@@ -297,6 +297,15 @@ def flash_attention_fwd(q: jax.Array, k: jax.Array, v: jax.Array,
 VMEM_BUDGET = 16 * 1024 * 1024
 BWD_Q_ROWS = 1024            # q rows (positions x G heads) per block
 BWD_KV_ROWS = (512, 256, 128)  # kv rows per block, largest that fits first
+FWD_Q_ROWS = 1024            # the forward's q rows per block, at most
+
+
+def fwd_block_q(block_q: int, G: int) -> int:
+    """The training forward's q block: ``block_q`` positions, fewer where
+    their ``G`` grouped heads would pass ``FWD_Q_ROWS`` rows (a multiple of
+    128 lanes), so that a block's float32 score tiles stay in VMEM."""
+    unit = 128 // math.gcd(G, 128)             # positions per 128 lanes
+    return min(block_q, max(unit, FWD_Q_ROWS // G // unit * unit))
 
 _NT = (((1,), (1,)), ((), ()))   # a @ b.T
 _NN = (((1,), (0,)), ((), ()))   # a @ b

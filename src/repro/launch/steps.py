@@ -24,6 +24,7 @@ from repro.launch import specs as specs_lib
 from repro.launch import tracing
 from repro.models import layers
 from repro.models import module as mod
+from repro.models.model import STAT_OVER_LAYERS
 from repro.optim import Optimizer
 
 
@@ -60,16 +61,27 @@ def auto_microbatches(cfg: ModelConfig, shape: ShapeConfig, plan: Plan,
     return nm
 
 
+def _value_and_grad(model, params, batch):
+    """((loss, stats), grads): stats are the model's step counters where it
+    gives them (``loss_and_stats``), else empty."""
+    if hasattr(model, "loss_and_stats"):
+        return jax.value_and_grad(model.loss_and_stats, has_aux=True)(
+            params, batch)
+    loss, grads = jax.value_and_grad(model.loss)(params, batch)
+    return (loss, {}), grads
+
+
 def _loss_and_grads(model, params, batch, nm: int):
-    """value_and_grad with optional lax.scan gradient accumulation.
+    """(loss, grads, stats): value_and_grad with optional lax.scan gradient
+    accumulation.
 
     Gradients are re-pinned to the parameter shardings (ZeRO-2: the data-axis
     reduce-scatter happens per layer inside the loop, not on a full-size
     unsharded stack afterwards)."""
     specs = model.param_specs()
     if nm <= 1:
-        loss, grads = jax.value_and_grad(model.loss)(params, batch)
-        return loss, mod.constrain_tree(grads, specs)
+        (loss, stats), grads = _value_and_grad(model, params, batch)
+        return loss, mod.constrain_tree(grads, specs), stats
 
     def split(x):
         return x.reshape((nm, x.shape[0] // nm) + x.shape[1:])
@@ -86,16 +98,18 @@ def _loss_and_grads(model, params, batch, nm: int):
 
     def acc(carry, mbatch):
         lsum, gsum = carry
-        l, g = jax.value_and_grad(model.loss)(params, mbatch)
+        (l, st), g = _value_and_grad(model, params, mbatch)
         g = mod.constrain_tree(g, specs)
         gsum = jax.tree_util.tree_map(
             lambda a, b: a + b.astype(a.dtype), gsum, g)
-        return (lsum + l, mod.constrain_tree(gsum, specs)), None
+        return (lsum + l, mod.constrain_tree(gsum, specs)), st
 
-    (loss, grads), _ = jax.lax.scan(acc, (jnp.zeros((), jnp.float32), zero), mb)
+    (loss, grads), stats = jax.lax.scan(
+        acc, (jnp.zeros((), jnp.float32), zero), mb)
     inv = 1.0 / nm
     grads = jax.tree_util.tree_map(lambda g: g * inv, grads)
-    return loss * inv, grads
+    return loss * inv, grads, {k: STAT_OVER_LAYERS[k](v)
+                               for k, v in stats.items()}
 
 
 def make_train_step(model, optimizer: Optimizer, plan: Plan, run: RunConfig,
@@ -108,9 +122,12 @@ def make_train_step(model, optimizer: Optimizer, plan: Plan, run: RunConfig,
     The select is ``jnp.where`` on the outputs, so buffer donation is
     preserved and a clean step is bitwise-identical to the unguarded step
     (a select with a true predicate is the identity). Guarded metrics are
-    ``{"loss", "grad_norm", "skipped"}``; the driver counts consecutive
-    ``skipped`` steps and aborts with ``TrainDivergedError`` — one bad batch
-    costs one skipped step, never a poisoned parameter tree.
+    ``{"loss", "grad_norm", "skipped"}``, with the model's step counters
+    beside them where it has any (``moe_rows``, ``moe_load_max`` of held
+    experts: :meth:`TransformerLM.forward_and_stats`); the training loop
+    counts consecutive ``skipped`` steps and aborts with
+    ``TrainDivergedError`` — one bad batch costs one skipped step, never a
+    poisoned parameter tree.
 
     ``grad_hook(loss, grads, arm) -> (loss, grads)`` is the chaos harness's
     trace-time injection point (see
@@ -126,14 +143,14 @@ def make_train_step(model, optimizer: Optimizer, plan: Plan, run: RunConfig,
 
     def train_step(params, opt_state, batch, arm=None):
         with use_plan(plan):
-            loss, grads = _loss_and_grads(model, params, batch, nm)
+            loss, grads, stats = _loss_and_grads(model, params, batch, nm)
             if grad_hook is not None:
                 loss, grads = grad_hook(loss, grads, arm)
             if not guard:
                 with tracing.scope("optimizer"):
                     new_params, new_opt = optimizer.update(grads, opt_state,
                                                            params)
-                return new_params, new_opt, {"loss": loss}
+                return new_params, new_opt, {"loss": loss, **stats}
             with tracing.scope("guard"):
                 gnorm = global_norm(grads)
                 ok = jnp.isfinite(loss) & jnp.isfinite(gnorm)
@@ -146,7 +163,7 @@ def make_train_step(model, optimizer: Optimizer, plan: Plan, run: RunConfig,
                     lambda n, o: jnp.where(ok, n, o), new, old)
 
             metrics = {"loss": loss, "grad_norm": gnorm,
-                       "skipped": jnp.logical_not(ok)}
+                       "skipped": jnp.logical_not(ok), **stats}
         with tracing.scope("guard"):
             return (sel(new_params, params), sel(new_opt, opt_state),
                     metrics)
@@ -178,7 +195,7 @@ def make_train_step_proteus(model, optimizer: Optimizer, plan: Plan,
 
     def per_pod(params, opt_state, batch):
         with use_plan(inner_plan):
-            loss, grads = _loss_and_grads(model, params, batch, nm)
+            loss, grads, _ = _loss_and_grads(model, params, batch, nm)
         grads = proteus.cross_pod_psum(
             grads, pod_axis, bits=run.proteus_grad_bits,
             block=run.proteus_block, mean=True, n_pods=n_pods)

@@ -26,16 +26,21 @@ import jax
 # own work (slicing each layer's weights out of the stacked parameters,
 # writing residuals and gradients into stacked buffers); ``attn``, ``mlp``
 # and ``attn_bwd`` (the flash backward: the Pallas kernel with its layout
-# work, or the jnp recompute backward) lie inside it
-SCOPES = ("embed", "layers", "attn", "attn_bwd", "mlp", "head_loss",
-          "optimizer", "guard")
+# work, or the jnp recompute backward) lie inside it. A layer of held experts
+# splits its ``mlp`` into ``moe_route`` (router, top-k, the sort into the
+# expert buffer), ``moe_experts`` (the grouped matmuls) and ``moe_combine``
+# (the gather back to tokens, weighted by the gates)
+SCOPES = ("embed", "layers", "attn", "attn_bwd", "mlp", "moe_route",
+          "moe_experts", "moe_combine", "head_loss", "optimizer", "guard")
 # Pallas kernels by their ``pallas_call`` name, which names the kernel's op
 # in a profile (``pallas:flash_bwd``): ``flash_fwd`` and ``flash_bwd`` are
-# the training attention's forward and backward
+# the training attention's forward and backward; ``gmm`` and ``tgmm`` the
+# held experts' grouped matmul (forward and input gradient) and its weight
+# gradient
 KERNELS = ("flash_fwd", "flash_bwd", "flash_decode", "flash_decode_paged",
            "flash_decode_quant", "flash_decode_paged_quant", "quant_matmul",
            "rglru_scan", "narrow_required_bits", "narrow_pack_int4",
-           "narrow_unpack_int4")
+           "narrow_unpack_int4", "gmm", "tgmm")
 SPAN_PREFIX = "repro."
 # host spans (without the prefix) and the ids each carries
 SPANS = {

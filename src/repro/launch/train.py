@@ -283,7 +283,12 @@ def train(arch: str, *, smoke: bool = True, steps: int = 100,
             print(f"  straggler flag: {flag}")
         if step % log_every == 0 or step == steps - 1:
             dt = time.time() - t_begin
-            print(f"step {step:5d} loss {loss:8.4f} "
+            # a model with held experts also counts the pairs routed to
+            # them and the busiest expert's load over the mean
+            moe = "".join(f" {k} {float(metrics[k]):.4g}"
+                          for k in ("moe_rows", "moe_load_max")
+                          if k in metrics)
+            print(f"step {step:5d} loss {loss:8.4f}{moe} "
                   f"({dt / max(step - start + 1, 1):.2f}s/step)")
         if preempt_after is not None and start < preempt_after <= step + 1:
             preempt.requested = True

@@ -29,6 +29,7 @@ from typing import Any, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from jax.sharding import PartitionSpec as P
 
@@ -38,6 +39,7 @@ from repro.core.proteus import required_bits_float
 from repro.kernels.common import (attn_impl, kv_page_size, kv_quant_mode,
                                   on_tpu, pack_int4, pad_axis, pad_positions,
                                   unpack_int4)
+from repro.kernels.flash_attention.kernel import fwd_block_q
 from repro.kernels.flash_attention.ops import (flash_attention_gqa_bwd,
                                                flash_attention_gqa_fwd,
                                                flash_decode,
@@ -83,15 +85,57 @@ def gated_mlp(x: jax.Array, wi_gate: jax.Array, wi_up: jax.Array,
     return dense(h, wo, "bsf,fd->bsd")
 
 
-def rope(x: jax.Array, positions: jax.Array, theta: float = 10_000.0) -> jax.Array:
-    """Rotary embedding. x: (..., S, H, D); positions: (S,) or (B, S)."""
+def yarn_rotary(head_dim: int, theta: float, factor: float,
+                original_max_positions: int, beta_fast: float,
+                beta_slow: float, attention_factor: float = 0.0
+                ) -> Tuple[np.ndarray, float]:
+    """(inverse frequencies (head_dim // 2,), attention factor) of YaRN, as
+    transformers' ``_compute_yarn_parameters`` gives them (``truncate``
+    rounding): the interpolated and extrapolated frequencies blended by a
+    linear ramp between the dimensions that turn ``beta_fast`` and
+    ``beta_slow`` times over the original context. The attention factor
+    scales cos and sin; 0 takes 0.1 ln(factor) + 1."""
+    half = head_dim // 2
+    pos_freqs = theta ** (np.arange(0, head_dim, 2, dtype=np.float32)
+                          / head_dim)
+    extrapolation = 1.0 / pos_freqs
+    interpolation = 1.0 / (factor * pos_freqs)
+
+    def dim_of(rotations):
+        return (head_dim * math.log(original_max_positions
+                                    / (rotations * 2 * math.pi))
+                / (2 * math.log(theta)))
+
+    low = max(math.floor(dim_of(beta_fast)), 0)
+    high = min(math.ceil(dim_of(beta_slow)), head_dim - 1)
+    if low == high:
+        high += 0.001
+    ramp = np.clip((np.arange(half, dtype=np.float32) - low) / (high - low),
+                   0, 1)
+    keep = 1 - ramp                         # share of the extrapolation
+    inv_freq = interpolation * (1 - keep) + extrapolation * keep
+    scale = attention_factor or 0.1 * math.log(factor) + 1.0
+    return inv_freq.astype(np.float32), float(scale)
+
+
+def rope(x: jax.Array, positions: jax.Array, theta: float = 10_000.0,
+         inv_freq: Optional[np.ndarray] = None,
+         scale: float = 1.0) -> jax.Array:
+    """Rotary embedding. x: (..., S, H, D); positions: (S,) or (B, S).
+    ``inv_freq`` replaces theta's frequencies and ``scale`` multiplies cos
+    and sin (YaRN: :func:`yarn_rotary`)."""
     d = x.shape[-1]
     half = d // 2
-    freq = theta ** (-jnp.arange(0, half, dtype=jnp.float32) / half)
+    if inv_freq is None:
+        freq = theta ** (-jnp.arange(0, half, dtype=jnp.float32) / half)
+    else:
+        freq = jnp.asarray(inv_freq, jnp.float32)
     ang = positions[..., None].astype(jnp.float32) * freq  # (..., S, half)
     # broadcast to (..., S, 1, half) over heads
     cos = jnp.cos(ang)[..., None, :]
     sin = jnp.sin(ang)[..., None, :]
+    if scale != 1.0:
+        cos, sin = cos * scale, sin * scale
     x1, x2 = x[..., :half], x[..., half:]
     y1 = x1 * cos - x2 * sin
     y2 = x2 * cos + x1 * sin
@@ -642,6 +686,8 @@ def chunked_attention(
     # training/prefill path: flash custom-VJP (O(S) activation memory)
     if (not quant and kv_positions is None and kv_valid_len is None and S > 1
             and isinstance(q_offset, int) and q_offset == 0):
+        if backend == "pallas":
+            cq = fwd_block_q(cq, G)
         Sp = -(-S // cq) * cq
         Tp = -(-T // ck) * ck
         qp = pad_axis(q, 1, Sp)

@@ -1,9 +1,12 @@
 """Decoder-only transformer LM (dense / MoE / VLM families) + model dispatch.
 
-One homogeneous block stack, scanned (``jax.lax.scan``) over stacked params so
-HLO size and compile time are O(1) in depth; KV-cache decode path for
-serving. Hybrid (RG-LRU), SSM (xLSTM) and enc-dec (audio) families live in
-sibling modules and share the same Model protocol:
+One block stack, scanned (``jax.lax.scan``) over stacked params so HLO size
+and compile time are O(1) in depth; KV-cache decode path for serving. A
+``layer_pattern`` (windowed and full layers, e.g. three of one to one of the
+other) makes the scan walk whole periods, the period's layers unrolled in
+its body so that each keeps a static window and rotary table. Hybrid
+(RG-LRU), SSM (xLSTM) and enc-dec (audio) families live in sibling modules
+and share the same Model protocol:
 
     param_specs() -> spec pytree
     loss(params, batch) -> scalar
@@ -30,8 +33,8 @@ from repro.models.layers import (aligned_cache_len, chunked_attention, dense,
                                  kv_cache_len, kv_cache_store,
                                  kv_cache_update, kv_cast, ring_cache_update,
                                  ring_position_ids, rms_norm, rope,
-                                 softmax_xent, stack_trees)
-from repro.models.moe import moe_ffn, moe_param_specs
+                                 softmax_xent, stack_trees, yarn_rotary)
+from repro.models.moe import held_moe_ffn, moe_ffn, moe_param_specs
 
 
 def _dt(name: str):
@@ -62,12 +65,16 @@ def mlp_param_specs(cfg: ModelConfig, dtype) -> Dict[str, mod.ParamSpec]:
     }
 
 
-def qkv(cfg: ModelConfig, p, xn: jax.Array, positions) -> Tuple[jax.Array, ...]:
+def qkv(cfg: ModelConfig, p, xn: jax.Array, positions,
+        rot: Optional[Tuple[Any, float]] = None) -> Tuple[jax.Array, ...]:
+    """q, k, v of one layer; ``rot`` (inverse frequencies, scale) replaces
+    the plain rotary embedding at ``rope_theta``."""
     q = dense(xn, p["w_q"], "bsd,dhe->bshe")
     k = dense(xn, p["w_k"], "bsd,dhe->bshe")
     v = dense(xn, p["w_v"], "bsd,dhe->bshe")
-    q = rope(q, positions, cfg.rope_theta)
-    k = rope(k, positions, cfg.rope_theta)
+    rot = () if rot is None else rot
+    q = rope(q, positions, cfg.rope_theta, *rot)
+    k = rope(k, positions, cfg.rope_theta, *rot)
     q = constrain(q, "act_batch", "act_seq", "act_heads", "act_hd")
     k = constrain(k, "act_batch", "act_seq", "act_kv", "act_hd")
     return q, k, v
@@ -75,6 +82,16 @@ def qkv(cfg: ModelConfig, p, xn: jax.Array, positions) -> Tuple[jax.Array, ...]:
 
 def attn_out(p, o: jax.Array) -> jax.Array:
     return dense(o, p["w_o"], "bshe,hed->bsd")
+
+
+# how each expert-layer counter goes from layers to the stack
+STAT_OVER_LAYERS = {"moe_rows": jnp.sum, "moe_load_max": jnp.max}
+
+
+def _stack(stats):
+    """A list of equal stat dicts -> one dict of stacked arrays."""
+    return jax.tree_util.tree_map(lambda *a: jnp.stack(a), *stats) \
+        if stats and stats[0] else {}
 
 
 # ---------------------------------------------------------------------------
@@ -115,8 +132,32 @@ class TransformerLM:
                                      ("embed", "vocab"), self.dtype)
         return specs
 
+    def layer_kinds(self) -> Tuple[Tuple[int, Any], ...]:
+        """(window, rotary) of each layer of one period of the stack: one
+        layer as ``attention_kind`` says, or ``layer_pattern``'s; a full
+        layer takes YaRN's rotary where ``yarn_factor`` is set (else
+        None, the plain table)."""
+        cfg = self.cfg
+        if not cfg.layer_pattern:
+            return ((cfg.sliding_window if cfg.attention_kind == "sliding"
+                     else 0, None),)
+        assert cfg.num_layers % len(cfg.layer_pattern) == 0, (
+            f"{cfg.num_layers} layers are not whole periods of "
+            f"{cfg.layer_pattern}")
+        yarn = None
+        if cfg.yarn_factor:
+            yarn = yarn_rotary(cfg.resolved_head_dim, cfg.rope_theta,
+                               cfg.yarn_factor,
+                               cfg.yarn_original_max_positions,
+                               cfg.yarn_beta_fast, cfg.yarn_beta_slow,
+                               cfg.yarn_attention_factor)
+        kinds = {"sliding": (cfg.sliding_window, None), "full": (0, yarn)}
+        return tuple(kinds[k] for k in cfg.layer_pattern)
+
     # -- one block -----------------------------------------------------------
-    def _block(self, p, x, positions, *, window, block_skip=False):
+    def _block(self, p, x, positions, *, window, rot=None, block_skip=False):
+        """One layer -> (x, stats): stats are the expert layer's counters
+        (empty elsewhere)."""
         cfg = self.cfg
         with tracing.scope("attn"):
             # barrier: stops XLA promoting the scan-saved bf16 residual stack
@@ -125,7 +166,7 @@ class TransformerLM:
             x = optimization_barrier(x)
             p = mod.constrain_tree(p, self.block_specs())
             xn = rms_norm(x, p["ln1"], cfg.norm_eps)
-            q, k, v = qkv(cfg, p["attn"], xn, positions)
+            q, k, v = qkv(cfg, p["attn"], xn, positions, rot)
             o = chunked_attention(q, k, v, causal=True, window=window,
                                   q_offset=0, chunk_q=cfg.attn_chunk_q,
                                   chunk_kv=cfg.attn_chunk_kv,
@@ -133,15 +174,30 @@ class TransformerLM:
             x = x + dense(o, p["attn"]["w_o"], "bshe,hed->bsd")
             x = constrain(x, "act_batch", "act_seq", "act_embed")
         with tracing.scope("mlp"):
-            x = x + self._ffn(p, rms_norm(x, p["ln2"], cfg.norm_eps))
+            y, stats = self._ffn(p, rms_norm(x, p["ln2"], cfg.norm_eps))
+            x = x + y
             x = constrain(x, "act_batch", "act_seq", "act_embed")
-        return x
+        return x, stats
 
-    def _ffn(self, p, xn2: jax.Array) -> jax.Array:
-        if self.cfg.num_experts > 0:
-            return moe_ffn(self.cfg, p["moe"], xn2)
+    def _ffn(self, p, xn2: jax.Array) -> Tuple[jax.Array, Dict[str, Any]]:
+        cfg = self.cfg
+        if cfg.num_experts > 0 and cfg.experts_held > 0:
+            return held_moe_ffn(cfg, p["moe"], xn2)
+        if cfg.num_experts > 0:
+            return moe_ffn(cfg, p["moe"], xn2), {}
         return gated_mlp(xn2, p["mlp"]["wi_gate"], p["mlp"]["wi_up"],
-                         p["mlp"]["wo"])
+                         p["mlp"]["wo"]), {}
+
+    def _serving_window(self) -> int:
+        """The one window of every layer, for the serving paths, which do
+        not take a stack of mixed layer kinds."""
+        kinds = self.layer_kinds()
+        if len({w for w, _ in kinds}) > 1 or any(r is not None
+                                                  for _, r in kinds):
+            raise NotImplementedError(
+                f"{self.cfg.name}: serving a stack of layer kinds "
+                f"{self.cfg.layer_pattern} is not built; it trains")
+        return kinds[0][0]
 
     def _embed(self, params, tokens: jax.Array) -> jax.Array:
         with tracing.scope("embed"):
@@ -158,6 +214,13 @@ class TransformerLM:
     # -- full-sequence forward (train / prefill) ------------------------------
     def forward(self, params, tokens: jax.Array,
                 patch_embeds: Optional[jax.Array] = None):
+        return self.forward_and_stats(params, tokens, patch_embeds)[0]
+
+    def forward_and_stats(self, params, tokens: jax.Array,
+                          patch_embeds: Optional[jax.Array] = None):
+        """(logits, stats): stats are the expert layers' counters over the
+        stack (``moe_rows`` summed, ``moe_load_max`` the largest), empty
+        for a model without held experts."""
         cfg = self.cfg
         x = self._embed(params, tokens)
         if patch_embeds is not None:
@@ -165,30 +228,57 @@ class TransformerLM:
         B, S, _ = x.shape
         x = constrain(x, "act_batch", "act_seq", "act_embed")
         positions = jnp.arange(S, dtype=jnp.int32)
-        window = cfg.sliding_window if cfg.attention_kind == "sliding" else 0
+        kinds = self.layer_kinds()
+        period = len(kinds)
 
-        def body(carry, layer_p):
-            return self._block(layer_p, carry, positions, window=window), None
+        def layer_fn(window, rot):
+            def body(carry, layer_p):
+                return self._block(layer_p, carry, positions, window=window,
+                                   rot=rot)
+            if cfg.remat != "none":
+                return jax.checkpoint(body, prevent_cse=False)
+            return body
 
-        block_fn = body
-        if cfg.remat != "none":
-            block_fn = jax.checkpoint(body, prevent_cse=False)
+        fns = [layer_fn(*k) for k in kinds]
+        blocks = params["blocks"]
+        if period == 1:
+            block_fn = fns[0]
+        else:
+            # one scan step is one period, its layers unrolled
+            blocks = jax.tree_util.tree_map(
+                lambda a: a.reshape((-1, period) + a.shape[1:]), blocks)
+
+            def block_fn(carry, period_p):
+                stats = []
+                for i, fn in enumerate(fns):
+                    carry, st = fn(carry, jax.tree_util.tree_map(
+                        lambda a: a[i], period_p))
+                    stats.append(st)
+                return carry, _stack(stats)
         with tracing.scope("layers"):
             if cfg.scan_layers:
-                x, _ = jax.lax.scan(block_fn, x, params["blocks"])
+                x, stats = jax.lax.scan(block_fn, x, blocks)
             else:
-                for i in range(cfg.num_layers):
-                    layer_p = jax.tree_util.tree_map(lambda a: a[i],
-                                                     params["blocks"])
-                    x, _ = block_fn(x, layer_p)
+                stats = []
+                for i in range(cfg.num_layers // period):
+                    x, st = block_fn(x, jax.tree_util.tree_map(
+                        lambda a: a[i], blocks))
+                    stats.append(st)
+                stats = _stack(stats)
         with tracing.scope("head_loss"):
             logits = self._head(params, x)
-            return constrain(logits, "act_batch", "act_seq", "act_vocab")
+            logits = constrain(logits, "act_batch", "act_seq", "act_vocab")
+        return logits, {k: STAT_OVER_LAYERS[k](v) for k, v in stats.items()}
 
     def loss(self, params, batch) -> jax.Array:
+        return self.loss_and_stats(params, batch)[0]
+
+    def loss_and_stats(self, params, batch):
+        """(loss, stats of :meth:`forward_and_stats`)."""
         cfg = self.cfg
         tokens = batch["tokens"]
-        logits = self.forward(params, tokens, batch.get("patch_embeds"))
+        logits, stats = self.forward_and_stats(params, tokens,
+                                               batch.get("patch_embeds"))
         if "patch_embeds" in batch and batch["patch_embeds"] is not None:
             # loss only over text region (after the patch prefix)
             P = batch["patch_embeds"].shape[1]
@@ -197,7 +287,7 @@ class TransformerLM:
         mask = batch.get("loss_mask")
         with tracing.scope("head_loss"):
             return softmax_xent(logits[:, :-1], labels[:, 1:],
-                                None if mask is None else mask[:, 1:])
+                                None if mask is None else mask[:, 1:]), stats
 
     # -- serving ---------------------------------------------------------------
     def cache_len(self, max_len: int) -> int:
@@ -244,7 +334,7 @@ class TransformerLM:
         B, S, _ = x.shape
         T = self.cache_len(max(max_len or S, S))
         positions = jnp.arange(S, dtype=jnp.int32)
-        window = cfg.sliding_window if cfg.attention_kind == "sliding" else 0
+        window = self._serving_window()
 
         def store(k):
             return kv_cache_store(k.astype(self.cdtype), S, T)
@@ -262,7 +352,7 @@ class TransformerLM:
                 h = h + dense(o, layer_p["attn"]["w_o"], "bshe,hed->bsd")
             with tracing.scope("mlp"):
                 h = h + self._ffn(layer_p,
-                                  rms_norm(h, layer_p["ln2"], cfg.norm_eps))
+                                  rms_norm(h, layer_p["ln2"], cfg.norm_eps))[0]
             with tracing.scope("attn"):
                 return h, (store(k), store(v))
 
@@ -306,7 +396,7 @@ class TransformerLM:
         x = self._embed(params, tokens)                          # (B,S,D)
         pos = cache["pos"]                                       # (B,)
         T = kv_cache_len(cache["k"])
-        window = cfg.sliding_window if cfg.attention_kind == "sliding" else 0
+        window = self._serving_window()
         if S == 1:
             slot = (pos % T).astype(jnp.int32)                   # (B,)
             positions = pos[:, None].astype(jnp.int32)           # (B, 1)
@@ -333,7 +423,7 @@ class TransformerLM:
                 h = h + dense(o, layer_p["attn"]["w_o"], "bshe,hed->bsd")
             with tracing.scope("mlp"):
                 h = h + self._ffn(layer_p,
-                                  rms_norm(h, layer_p["ln2"], cfg.norm_eps))
+                                  rms_norm(h, layer_p["ln2"], cfg.norm_eps))[0]
             return h, (ck, cv)
 
         tm = jax.tree_util.tree_map

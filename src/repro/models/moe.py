@@ -1,9 +1,12 @@
 """Mixture-of-Experts FFN — the MIMDRAM MIMD-segment showcase.
 
-Experts are independent programs executing concurrently in different mesh
-segments (expert dim sharded over the 'model' axis). Token dispatch is the
-capacity-bounded scatter/gather formulation: O(T*k) routing work plus
-O(E*C*d*ff) expert compute — no O(T*E*C) one-hot tensors.
+Two paths. :func:`held_moe_ffn` (``experts_held > 0``) is one device's share
+of an expert-parallel layer: it routes over every expert, computes only the
+experts it holds, dropless, through the grouped matmul kernel. :func:`moe_ffn`
+holds every expert, sharded over the 'model' axis as independent programs in
+different mesh segments, with the capacity-bounded scatter/gather dispatch:
+O(T*k) routing work plus O(E*C*d*ff) expert compute — no O(T*E*C) one-hot
+tensors; tokens past an expert's capacity are dropped.
 """
 from __future__ import annotations
 
@@ -15,18 +18,83 @@ import jax.numpy as jnp
 from repro import compat
 from repro.configs.base import ModelConfig
 from repro.core.mimdram import constrain
+from repro.kernels.grouped_matmul import grouped_matmul
+from repro.launch import tracing
 from repro.models import module as mod
 from repro.models.layers import dense
 
 
 def moe_param_specs(cfg: ModelConfig, dtype: Any) -> Dict[str, mod.ParamSpec]:
+    """The router over all ``num_experts``, and the experts this layer
+    holds (``experts_held``, or all of them)."""
     d, f, e = cfg.d_model, cfg.d_ff, cfg.num_experts
+    h = cfg.experts_held or e
     return {
         "router": mod.spec((d, e), ("embed", "expert"), dtype),
-        "wi_gate": mod.spec((e, d, f), ("expert", "embed", "mlp"), dtype, ("normal", 1)),
-        "wi_up": mod.spec((e, d, f), ("expert", "embed", "mlp"), dtype, ("normal", 1)),
-        "wo": mod.spec((e, f, d), ("expert", "mlp", "embed"), dtype, ("normal", 1)),
+        "wi_gate": mod.spec((h, d, f), ("expert", "embed", "mlp"), dtype, ("normal", 1)),
+        "wi_up": mod.spec((h, d, f), ("expert", "embed", "mlp"), dtype, ("normal", 1)),
+        "wo": mod.spec((h, f, d), ("expert", "mlp", "embed"), dtype, ("normal", 1)),
     }
+
+
+def held_moe_ffn(cfg: ModelConfig, p: Dict[str, jax.Array], x: jax.Array
+                 ) -> Tuple[jax.Array, Dict[str, jax.Array]]:
+    """x: (B, S, D) -> ((B, S, D), stats): the part of a dropless top-k MoE
+    that the held experts ``[expert_offset, expert_offset + experts_held)``
+    give.
+
+    The router (float32, at the highest matmul precision) scores all
+    ``num_experts``; each token keeps its top ``experts_per_token``, with
+    gates renormalised over them. Of those (token, expert) pairs the held
+    ones are sorted by expert into a buffer of ``T * min(K, E_held)`` rows,
+    as many as there can be, so no pair is ever dropped and a token's
+    result never depends on its batch mates. The held experts run as a
+    SwiGLU through the grouped matmul, and each pair's row is gathered back
+    and weighted by its gate. The absent experts' part is left out (the
+    other devices of the layer give it). ``stats``: ``moe_rows``, the pairs
+    routed here, and ``moe_load_max``, the busiest held expert's rows over
+    the mean.
+    """
+    B, S, D = x.shape
+    T = B * S
+    K, Eh, off = cfg.experts_per_token, cfg.experts_held, cfg.expert_offset
+    M = T * min(K, Eh)
+    xt = x.reshape(T, D)
+    with tracing.scope("moe_route"):
+        logits = jnp.einsum("td,de->te", xt.astype(jnp.float32),
+                            p["router"].astype(jnp.float32),
+                            precision=jax.lax.Precision.HIGHEST)
+        gate, idx = jax.lax.top_k(jax.nn.softmax(logits, axis=-1), K)
+        gate = gate / gate.sum(-1, keepdims=True)
+        local = idx - off
+        held = (local >= 0) & (local < Eh)                      # (T, K)
+        key = jnp.where(held, local, Eh).reshape(T * K)
+        # pairs in expert order (a stable sort keeps token order within an
+        # expert); the held ones fill the buffer's first rows
+        order = jnp.argsort(key, stable=True)[:M]
+        one_hot = (key[:, None] == jnp.arange(Eh)[None, :]).astype(jnp.int32)
+        sizes = one_hot.sum(0)                                  # (Eh,)
+        # each held pair's row: its expert's first row plus its rank there
+        rank = ((jnp.cumsum(one_hot, 0) - one_hot) * one_hot).sum(-1)
+        first = jnp.cumsum(sizes) - sizes
+        # a pair that is not held points past the buffer
+        row = jnp.where(held.reshape(-1),
+                        first[jnp.minimum(key, Eh - 1)] + rank, M)
+        total = sizes.sum()
+        filled = (jnp.arange(M) < total)[:, None]
+        xs = jnp.where(filled, xt[order // K], 0)
+    with tracing.scope("moe_experts"):
+        g = grouped_matmul(xs, p["wi_gate"], sizes)
+        u = grouped_matmul(xs, p["wi_up"], sizes)
+        ys = grouped_matmul(jax.nn.silu(g) * u, p["wo"], sizes)
+    with tracing.scope("moe_combine"):
+        # only written rows are read: the gather fills the pairs that point
+        # past the buffer with zero
+        y = ys.at[row].get(mode="fill", fill_value=0).reshape(T, K, D)
+        y = (y.astype(jnp.float32) * gate[..., None]).sum(1).astype(x.dtype)
+    stats = {"moe_rows": total,
+             "moe_load_max": sizes.max() * Eh / jnp.maximum(total, 1)}
+    return y.reshape(B, S, D), stats
 
 
 def _capacity(cfg: ModelConfig, tokens: int) -> int:

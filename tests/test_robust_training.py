@@ -292,7 +292,15 @@ def test_main_resume_past_end_prints_nothing_to_do(tmp_path, monkeypatch,
     monkeypatch.setattr(sys, "argv", [
         "train", "--arch", ARCH, "--steps", "3", "--batch", str(B),
         "--seq", str(S), "--checkpoint-dir", str(tmp_path), "--resume"])
-    train_mod.main()
+    # main() turns on the persistent compilation cache for its process;
+    # turn it off again, so that later tests in this process compile afresh
+    from jax.experimental.compilation_cache import compilation_cache
+    before = jax.config.jax_compilation_cache_dir
+    try:
+        train_mod.main()
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)
+        compilation_cache.reset_cache()
     outp = capsys.readouterr().out
     assert "nothing to do: resumed at step 3" in outp
     assert "final loss" not in outp

@@ -6,9 +6,10 @@ chip of a described (not attached) ``v5e:2x2`` topology: stablelm-1.6b's
 attention (32 kv heads, head_dim 64, bf16) with 8 slots of a 576-token cache
 and a 512-token prefill; and the training attention's forward and backward
 kernels at internlm2-1.8b-6l's per-row shapes (1024 tokens, 16 query heads,
-8 kv heads, head_dim 128, bf16). Nothing runs; a refused block shape, a
-kernel that does not lower, or scratch that overflows VMEM fails the
-compile.
+8 kv heads, head_dim 128, bf16); and the held experts' grouped matmul
+(``gmm``, ``tgmm``) at Mellum2's expert widths (2304 by 896, 8 experts).
+Nothing runs; a refused block shape, a kernel that does not lower, or
+scratch that overflows VMEM fails the compile.
 
 The topology is described inside a fixture only: one process at a time may
 hold the TPU library, so no module-level code here may touch it.
@@ -24,6 +25,7 @@ from repro.compat import make_mesh
 from repro.configs import ShapeConfig, get_config
 from repro.core.mimdram import plan_sharding, use_plan
 from repro.kernels.flash_attention import ops
+from repro.kernels.grouped_matmul import grouped_matmul
 from repro.models.layers import chunked_attention, flash_attention_pallas
 
 H, D = 32, 64                  # stablelm-1.6b kv heads and head_dim
@@ -32,6 +34,9 @@ PS = 128                       # chip_smoke.py's page size
 NP = -(-T // PS)
 P = B * NP + 1                 # engine pool: every slot's pages + trash page
 TS, TQ, TKV, TD = 1024, 16, 8, 128   # the train cell's row: internlm2-1.8b
+# Mellum2's expert layer: d_model, expert width, experts held; and a buffer
+# of 4096 routed rows (eight row tiles)
+ED, EF, EH, EM = 2304, 896, 8, 4096
 
 
 @pytest.fixture(scope="module")
@@ -148,3 +153,46 @@ def test_attention_compiles_per_batch_shard_on_4_chips(topo, monkeypatch):
     text = jax.jit(step).lower(x, x, x).compile().as_text()
     assert "tpu_custom_call" in text
     assert "flash_bwd" in text
+
+
+def test_grouped_matmul_compiles_for_v5e(one_chip, monkeypatch):
+    """The held experts' up and down projections with their gradients:
+    the forward and input-gradient kernel (``gmm``, both orientations) and
+    the weight-gradient kernel (``tgmm``) at Mellum2's widths."""
+    monkeypatch.setenv("REPRO_PALLAS_INTERPRET", "0")
+    bf, f32 = jnp.bfloat16, jnp.float32
+
+    def grads(x, w_in, w_out, sizes):
+        def loss(x, w_in, w_out):
+            h = grouped_matmul(x, w_in, sizes)
+            return grouped_matmul(h, w_out, sizes).astype(f32).sum()
+        return jax.grad(loss, argnums=(0, 1, 2))(x, w_in, w_out)
+
+    args = [_sd(one_chip, (EM, ED), bf), _sd(one_chip, (EH, ED, EF), f32),
+            _sd(one_chip, (EH, EF, ED), f32),
+            _sd(one_chip, (EH,), jnp.int32)]
+    text = jax.jit(grads).lower(*args).compile().as_text()
+    # the first forward, both input gradients and both weight gradients
+    # (the second forward's value is not needed)
+    assert text.count('custom_call_target="tpu_custom_call"') == 5
+    assert "%gmm" in text and "%tgmm" in text
+
+
+def test_window_attention_with_eight_heads_a_group_compiles_for_v5e(
+        one_chip, monkeypatch):
+    """Mellum2's training attention on one 4096-token row: 32 query heads
+    on 4 kv heads of 128, a 1024-key window; forward and backward through
+    ``chunked_attention``, whose forward q block shrinks with the group
+    (``fwd_block_q``) so that its score tiles fit the kernel's VMEM."""
+    monkeypatch.setenv("REPRO_PALLAS_INTERPRET", "0")
+    bf = jnp.bfloat16
+    q = _sd(one_chip, (1, 4096, 32, 128), bf)
+    kv = _sd(one_chip, (1, 4096, 4, 128), bf)
+
+    def grads(q, k, v):
+        return jax.grad(lambda q, k, v: chunked_attention(
+            q, k, v, causal=True, window=1024, impl="pallas").astype(
+                jnp.float32).sum(), argnums=(0, 1, 2))(q, k, v)
+
+    text = jax.jit(grads).lower(q, kv, kv).compile().as_text()
+    assert "flash_fwd" in text and "flash_bwd" in text

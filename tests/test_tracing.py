@@ -26,11 +26,21 @@ from repro.models import abstract_params, build_model, init_params
 from repro.optim import make_optimizer
 
 ARCH = "pimref-100m"
+# the scopes of a layer of held experts, which a dense model has not
+MOE_SCOPES = ("moe_route", "moe_experts", "moe_combine")
 
 
-def _train_step_hlo() -> str:
+def _held_moe_config():
+    """pimref-100m at smoke widths with every layer's FFN two of eight
+    experts held, top 4, in a period of a windowed and a full layer."""
+    return get_config(ARCH, smoke=True).replace(
+        family="moe", num_experts=8, experts_per_token=4, experts_held=2,
+        d_ff=32, layer_pattern=("sliding", "full"), sliding_window=8)
+
+
+def _train_step_hlo(cfg=None) -> str:
     """The guarded train step's optimized HLO at smoke widths."""
-    cfg = get_config(ARCH, smoke=True)
+    cfg = cfg or get_config(ARCH, smoke=True)
     shape = ShapeConfig("t", 32, 4, "train")
     plan = plan_sharding(cfg, shape, mesh_lib.make_local_mesh(("data",)))
     model = build_model(cfg)
@@ -66,9 +76,24 @@ def test_scopes_change_metadata_only(monkeypatch):
     assert _strip(scoped) == _strip(plain)
     names = set(re.findall(r'op_name="([^"]*)"', scoped))
     for sc in tracing.SCOPES:
-        assert any(f"/{sc}/" in n or f"({sc})" in n for n in names), sc
+        if sc not in MOE_SCOPES:
+            assert any(f"/{sc}/" in n or f"({sc})" in n for n in names), sc
     # JAX marks the backward pass's names: what the reader uses
     assert any("transpose(jvp(" in n and "/attn_bwd/" in n for n in names)
+
+
+def test_held_expert_scopes_change_metadata_only(monkeypatch):
+    """A step with held experts names its routing, grouped matmuls and
+    combine, inside ``mlp``, in both directions; the names change nothing
+    else."""
+    scoped = _train_step_hlo(_held_moe_config())
+    monkeypatch.setattr(tracing, "scope",
+                        lambda name: contextlib.nullcontext())
+    assert _strip(scoped) == _strip(_train_step_hlo(_held_moe_config()))
+    names = set(re.findall(r'op_name="([^"]*)"', scoped))
+    for sc in MOE_SCOPES:
+        assert any(f"/mlp/{sc}/" in n for n in names), sc
+        assert any("transpose(" in n and f"/{sc}/" in n for n in names), sc
 
 
 def test_kernel_names_are_listed():
@@ -80,7 +105,7 @@ def test_kernel_names_are_listed():
     for path in glob.glob(os.path.join(src, "**", "*.py"), recursive=True):
         with open(path) as f:
             names |= set(re.findall(r'name="(\w+)"', f.read()))
-    assert "flash_bwd" in names
+    assert {"flash_bwd", "gmm", "tgmm"} <= names
     assert names <= set(tracing.KERNELS)
 
 
